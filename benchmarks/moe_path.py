@@ -69,6 +69,7 @@ def run(smoke: bool = True, out: str = "BENCH_moe_path.json") -> dict:
     from repro.core.go_cache import go_cache_init, go_cache_step
     from repro.core.grouping import default_groups, group_of_expert_from_groups
     from repro.kernels.ops import go_selected_ffn, plan_tile_dispatch
+    from repro.launch.mesh import make_mesh
 
     if smoke:
         T, d, E, k, g, de, bn, steps, B = 128, 64, 8, 2, 2, 64, 8, 8, 16
@@ -122,13 +123,13 @@ def run(smoke: bool = True, out: str = "BENCH_moe_path.json") -> dict:
     n_dev = jax.device_count()
     M = 2 if (n_dev >= 2 and E % 2 == 0) else 0
     if M:
-        mesh = jax.make_mesh((n_dev // M, M), ("data", "model"))
+        mesh = make_mesh((n_dev // M, M), ("data", "model"))
         B_ep = 4
         S = T // B_ep
         h = x.reshape(B_ep, S, d)
         f_ep_x = jax.jit(lambda h: MOE.moe_forward_ep(params, h, e_xla))
         f_ep_p = jax.jit(lambda h: MOE.moe_forward_ep(params, h, e_pal))
-        with mesh:
+        with jax.set_mesh(mesh):
             us_ep_x = _timeit(lambda: f_ep_x(h)[0].block_until_ready())
             us_ep_p = _timeit(lambda: f_ep_p(h)[0].block_until_ready())
             a_x = f_ep_x(h)[1]
@@ -296,6 +297,8 @@ def main() -> None:
             os.environ.get("XLA_FLAGS", "")
             + f" --xla_force_host_platform_device_count="
               f"{args.sharded_devices}").strip()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     rep = run(smoke=args.smoke, out=args.out)
     f, sh, dck = rep["forward"], rep["forward_sharded"], rep["decode"]
     pl = rep["planner_us"]

@@ -29,6 +29,8 @@ def main() -> None:
             + " --xla_force_host_platform_device_count=4").strip()
 
     from benchmarks import paper_fig4, paper_fig5, paper_table1, roofline
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     rows = []
 

@@ -783,6 +783,8 @@ def main():
     p = args.prompt or (16 if args.smoke else 64)
     g = args.gen or (8 if args.smoke else 32)
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     rep = run(args.arch, smoke=args.smoke, slot_counts=slot_counts,
               num_requests=n, prompt_len=p, gen=g, rate=args.rate,
               seed=args.seed, paged=args.paged, page_size=args.page_size,

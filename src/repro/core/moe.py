@@ -74,15 +74,18 @@ def resolve_backend(e: MoEConfig, refs=None) -> str:
 
 def _under_autodiff(x) -> bool:
     """Best-effort: is `x` being traced for differentiation? Walks the tracer
-    nesting for a JVP tracer (grad/vjp linearization), unwrapping jit/vmap
-    tracers along the way. grad-of-jit retraces are caught at transpose time
-    by jax itself — this only makes the common paths fail early and clearly."""
+    nesting for a linearize tracer (grad/vjp) or a JVP tracer (jvp),
+    unwrapping vmap tracers along the way. grad-of-jit retraces are caught at
+    transpose time by jax itself — this only makes the common paths fail
+    early and clearly. jax does not export the linearize tracer's class, so
+    it is matched by name."""
     from jax.interpreters import ad
     t = x
     for _ in range(16):
         if not isinstance(t, jax.core.Tracer):
             return False
-        if isinstance(t, ad.JVPTracer):
+        if isinstance(t, ad.JVPTracer) or \
+                type(t).__name__ == "LinearizeTracer":
             return True
         t = getattr(t, "primal", getattr(t, "val", None))
     return False
@@ -562,7 +565,6 @@ def moe_forward_ep(params: dict, h: jax.Array, e: MoEConfig) -> tuple:
     h [B, S, d] -> (y [B, S, d], aux). Token-choice only; requires
     E % model_axis == 0 (callers fall back to the vmapped path otherwise).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.models.layers import current_mesh, dp_spec
@@ -596,8 +598,7 @@ def moe_forward_ep(params: dict, h: jax.Array, e: MoEConfig) -> tuple:
                 # evicted pairs keep their rows, lose their combine weight
                 y, _, plan = OPS.moe_ffn_fused(
                     xb, tok, ef, wf, {"wg": wg, "wi": wi, "wo": wo}, E, S,
-                    bn=bn, expert_offset=lo, num_local=E_loc, capacity=C,
-                    replicate_under_mesh=False)   # shard_map body: local data
+                    bn=bn, expert_offset=lo, num_local=E_loc, capacity=C)
                 cnt = plan.counts[:E_loc]
                 dropped = (local & (plan.pos >= C)).sum()
             else:
@@ -619,12 +620,12 @@ def moe_forward_ep(params: dict, h: jax.Array, e: MoEConfig) -> tuple:
         return (y, bal, cnt, dropped)
 
     bank = params["experts"]
-    y, bal, cnt, dropped = shard_map(
+    y, bal, cnt, dropped = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(dp, None, None), P(), P("model", None, None),
                   P("model", None, None), P("model", None, None)),
         out_specs=(P(dp, None, None), P(), P("model"), P()),
-        check_rep=False,
+        check_vma=False,
     )(h, params["gate"], bank["wg"], bank["wi"], bank["wo"])
 
     y = y.astype(h.dtype) + jax.vmap(lambda xb: _shared_out(params, xb))(h)

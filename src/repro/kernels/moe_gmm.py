@@ -34,9 +34,10 @@ invalid tail tiles, so the pipeline re-uses the staged VMEM buffer instead of
 issuing fresh HBM copies for tiles it will not compute.
 
 `interpret=None` auto-selects from the LOWERING context, not the host default:
-inside a mesh (`with mesh:` — shard_map bodies, sharded jits) the kernel lowers
-for the mesh's devices, which may differ from `jax.default_backend()` (a forced
-CPU host mesh on a TPU host, or explicit device placement). The resolved value
+inside a mesh (`jax.set_mesh(mesh)` — shard_map bodies, sharded jits) the
+kernel lowers for the mesh's devices, which may differ from
+`jax.default_backend()` (a forced CPU host mesh on a TPU host, or explicit
+device placement). The resolved value
 is part of the jit cache key — the public entry points resolve it BEFORE the
 jit boundary, so a process that lowers for both platforms (TPU eager + CPU
 mesh tests) compiles both variants instead of replaying whichever traced
@@ -50,20 +51,18 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-
-def _mesh_lib():
-    from jax._src import mesh as mesh_lib
-    return mesh_lib
+from jax.sharding import PartitionSpec
 
 
 def lowering_platform() -> str:
     """The platform the next pallas_call actually lowers for: the active
-    mesh's devices when inside a `with mesh:` context (shard_map / sharded
-    jit tracing happens there), the host default backend otherwise."""
-    m = _mesh_lib().thread_resources.env.physical_mesh
-    if m is not None and not m.empty:
-        return m.devices.flat[0].platform
+    mesh's devices when inside `jax.set_mesh(mesh)` (shard_map / sharded jit
+    tracing happens there), the host default backend otherwise. The abstract
+    mesh names its devices by kind only; every TPU kind starts with "TPU"."""
+    m = jax.sharding.get_abstract_mesh()
+    if not m.empty and m.abstract_device is not None:
+        kind = m.abstract_device.device_kind
+        return "tpu" if kind.lower().startswith("tpu") else kind.lower()
     return jax.default_backend()
 
 
@@ -74,7 +73,7 @@ def default_interpret() -> bool:
 
 def replicate_for_gspmd(*arrays):
     """Pin arrays to a fully-replicated layout when tracing under a GSPMD
-    mesh (`with mesh:` + jit).
+    mesh (`jax.set_mesh(mesh)` + jit).
 
     The grouped-GEMM pipeline — the planner's small scatter/gather chains
     feeding a pallas_call — has no SPMD partitioning rule; letting the
@@ -85,19 +84,33 @@ def replicate_for_gspmd(*arrays):
     inputs replicated keeps every downstream op replicated, which matches
     the unsharded numerics exactly.
 
-    Callers that run inside a shard_map body (manual mesh axes — the EP
-    path, where data is already shard-local) must NOT call this: a sharding
-    constraint has no meaning there (and jax rejects it under check_rep).
-    The distinction is static at every call site, so it is the caller's
-    switch (`moe_ffn_fused(replicate_under_mesh=...)`) rather than a
-    runtime axis-env probe."""
-    from jax.sharding import NamedSharding, PartitionSpec
-    m = _mesh_lib().thread_resources.env.physical_mesh
-    if m is not None and not m.empty:
-        rep = NamedSharding(m, PartitionSpec())
-        arrays = tuple(jax.lax.with_sharding_constraint(a, rep)
+    Inside a shard_map body (every mesh axis manual — the EP path, where
+    data is already shard-local) the arrays pass through untouched."""
+    if _gspmd_mesh() is not None:
+        arrays = tuple(jax.lax.with_sharding_constraint(a, PartitionSpec())
                        for a in arrays)
     return arrays if len(arrays) > 1 else arrays[0]
+
+
+def _gspmd_mesh():
+    """The ambient mesh while GSPMD partitions the traced program (a mesh
+    with automatic axes), or None: no mesh, or a shard_map body."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty or m.are_all_axes_manual else m
+
+
+def mosaic_call(kernel, *operands):
+    """Apply `kernel` (a pallas_call) to `operands` so that it also lowers
+    inside a sharded program. Mosaic kernels have no partitioning rule, and
+    JAX lowers one only where every mesh axis is manual. Under a GSPMD mesh
+    the call therefore runs in a shard_map over that mesh with every operand
+    and the result replicated: each device computes the whole call, the same
+    numbers as unsharded."""
+    m = _gspmd_mesh()
+    if m is None:
+        return kernel(*operands)
+    return jax.shard_map(kernel, mesh=m, in_specs=PartitionSpec(),
+                         out_specs=PartitionSpec(), check_vma=False)(*operands)
 
 
 def _pad_to(a: jax.Array, axis: int, size: int) -> jax.Array:
@@ -291,12 +304,12 @@ def _gmm(x, w, tile_expert, tile_valid, *, bn, bk, bf, interpret, out_dtype):
         out_specs=pl.BlockSpec((bn, bf), lambda i, j, k, te, tv: (i, j)),
         scratch_shapes=[pltpu.VMEM((bn, bf), jnp.float32)],
     )
-    y = pl.pallas_call(
+    y = mosaic_call(pl.pallas_call(
         functools.partial(_gmm_kernel, nk=nk),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((ni * bn, Fp), out_dtype or x.dtype),
         interpret=interpret,
-    )(te, tv, xp, wp)
+    ), te, tv, xp, wp)
     return y[:N, :F]
 
 
@@ -352,12 +365,12 @@ def _gmm_scaled(x, w, tile_expert, tile_valid, row_scale, *, bn, bk, bf,
         out_specs=pl.BlockSpec((bn, bf), lambda i, j, k, te, tv: (i, j)),
         scratch_shapes=[pltpu.VMEM((bn, bf), jnp.float32)],
     )
-    y = pl.pallas_call(
+    y = mosaic_call(pl.pallas_call(
         functools.partial(_gmm_scaled_kernel, nk=nk),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((ni * bn, Fp), out_dtype),
         interpret=interpret,
-    )(te, tv, xp, wp, sp)
+    ), te, tv, xp, wp, sp)
     return y[:N, :F]
 
 
@@ -410,12 +423,12 @@ def _gmm_scaled_fused(x, w, tile_expert, tile_expert2, tile_valid, row_scale,
         out_specs=pl.BlockSpec((bn, bf), lambda i, j, k, te, te2, tv: (i, j)),
         scratch_shapes=[pltpu.VMEM((bn, bf), jnp.float32)],
     )
-    y = pl.pallas_call(
+    y = mosaic_call(pl.pallas_call(
         functools.partial(_gmm_scaled_fused_kernel, nk=nk),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((ni * bn, Fp), out_dtype),
         interpret=interpret,
-    )(te, te2, tv, xp, wp, wp, selp, sp)
+    ), te, te2, tv, xp, wp, wp, selp, sp)
     return y[:N, :F]
 
 
@@ -453,12 +466,12 @@ def _gmm_swiglu_fused(x, wg, wi, tile_expert, tile_expert2, tile_valid,
         scratch_shapes=[pltpu.VMEM((bn, bf), jnp.float32),
                         pltpu.VMEM((bn, bf), jnp.float32)],
     )
-    y = pl.pallas_call(
+    y = mosaic_call(pl.pallas_call(
         functools.partial(_gmm_swiglu_fused_kernel, nk=nk),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((ni * bn, Fp), x.dtype),
         interpret=interpret,
-    )(te, te2, tv, xp, wgp, wip, wgp, wip, selp)
+    ), te, te2, tv, xp, wgp, wip, wgp, wip, selp)
     return y[:N, :F]
 
 
@@ -486,10 +499,10 @@ def _gmm_swiglu(x, wg, wi, tile_expert, tile_valid, *, bn, bk, bf, interpret):
         scratch_shapes=[pltpu.VMEM((bn, bf), jnp.float32),
                         pltpu.VMEM((bn, bf), jnp.float32)],
     )
-    y = pl.pallas_call(
+    y = mosaic_call(pl.pallas_call(
         functools.partial(_gmm_swiglu_kernel, nk=nk),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((ni * bn, Fp), x.dtype),
         interpret=interpret,
-    )(te, tv, xp, wgp, wip)
+    ), te, tv, xp, wgp, wip)
     return y[:N, :F]

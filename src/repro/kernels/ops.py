@@ -327,8 +327,7 @@ def moe_ffn_fused(x_src: jax.Array, tok: jax.Array, ef: jax.Array,
                   num_tokens: int, *, expert_of_lane: jax.Array | None = None,
                   bn: int = 0, interpret: bool | None = None,
                   expert_offset: jax.Array | int = 0, num_local: int = 0,
-                  capacity: int = 0, fuse=None,
-                  replicate_under_mesh: bool = True):
+                  capacity: int = 0, fuse=None):
     """Grouped-GEMM MoE FFN over (token, expert) pairs with fused combine.
 
     x_src [T_src, d] source rows; tok [N] source row per pair; ef [N] lane id
@@ -347,10 +346,6 @@ def moe_ffn_fused(x_src: jax.Array, tok: jax.Array, ef: jax.Array,
     combine weight — capacity drops without a second sort; read the kept
     mask back off `plan.pos < capacity`.
 
-    `replicate_under_mesh=False` is for callers tracing inside a shard_map
-    body (the EP path): their operands are shard-local and must not get the
-    GSPMD replication pin.
-
     `fuse` (static pair ids per lane) packs paired lanes into shared tiles;
     the straddle tile's rows are resolved in-kernel via the plan's per-row
     selector, so fusion is numerically exact.
@@ -361,10 +356,8 @@ def moe_ffn_fused(x_src: jax.Array, tok: jax.Array, ef: jax.Array,
     """
     bn = bn or default_block_rows()
     # under a GSPMD mesh the whole branch computes replicated (see
-    # replicate_for_gspmd); shard_map callers (the EP body, whose data is
-    # already shard-local) pass replicate_under_mesh=False
-    if replicate_under_mesh:
-        x_src, tok, ef, wf = replicate_for_gspmd(x_src, tok, ef, wf)
+    # replicate_for_gspmd; a no-op in the EP shard_map body)
+    x_src, tok, ef, wf = replicate_for_gspmd(x_src, tok, ef, wf)
     plan = plan_tile_dispatch(ef, num_experts, bn, expert_offset=expert_offset,
                               num_local=num_local, fuse=fuse)
     if capacity:

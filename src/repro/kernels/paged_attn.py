@@ -8,10 +8,10 @@ this kernel is the attention-side sibling. The dense gather reads EVERY
 page slot of every block table each tick — bandwidth scales with
 `max_tokens`. Here the grid walks (batch row, logical page) with the block
 table scalar-prefetched, so each step stages exactly ONE physical page into
-VMEM; pages past the row's position `t` (always mapped to the null page 0)
-skip their FLOPs via `pl.when` AND resolve to the constant block index 0,
-so the pipeline re-uses the staged null-page buffer instead of issuing
-fresh HBM copies — per-tick traffic scales with LIVE tokens.
+VMEM; pages past the row's position `t` skip their FLOPs via `pl.when` AND
+resolve to the block index of the row's last live page, so the pipeline
+re-uses the staged buffer instead of issuing fresh HBM copies — per-tick
+traffic scales with LIVE tokens.
 
 Kernels:
   paged_attn_decode(q, k_pages, v_pages, block_table, t)   -> [B, Hq, hd]
@@ -31,17 +31,16 @@ Masking rules (matching the gather path exactly):
 Null pages need no special-casing for CORRECTNESS — every position they
 back is already masked by the rules above (block tables only map live
 positions to real pages) — but they are where the bandwidth win comes
-from: a dead page's block index is 0, constant across the tail of the row,
-so only compute-live pages cost HBM traffic.
+from: a dead page's block index repeats the last live one, constant across
+the tail of the row, so only compute-live pages cost HBM traffic.
 
 `interpret=None` auto-selects from the lowering context exactly like
 kernels/moe_gmm.py (pallas lowers via Mosaic only on TPU; CPU CI runs the
 same kernel body in interpret mode), and the resolved value is part of the
-jit cache key. Under a GSPMD mesh the inputs are pinned replicated
-(`replicate_for_gspmd`) — pallas_call has no SPMD partitioning rule, and
-the interpret lowering miscompiles on sharded CPU host meshes (the
-moe_gmm.py precedent); a shard_mapped page-parallel variant is the ROADMAP
-follow-up for real multi-chip TPU.
+jit cache key. Under a GSPMD mesh the kernel runs replicated on every
+device (`moe_gmm.py::mosaic_call`) — a Mosaic kernel has no partitioning
+rule; a page-parallel shard_map variant is the ROADMAP follow-up for real
+multi-chip TPU.
 
 `resolve_mode(cfg)` is the path selector consumed by models/attention.py
 and launch/sharding.py: cfg.paged_attn "kernel" / "gather" are explicit,
@@ -59,7 +58,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.moe_gmm import (default_interpret, lowering_platform,
-                                   replicate_for_gspmd)
+                                   mosaic_call)
 
 NEG_INF = -1e30
 
@@ -77,73 +76,135 @@ def resolve_mode(cfg) -> str:
     return mode
 
 
+# ------------------------------------------------------------- shared step
+#
+# Mosaic-friendly layout: the kernels never reshape, transpose or slice a
+# head out of a VMEM tile. A page [ps, Hkv, hd] is viewed (free, in HBM) as
+# [ps*Hkv, hd] — row c holds position c // Hkv, kv head c % Hkv — and the
+# queries as [R, hd], one row per (query position, q head). One 2-D matmul
+# scores every query row against every page row; `ok` keeps only the rows of
+# the query's own kv head (GQA grouping) at attendable positions, so masked
+# rows leave exactly 0 in the softmax. The matmul does Hkv x the useful
+# FLOPs, which the MXU absorbs: decode attention is bound by page bytes.
+
+NO_WINDOW = 1 << 30     # a window span no position reaches
+
+
+def _span(w):
+    """Keys must sit at k_pos > q_pos - span: `w` inside a sliding window,
+    an unreachable span for global attention (w <= 0). A scalar i32 select,
+    so no boolean select reaches a vector mask."""
+    return jnp.where(w > 0, w, NO_WINDOW)
+
+
+def _reset(m_ref, l_ref, acc_ref):
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+
+def _finish(o_ref, l_ref, acc_ref):
+    o_ref[...] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-20)
+
+
+def _row_scales(s_ref, head):
+    """Per-query-row scale [R, 1] from a page's per-kv-head scales [1, Hkv]:
+    a masked lane sum with one nonzero term, so exact in f32."""
+    hkv = s_ref.shape[-1]
+    pick = head == jax.lax.broadcasted_iota(jnp.int32, (1, hkv), 1)
+    return jnp.sum(jnp.where(pick, s_ref[...], 0.0), axis=-1, keepdims=True)
+
+
+def _attend_page(q, k_ref, v_ref, ks_ref, vs_ref, head, ok, m_ref, l_ref,
+                 acc_ref, *, scale: float, softcap: float):
+    """One online-softmax step of R query rows [R, hd] against one page
+    [ps*Hkv, hd]. `head` [R, 1] is each row's kv head, `ok` [R, ps*Hkv] the
+    mask. int8 pages (ks_ref/vs_ref given) run in f32 and fold their scales
+    into the scores and the weighted values per row."""
+    k, v = k_ref[...], v_ref[...]
+    if ks_ref is not None:
+        q, k, v = (a.astype(jnp.float32) for a in (q, k, v))
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    if ks_ref is not None:
+        s = s * _row_scales(ks_ref, head)
+    s = s * scale
+    if softcap > 0:
+        s = softcap * jnp.tanh(s / softcap)
+    s = jnp.where(ok, s, NEG_INF)
+    m_prev = m_ref[...]
+    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    corr = jnp.exp(m_prev - m_new)
+    l_ref[...] = l_ref[...] * corr + p.sum(axis=-1, keepdims=True)
+    pv = jnp.dot(p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+    if vs_ref is not None:
+        pv = pv * _row_scales(vs_ref, head)
+    acc_ref[...] = acc_ref[...] * corr + pv
+    m_ref[...] = m_new
+
+
+def _page_specs(ps: int, hkv: int, hd: int, quant: bool, page_index):
+    """BlockSpecs for the K and V page views [NP, ps*Hkv, hd] (plus their
+    [NP, 1, Hkv] scale views when quantized), all gathered by `page_index`
+    (grid indices + scalar-prefetch refs -> physical page)."""
+    page = pl.BlockSpec((pl.squeezed, ps * hkv, hd),
+                        lambda *a: (page_index(*a), 0, 0))
+    specs = [page, page]
+    if quant:
+        sc = pl.BlockSpec((pl.squeezed, 1, hkv),
+                          lambda *a: (page_index(*a), 0, 0))
+        specs += [sc, sc]
+    return specs
+
+
+def _page_operands(k_pages, v_pages, k_scales, v_scales):
+    NP, ps, hkv, hd = k_pages.shape
+    ops = [k_pages.reshape(NP, ps * hkv, hd), v_pages.reshape(NP, ps * hkv, hd)]
+    if k_scales is not None:
+        ops += [s.astype(jnp.float32).reshape(NP, 1, hkv)
+                for s in (k_scales, v_scales)]
+    return ops
+
+
 # --------------------------------------------------------------------- decode
 
 def _decode_kernel(bt_ref, tv_ref, wv_ref, q_ref, k_ref, v_ref, *rest,
-                   ps: int, num_pages: int, softcap: float, scale: float,
-                   quant: bool = False):
+                   ps: int, hkv: int, group: int, num_pages: int,
+                   softcap: float, scale: float, quant: bool = False):
     """Grid (b, j): batch row b, logical page j (j innermost — the online-
     softmax reduction axis). Scalar-prefetched refs: block table [B, P],
     positions [B], window [1]. With `quant`, two extra operands carry the
     page's per-kv-head scales ([1, Hkv] blocks gathered by the same
-    block-table index map) and the int8 page dequantizes in-VMEM — HBM
+    block-table index map) and the int8 page dequantizes in-kernel — HBM
     traffic drops with the storage dtype while compute stays fp32."""
-    if quant:
-        ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
-    else:
-        o_ref, m_ref, l_ref, acc_ref = rest
+    ks_ref, vs_ref = rest[:2] if quant else (None, None)
+    o_ref, m_ref, l_ref, acc_ref = rest[-4:]
     b, j = pl.program_id(0), pl.program_id(1)
     t = tv_ref[b]
-    w = wv_ref[0]
+    span = _span(wv_ref[0])
 
     @pl.when(j == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref[...], NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref[...])
-        acc_ref[...] = jnp.zeros_like(acc_ref[...])
+        _reset(m_ref, l_ref, acc_ref)
 
     base = j * ps
     # live <=> the page holds at least one attendable position: some
     # k_pos in [base, base+ps-1] with k_pos <= t (and inside the window).
-    # Dead pages (always block-table index 0, the null page) skip ALL work.
-    live = base <= t
-    live = jnp.logical_and(
-        live, jnp.where(w > 0, base + ps - 1 > t - w, True))
-
-    @pl.when(live)
+    # Dead pages skip ALL work.
+    @pl.when((base <= t) & (base + ps - 1 > t - span))
     def _attend():
-        q = q_ref[0]                                   # [Hq, hd]
-        k = k_ref[0]                                   # [ps, Hkv, hd]
-        v = v_ref[0]
-        if quant:
-            k = k.astype(jnp.float32) * ks_ref[0][None, :, None]
-            v = v.astype(jnp.float32) * vs_ref[0][None, :, None]
-        Hkv, G = m_ref.shape
-        hd = q.shape[-1]
-        qg = q.reshape(Hkv, G, hd)
-        s = jnp.einsum("hgd,phd->hgp", qg, k,
-                       preferred_element_type=jnp.float32) * scale
-        if softcap > 0:
-            s = softcap * jnp.tanh(s / softcap)
-        k_pos = base + jax.lax.broadcasted_iota(jnp.int32, (1, 1, ps), 2)
-        msk = k_pos <= t
-        msk = jnp.logical_and(msk, jnp.where(w > 0, k_pos > t - w, True))
-        s = jnp.where(msk, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        p = jnp.exp(s - m_new[..., None])
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + p.sum(axis=-1)
-        acc_ref[...] = acc_ref[...] * corr[..., None] + jnp.einsum(
-            "hgp,phd->hgd", p.astype(v.dtype), v,
-            preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+        R, C = q_ref.shape[0], ps * hkv
+        head = jax.lax.broadcasted_iota(jnp.int32, (R, 1), 0) // group
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, C), 1)
+        k_pos = base + col // hkv
+        ok = (col % hkv == head) & (k_pos <= t) & (k_pos > t - span)
+        _attend_page(q_ref[...], k_ref, v_ref, ks_ref, vs_ref, head, ok,
+                     m_ref, l_ref, acc_ref, scale=scale, softcap=softcap)
 
     @pl.when(j == num_pages - 1)
     def _done():
-        l = jnp.maximum(l_ref[...], 1e-20)
-        out = acc_ref[...] / l[..., None]               # [Hkv, G, hd]
-        o_ref[0] = out.reshape(o_ref.shape[1], o_ref.shape[2])
+        _finish(o_ref, l_ref, acc_ref)
 
 
 def paged_attn_decode(q, k_pages, v_pages, block_table, t, *, window=0,
@@ -156,7 +217,7 @@ def paged_attn_decode(q, k_pages, v_pages, block_table, t, *, window=0,
     int32; t scalar or [B] int32 (current position per row); window a
     traced int32 scalar (0 = global). `k_scales`/`v_scales` [NP, Hkv] f32
     mark a QUANTIZED pool (int8 pages): the kernel gathers each page's
-    scales alongside it and dequantizes in-VMEM. Returns fp32 [B, Hq, hd]
+    scales alongside it and dequantizes in-kernel. Returns fp32 [B, Hq, hd]
     — the pre-`wo` attention output, matching _decode_sdpa's epilogue
     dtype."""
     if interpret is None:
@@ -181,115 +242,92 @@ def _paged_attn_decode(q, k_pages, v_pages, block_table, t_vec, window,
     B, Hq, hd = q.shape
     NP, ps, Hkv, _ = k_pages.shape
     P = block_table.shape[1]
-    G = Hq // Hkv
     quant = k_scales is not None
-    scale = 1.0 / (hd ** 0.5)
     bt = block_table.astype(jnp.int32)
     tv = t_vec.astype(jnp.int32)
     wv = window.astype(jnp.int32).reshape(1)
-    ops = [q, k_pages, v_pages, bt, tv, wv]
-    if quant:
-        ops += [k_scales.astype(jnp.float32), v_scales.astype(jnp.float32)]
-    ops = replicate_for_gspmd(*ops)
-    q, k_pages, v_pages, bt, tv, wv = ops[:6]
 
-    page_spec = pl.BlockSpec((1, ps, Hkv, hd),
-                             lambda b, j, bt, tv, wv: (bt[b, j], 0, 0, 0))
-    in_specs = [
-        pl.BlockSpec((1, Hq, hd), lambda b, j, bt, tv, wv: (b, 0, 0)),
-        page_spec, page_spec,
-    ]
-    if quant:
-        scale_spec = pl.BlockSpec((1, Hkv),
-                                  lambda b, j, bt, tv, wv: (bt[b, j], 0))
-        in_specs += [scale_spec, scale_spec]
+    def page_index(b, j, bt, tv, wv):
+        # pages past the row's position repeat its last live page, so the
+        # pipeline skips their copies (their compute is skipped in-kernel)
+        return bt[b, jnp.minimum(j, tv[b] // ps)]
+
+    row_spec = pl.BlockSpec((pl.squeezed, Hq, hd),
+                            lambda b, j, bt, tv, wv: (b, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(B, P),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, Hq, hd), lambda b, j, bt, tv, wv: (b, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((Hkv, G), jnp.float32),
-                        pltpu.VMEM((Hkv, G), jnp.float32),
-                        pltpu.VMEM((Hkv, G, hd), jnp.float32)],
+        in_specs=[row_spec] + _page_specs(ps, Hkv, hd, quant, page_index),
+        out_specs=row_spec,
+        scratch_shapes=[pltpu.VMEM((Hq, 1), jnp.float32),
+                        pltpu.VMEM((Hq, 1), jnp.float32),
+                        pltpu.VMEM((Hq, hd), jnp.float32)],
     )
-    return pl.pallas_call(
-        functools.partial(_decode_kernel, ps=ps, num_pages=P,
-                          softcap=softcap, scale=scale, quant=quant),
+    return mosaic_call(pl.pallas_call(
+        functools.partial(_decode_kernel, ps=ps, hkv=Hkv, group=Hq // Hkv,
+                          num_pages=P, softcap=softcap,
+                          scale=1.0 / (hd ** 0.5), quant=quant),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hq, hd), jnp.float32),
         interpret=interpret,
-    )(bt, tv, wv, q, k_pages, v_pages, *ops[6:])
+    ), bt, tv, wv, q, *_page_operands(k_pages, v_pages, k_scales, v_scales))
 
 
 # -------------------------------------------------------------- chunk prefill
 
+SCORE_BUDGET = 1 << 18  # f32 elements of one chunk step's [rows, ps*Hkv] scores
+
+
+def query_tile(cs: int, heads: int, cols: int) -> int:
+    """Query positions per chunk-kernel step: the largest divisor of the
+    chunk `cs` whose [tq*heads, cols] score block fits SCORE_BUDGET and whose
+    row count is a multiple of 8 (the TPU block tiling) or the whole chunk."""
+    fits = [tq for tq in range(cs, 0, -1)
+            if cs % tq == 0 and (tq == cs or tq * heads % 8 == 0)]
+    return next((tq for tq in fits if tq * heads * cols <= SCORE_BUDGET),
+                fits[-1])
+
+
 def _chunk_kernel(bt_ref, sv_ref, kl_ref, wv_ref, q_ref, k_ref, v_ref, *rest,
-                  ps: int, num_pages: int, softcap: float, scale: float,
+                  ps: int, hkv: int, heads: int, group: int, tq: int,
+                  num_pages: int, softcap: float, scale: float,
                   quant: bool = False):
-    """Grid (b, j): one [Cs]-query chunk per batch row against the row's
-    pages. Scalar-prefetched: block table [B, P], start [1], kv_len [1],
-    window [1]. `quant` adds per-page scale operands exactly as in
-    _decode_kernel."""
-    if quant:
-        ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
-    else:
-        o_ref, m_ref, l_ref, acc_ref = rest
-    b, j = pl.program_id(0), pl.program_id(1)
-    start = sv_ref[0]
+    """Grid (b, i, j): query tile i (tq chunk positions x Hq heads) of batch
+    row b against the row's page j. Scalar-prefetched: block table [B, P],
+    start [1], kv_len [1], window [1]. `quant` adds per-page scale operands
+    exactly as in _decode_kernel."""
+    ks_ref, vs_ref = rest[:2] if quant else (None, None)
+    o_ref, m_ref, l_ref, acc_ref = rest[-4:]
+    i, j = pl.program_id(1), pl.program_id(2)
+    q0 = sv_ref[0] + i * tq                 # the tile's first query position
     kvl = kl_ref[0]
-    w = wv_ref[0]
+    span = _span(wv_ref[0])
 
     @pl.when(j == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref[...], NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref[...])
-        acc_ref[...] = jnp.zeros_like(acc_ref[...])
+        _reset(m_ref, l_ref, acc_ref)
 
     base = j * ps
-    # queries sit at start..start+Cs-1 < kv_len; a page is live iff it can
-    # hold a key some query attends: k_pos < kv_len and (window) k_pos
-    # reaches past the EARLIEST query's window start.
-    live = base < kvl
-    live = jnp.logical_and(
-        live, jnp.where(w > 0, base + ps - 1 > start - w, True))
-
-    @pl.when(live)
+    # a page is live iff it can hold a key some query of the tile attends:
+    # k_pos < kv_len, k_pos <= the LAST query (causal) and (window) k_pos
+    # reaching past the EARLIEST query's window start.
+    @pl.when((base < kvl) & (base <= q0 + tq - 1)
+             & (base + ps - 1 > q0 - span))
     def _attend():
-        q = q_ref[0]                                   # [Cs, Hq, hd]
-        k = k_ref[0]                                   # [ps, Hkv, hd]
-        v = v_ref[0]
-        if quant:
-            k = k.astype(jnp.float32) * ks_ref[0][None, :, None]
-            v = v.astype(jnp.float32) * vs_ref[0][None, :, None]
-        Cs, Hkv, G = m_ref.shape
-        hd = q.shape[-1]
-        qg = q.reshape(Cs, Hkv, G, hd)
-        s = jnp.einsum("chgd,phd->chgp", qg, k,
-                       preferred_element_type=jnp.float32) * scale
-        if softcap > 0:
-            s = softcap * jnp.tanh(s / softcap)
-        k_pos = base + jax.lax.broadcasted_iota(
-            jnp.int32, (1, 1, 1, ps), 3)               # [1,1,1,ps]
-        q_pos = start + jax.lax.broadcasted_iota(
-            jnp.int32, (Cs, 1, 1, 1), 0)               # [Cs,1,1,1]
-        msk = jnp.logical_and(k_pos < kvl, k_pos <= q_pos)
-        msk = jnp.logical_and(msk, jnp.where(w > 0, k_pos > q_pos - w, True))
-        s = jnp.where(msk, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        p = jnp.exp(s - m_new[..., None])
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + p.sum(axis=-1)
-        acc_ref[...] = acc_ref[...] * corr[..., None] + jnp.einsum(
-            "chgp,phd->chgd", p.astype(v.dtype), v,
-            preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+        R, C = q_ref.shape[0], ps * hkv
+        row = jax.lax.broadcasted_iota(jnp.int32, (R, 1), 0)
+        head = row % heads // group
+        q_pos = q0 + row // heads
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, C), 1)
+        k_pos = base + col // hkv
+        ok = ((col % hkv == head) & (k_pos < kvl) & (k_pos <= q_pos)
+              & (k_pos > q_pos - span))
+        _attend_page(q_ref[...], k_ref, v_ref, ks_ref, vs_ref, head, ok,
+                     m_ref, l_ref, acc_ref, scale=scale, softcap=softcap)
 
     @pl.when(j == num_pages - 1)
     def _done():
-        l = jnp.maximum(l_ref[...], 1e-20)
-        out = acc_ref[...] / l[..., None]               # [Cs, Hkv, G, hd]
-        o_ref[0] = out.reshape(o_ref.shape[1], o_ref.shape[2], o_ref.shape[3])
+        _finish(o_ref, l_ref, acc_ref)
 
 
 def paged_attn_chunk(q, k_pages, v_pages, block_table, start, kv_len, *,
@@ -327,47 +365,40 @@ def _paged_attn_chunk(q, k_pages, v_pages, block_table, start, kv_len,
     B, Cs, Hq, hd = q.shape
     NP, ps, Hkv, _ = k_pages.shape
     P = block_table.shape[1]
-    G = Hq // Hkv
     quant = k_scales is not None
-    scale = 1.0 / (hd ** 0.5)
+    tq = query_tile(Cs, Hq, ps * Hkv)
     bt = block_table.astype(jnp.int32)
     sv = start.astype(jnp.int32).reshape(1)
     kl = kv_len.astype(jnp.int32).reshape(1)
     wv = window.astype(jnp.int32).reshape(1)
-    ops = [q, k_pages, v_pages, bt, sv, kl, wv]
-    if quant:
-        ops += [k_scales.astype(jnp.float32), v_scales.astype(jnp.float32)]
-    ops = replicate_for_gspmd(*ops)
-    q, k_pages, v_pages, bt, sv, kl, wv = ops[:7]
 
-    page_spec = pl.BlockSpec((1, ps, Hkv, hd),
-                             lambda b, j, bt, sv, kl, wv: (bt[b, j], 0, 0, 0))
-    in_specs = [
-        pl.BlockSpec((1, Cs, Hq, hd),
-                     lambda b, j, bt, sv, kl, wv: (b, 0, 0, 0)),
-        page_spec, page_spec,
-    ]
-    if quant:
-        scale_spec = pl.BlockSpec((1, Hkv),
-                                  lambda b, j, bt, sv, kl, wv: (bt[b, j], 0))
-        in_specs += [scale_spec, scale_spec]
+    def page_index(b, i, j, bt, sv, kl, wv):
+        # pages past the tile's last attendable key repeat that key's page
+        last = jnp.minimum(kl[0], sv[0] + (i + 1) * tq) - 1
+        return bt[b, jnp.clip(j, 0, jnp.maximum(last, 0) // ps)]
+
+    rows_spec = pl.BlockSpec((pl.squeezed, tq * Hq, hd),
+                             lambda b, i, j, bt, sv, kl, wv: (b, i, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(B, P),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, Cs, Hq, hd),
-                               lambda b, j, bt, sv, kl, wv: (b, 0, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((Cs, Hkv, G), jnp.float32),
-                        pltpu.VMEM((Cs, Hkv, G), jnp.float32),
-                        pltpu.VMEM((Cs, Hkv, G, hd), jnp.float32)],
+        grid=(B, Cs // tq, P),
+        in_specs=[rows_spec] + _page_specs(ps, Hkv, hd, quant, page_index),
+        out_specs=rows_spec,
+        scratch_shapes=[pltpu.VMEM((tq * Hq, 1), jnp.float32),
+                        pltpu.VMEM((tq * Hq, 1), jnp.float32),
+                        pltpu.VMEM((tq * Hq, hd), jnp.float32)],
     )
-    return pl.pallas_call(
-        functools.partial(_chunk_kernel, ps=ps, num_pages=P,
-                          softcap=softcap, scale=scale, quant=quant),
+    out = mosaic_call(pl.pallas_call(
+        functools.partial(_chunk_kernel, ps=ps, hkv=Hkv, heads=Hq,
+                          group=Hq // Hkv, tq=tq, num_pages=P,
+                          softcap=softcap, scale=1.0 / (hd ** 0.5),
+                          quant=quant),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Cs, Hq, hd), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((B, Cs * Hq, hd), jnp.float32),
         interpret=interpret,
-    )(bt, sv, kl, wv, q, k_pages, v_pages, *ops[7:])
+    ), bt, sv, kl, wv, q.reshape(B, Cs * Hq, hd),
+        *_page_operands(k_pages, v_pages, k_scales, v_scales))
+    return out.reshape(B, Cs, Hq, hd)
 
 
 # ------------------------------------------------------------ traffic model

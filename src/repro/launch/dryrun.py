@@ -101,7 +101,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool = False,
     mesh_tag = "2pod" if multi_pod else "1pod"
     mesh = make_production_mesh(multi_pod=multi_pod)
     t0 = time.time()
-    with mesh:
+    with jax.set_mesh(mesh):
         specs, in_sh, fn, order = _sharded_specs(
             cfg, shape, mesh, policy=policy, micro_global=micro_global)
         args = [specs[k] for k in order]

@@ -162,6 +162,38 @@ def serve_continuous(params, cfg, prompts: list, gen_tokens: int, *,
     }
 
 
+def init_params(key, cfg, mesh=None) -> dict:
+    """Random weights from `key`, made on the device(s) that will hold
+    them: with a mesh, each leaf is created already sharded by the
+    serving rules (launch/sharding.py, experts over "model"), so a model
+    that only fits across chips never lands whole on chip 0."""
+    if mesh is None:
+        return jax.jit(model_init, static_argnums=1)(key, cfg)
+    from repro.launch.sharding import param_shardings
+    shapes = jax.eval_shape(lambda k: model_init(k, cfg), key)
+    out = param_shardings(shapes, cfg, mesh, mode="serve")
+    return jax.jit(model_init, static_argnums=1, out_shardings=out)(key, cfg)
+
+
+def _supervise(args) -> None:
+    """The --supervise parent: re-exec this CLI (minus --supervise) as a
+    watched child. The child journals; a restarted generation finds the
+    committed snapshot in --journal-dir and recovers instead of starting
+    fresh. The parent only watches, so it never initialises a JAX backend:
+    a parent that did would hold the accelerator its child needs."""
+    from repro.runtime.fault import ProcessSupervisor
+    os.makedirs(args.journal_dir, exist_ok=True)
+    child = [sys.executable, "-m", "repro.launch.serve"] + \
+        [a for a in sys.argv[1:] if a != "--supervise"]
+    sup = ProcessSupervisor(
+        child, heartbeat_file=os.path.join(args.journal_dir, "heartbeat"))
+    code = sup.run()
+    print(f"supervised serve exited {code} after "
+          f"{sup.stats.restarts} restart(s), "
+          f"{sup.stats.heartbeat_kills} heartbeat kill(s) "
+          f"(exit codes {sup.stats.exit_codes})")
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
@@ -271,30 +303,23 @@ def main():
                  "generations re-dispatch through recover())")
 
     if args.supervise:
-        # parent: re-exec this CLI (minus --supervise) as a watched child.
-        # The child journals; a restarted generation finds the committed
-        # snapshot in --journal-dir and recovers instead of starting fresh.
-        from repro.runtime.fault import ProcessSupervisor
-        os.makedirs(args.journal_dir, exist_ok=True)
-        child = [sys.executable, "-m", "repro.launch.serve"] + \
-            [a for a in sys.argv[1:] if a != "--supervise"]
-        sup = ProcessSupervisor(
-            child,
-            heartbeat_file=os.path.join(args.journal_dir, "heartbeat"))
-        code = sup.run()
-        print(f"supervised serve exited {code} after "
-              f"{sup.stats.restarts} restart(s), "
-              f"{sup.stats.heartbeat_kills} heartbeat kill(s) "
-              f"(exit codes {sup.stats.exit_codes})")
+        # before anything that could touch a device: the parent only watches
+        _supervise(args)
         return
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     cfg = get_config(args.arch, smoke=args.smoke)
     if args.backend is not None and cfg.moe is not None:
         import dataclasses
         cfg = cfg.with_overrides(
             moe=dataclasses.replace(cfg.moe, backend=args.backend))
+    mesh = None
+    if args.mesh_model:
+        from repro.launch.mesh import make_smoke_mesh
+        mesh = make_smoke_mesh(model=args.mesh_model)
     key = jax.random.PRNGKey(0)
-    params = model_init(key, cfg)
+    params = init_params(key, cfg, mesh)
     extras = {}
     if cfg.cross_attn_every:
         extras["image_embeds"] = extras["memory"] = jnp.zeros(
@@ -309,11 +334,6 @@ def main():
               f"({res['tok_per_s']:.1f} tok/s)")
         print("sample:", np.asarray(res["tokens"][0])[:16])
         return
-
-    mesh = None
-    if args.mesh_model:
-        from repro.launch.mesh import make_smoke_mesh
-        mesh = make_smoke_mesh(model=args.mesh_model)
 
     rng = np.random.default_rng(0)
     prompts = [rng.integers(0, cfg.vocab_size, size=args.prompt, dtype=np.int32)

@@ -3,9 +3,8 @@
 Pure-JAX XLA path used by training / prefill / the multi-pod dry-run. The
 double-chunked online-softmax scan bounds the materialized score block to
 [B, H, cq, ck] regardless of GSPMD propagation, which is what lets the 32k
-prefill cells fit HBM. (On real TPU the Pallas flash kernel would replace the
-inner loop; Pallas cannot be *lowered* for TPU from this CPU-only container,
-so the XLA path is the dry-run/compile path.)
+prefill cells fit HBM. (On real TPU a Pallas flash kernel would replace the
+inner loop; the XLA path is what the dry-run compiles.)
 
 Supports: GQA (num_kv_heads < num_heads), RoPE, causal / sliding-window /
 bidirectional / cross masks, QKV bias (qwen2), logit softcap, single-token

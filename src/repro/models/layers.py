@@ -18,10 +18,10 @@ def dtype_of(cfg) -> jnp.dtype:
 # ------------------------------------------------------------ sharding hints
 
 def current_mesh():
-    """The Mesh from an enclosing `with mesh:` context, or None."""
-    from jax._src import mesh as mesh_lib
-    m = mesh_lib.thread_resources.env.physical_mesh
-    return None if m is None or m.empty else m
+    """The (abstract) mesh set by an enclosing `jax.set_mesh(mesh)`, or
+    None. Readable inside jit traces and shard_map bodies."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m
 
 
 def mesh_axis(name: str) -> int:
@@ -45,7 +45,7 @@ def shard_hint(x, *spec):
     m = current_mesh()
     if m is None:
         return x
-    from jax.sharding import NamedSharding, PartitionSpec
+    from jax.sharding import PartitionSpec
     names = set(m.axis_names)
 
     def clean(e):
@@ -56,7 +56,7 @@ def shard_hint(x, *spec):
         return e if e in names else None
 
     return jax.lax.with_sharding_constraint(
-        x, NamedSharding(m, PartitionSpec(*[clean(e) for e in spec])))
+        x, PartitionSpec(*[clean(e) for e in spec]))
 
 
 # ---------------------------------------------------------------- init utils

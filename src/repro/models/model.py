@@ -54,7 +54,7 @@ from repro.core.go_cache import (GOCache, go_cache_init, go_cache_init_slot,
 from repro.core.grouping import default_groups, group_of_expert_from_groups
 from repro.models import attention as ATT
 from repro.models import blocks as B
-from repro.models.layers import (dense_init, embed_init, rmsnorm,
+from repro.models.layers import (dense_init, dtype_of, embed_init, rmsnorm,
                                  rmsnorm_init, split, stack_init)
 from repro.models.ssm import mamba2_init_state
 from repro.models.xlstm import mlstm_init_state, slstm_init_state
@@ -79,24 +79,25 @@ def layer_windows(cfg) -> np.ndarray:
 def _moe_deployment(moe_cfg):
     """Deployment-time C2 artifacts, computed ONCE per MoE config (host-side
     numpy) instead of inside every traced forward: the [E] group-id map and
-    the [G, g] member matrix the group-multiplexed paths consume."""
+    the [G, g] member matrix the group-multiplexed paths consume. Cached as
+    numpy: a jax array made while a jit traces is that trace's tracer."""
     groups = default_groups(moe_cfg)
-    return (jnp.asarray(group_of_expert_from_groups(groups), jnp.int32),
-            jnp.asarray(groups, jnp.int32))
+    return (np.asarray(group_of_expert_from_groups(groups), np.int32),
+            np.asarray(groups, np.int32))
 
 
 def expert_groups(cfg) -> jax.Array | None:
     """C2 grouping -> [E] group id per expert (None for non-MoE)."""
     if cfg.moe is None:
         return None
-    return _moe_deployment(cfg.moe)[0]
+    return jnp.asarray(_moe_deployment(cfg.moe)[0])
 
 
 def expert_group_members(cfg) -> jax.Array | None:
     """C2 grouping -> [G, g] expert ids per group (None for non-MoE)."""
     if cfg.moe is None:
         return None
-    return _moe_deployment(cfg.moe)[1]
+    return jnp.asarray(_moe_deployment(cfg.moe)[1])
 
 
 def _maybe_remat(fn, cfg):
@@ -188,10 +189,16 @@ def _dec_self_init_fn(cfg):
 
 # -------------------------------------------------------------------- forward
 
+def embed_tokens(params: dict, tokens: jax.Array, cfg) -> jax.Array:
+    """Token embeddings as activations in cfg.dtype (the weights may be
+    stored narrower, e.g. bf16 weights evaluated in f32)."""
+    return params["embed"][tokens].astype(dtype_of(cfg))
+
+
 def model_forward(params: dict, tokens: jax.Array, cfg, extras: dict | None = None):
     """tokens [B, S] -> (x_final [B, S, d] normalized, aux_balance_loss)."""
     extras = extras or {}
-    x = params["embed"][tokens]
+    x = embed_tokens(params, tokens, cfg)
     if cfg.block == "attn" and cfg.encoder_layers > 0:
         return _fwd_whisper(params, x, cfg, extras)
     S = tokens.shape[1]
@@ -617,7 +624,7 @@ def write_decode_slot(state: dict, slot, src: dict, page_ids=None) -> dict:
 
 def serve_step(params: dict, state: dict, tokens_t: jax.Array, cfg):
     """One decode step. tokens_t [B] int32 -> (logits [B, V] fp32, state)."""
-    x = params["embed"][tokens_t][:, None, :]            # [B, 1, d]
+    x = embed_tokens(params, tokens_t, cfg)[:, None, :]  # [B, 1, d]
     t = state["t"]
 
     if cfg.block == "attn" and cfg.encoder_layers > 0:
@@ -856,7 +863,7 @@ def prefill(params: dict, tokens: jax.Array, cfg, extras: dict | None = None,
     windows = jnp.asarray(layer_windows(cfg))
     goe = expert_groups(cfg)
     gm = expert_group_members(cfg)
-    x = params["embed"][tokens]
+    x = embed_tokens(params, tokens, cfg)
     has_go = "go" in state
 
     def body(x, xs):
@@ -936,7 +943,7 @@ def prefill_chunk(params: dict, state: dict, tokens: jax.Array, cfg,
     windows = jnp.asarray(layer_windows(cfg))
     goe = expert_groups(cfg)
     gm = expert_group_members(cfg)
-    x = params["embed"][tokens]
+    x = embed_tokens(params, tokens, cfg)
     has_go = "go" in state
     paged = "block_table" in state
     qkv = paged and "k_scales" in state

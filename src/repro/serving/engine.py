@@ -83,6 +83,7 @@ the CI lane).
 """
 from __future__ import annotations
 
+import contextlib
 import heapq
 import itertools
 import math
@@ -480,13 +481,9 @@ class ServingEngine:
                     f"pages of {self.pool.page_size} tokens, but the pool "
                     f"only has {usable} usable pages")
         if self.expert_aware:
-            if self.mesh is None:
+            with self._mesh_scope():
                 req.expert_sig = expert_signature(
                     self.params, req.prompt, self.cfg)
-            else:
-                with self.mesh:
-                    req.expert_sig = expert_signature(
-                        self.params, req.prompt, self.cfg)
         req.arrival_time = req.submit_time = time.monotonic()
         try:
             self.scheduler.submit(req, now_step=self.step_count)
@@ -843,6 +840,15 @@ class ServingEngine:
             return self._run_decode_step()
         return self.supervisor.run(tick, step=self.step_count)
 
+    def _mesh_scope(self):
+        """The engine's mesh as the ambient mesh (`jax.set_mesh`) while a
+        step over its sharded parameters traces and runs — a Mosaic kernel
+        lowers only under it (`kernels/moe_gmm.py::mosaic_call`); a no-op
+        when unsharded."""
+        if self.mesh is None:
+            return contextlib.nullcontext()
+        return jax.set_mesh(self.mesh)
+
     def _run_decode_step(self):
         """One jitted decode tick, inside the mesh context when sharded (the
         jit cache keys on the ambient mesh, so the sharded and unsharded
@@ -859,11 +865,8 @@ class ServingEngine:
                      jnp.asarray(self.pool.top_ps),
                      jnp.asarray(self.pool.keys))
         fn = _decode_step_sampled if sampling else _decode_step
-        if self.mesh is None:
+        with self._mesh_scope():
             out = fn(*args, self.cfg)
-        else:
-            with self.mesh:
-                out = fn(*args, self.cfg)
         if sampling:
             return out                       # (toks, state, ok, new_keys)
         toks, state, ok = out
@@ -903,10 +906,12 @@ class ServingEngine:
         prompt, valid_len = (self._bucketed(req.prompt) if self.prompt_buckets
                              else (req.prompt, None))
         self.prefill_lengths.add(int(prompt.shape[0]))
-        slot_state, logits = _jit_prefill(
-            self.params, jnp.asarray(prompt, jnp.int32)[None, :],
-            self.cfg, req.extras or {}, self.pool.max_tokens,
-            None if valid_len is None else jnp.asarray(valid_len, jnp.int32))
+        with self._mesh_scope():
+            slot_state, logits = _jit_prefill(
+                self.params, jnp.asarray(prompt, jnp.int32)[None, :],
+                self.cfg, req.extras or {}, self.pool.max_tokens,
+                None if valid_len is None
+                else jnp.asarray(valid_len, jnp.int32))
         self._install(slot, req, slot_state, logits, done)
 
     def _install(self, slot: int, req: Request, slot_state, logits,
@@ -1078,10 +1083,7 @@ class ServingEngine:
         args = (self.params, state, jnp.asarray(chunk, jnp.int32)[None, :],
                 self.cfg, jnp.asarray(start, jnp.int32),
                 jnp.asarray(rem, jnp.int32))
-        if self.mesh is not None:
-            with self.mesh:
-                state, logits = _jit_prefill_chunk(*args)
-        else:
+        with self._mesh_scope():
             state, logits = _jit_prefill_chunk(*args)
         self.pool.state["k_pages"] = state.pop("k_pages")
         self.pool.state["v_pages"] = state.pop("v_pages")
@@ -1197,10 +1199,7 @@ class ServingEngine:
         args = (self.params, job.state,
                 jnp.asarray(chunk, jnp.int32)[None, :], self.cfg,
                 jnp.asarray(job.pos, jnp.int32), jnp.asarray(valid, jnp.int32))
-        if paged and self.mesh is not None:
-            with self.mesh:
-                job.state, job.logits = _jit_prefill_chunk(*args)
-        else:
+        with self._mesh_scope():
             job.state, job.logits = _jit_prefill_chunk(*args)
         if paged:
             self.pool.state["k_pages"] = job.state.pop("k_pages")

@@ -6,22 +6,5 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-# Optional-hypothesis shim: property-test modules do
-# `from conftest import given, settings, st` so they collect (and their
-# non-property tests run) without the dev extra; @given tests skip.
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:
-    import pytest
-
-    class _NoStrategies:
-        def __getattr__(self, name):
-            return lambda *a, **k: None
-
-    st = _NoStrategies()
-
-    def settings(*a, **k):
-        return lambda fn: fn
-
-    def given(*a, **k):
-        return pytest.mark.skip(reason="hypothesis not installed")
+# property-test modules import these from here
+from hypothesis import given, settings, strategies as st  # noqa: E402,F401

@@ -344,6 +344,45 @@ def _serve_env():
     return env
 
 
+_RECORD_CHILD = """
+import sys
+from repro.runtime import fault
+
+class Recorder(fault.ProcessSupervisor):
+    def run(self):
+        print("CHILD", " ".join(self.cmd[1:]))
+        return 0
+
+fault.ProcessSupervisor = Recorder
+from repro.launch import serve
+sys.argv = ["serve"] + sys.argv[1:]
+serve.main()
+"""
+
+
+def test_supervise_parent_never_initialises_a_backend(tmp_path):
+    """The --supervise parent only watches its child, so it must reach the
+    child's launch without initialising a JAX backend: on an accelerator
+    host a parent that did would hold the device its child needs. Here
+    JAX_PLATFORMS names no real platform, so any backend initialisation
+    raises; the supervisor is replaced by a recorder of the child command.
+    The same CLI without --supervise fails on that trap."""
+    env = dict(_serve_env(), JAX_PLATFORMS="no-such-platform")
+    args = _serve_cmd(tmp_path / "jnl", "--supervise")[3:]
+    out = subprocess.run([sys.executable, "-c", _RECORD_CHILD, *args],
+                         env=env, capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    child = [ln for ln in out.stdout.splitlines() if ln.startswith("CHILD")]
+    assert child == ["CHILD -m repro.launch.serve " + " ".join(args[:-1])]
+    assert "supervised serve exited 0" in out.stdout
+
+    trap = subprocess.run([sys.executable, "-c", _RECORD_CHILD, *args[:-1]],
+                          env=env, capture_output=True, text=True,
+                          timeout=300)
+    assert trap.returncode != 0
+    assert "Unable to initialize backend" in trap.stderr
+
+
 @needs_crash_lane
 def test_sigkill_mid_decode_then_recover(tmp_path):
     """A real `kill -9` at a chaos-chosen decode tick: the child dies with

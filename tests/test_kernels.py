@@ -9,6 +9,7 @@ from conftest import given, settings, st
 from repro.kernels import ref
 from repro.kernels.go_topk import go_topk_update
 from repro.kernels.moe_gmm import gmm, gmm_swiglu
+from repro.launch.mesh import make_mesh
 
 SWEEP = [
     # (N, K, F, E, bn, dtype)
@@ -289,8 +290,8 @@ def test_default_interpret_resolves_from_lowering_context(monkeypatch):
     assert moe_gmm.default_interpret() is (jax.default_backend() != "tpu")
     monkeypatch.setattr(moe_gmm.jax, "default_backend", lambda: "tpu")
     assert moe_gmm.default_interpret() is False      # no mesh: host decides
-    mesh = jax.make_mesh((1, 1), ("data", "model"))  # CPU devices
-    with mesh:
+    mesh = make_mesh((1, 1), ("data", "model"))      # CPU devices
+    with jax.set_mesh(mesh):
         assert moe_gmm.default_interpret() is True   # mesh devices decide
     assert moe_gmm.default_interpret() is False      # context popped
 
@@ -299,7 +300,7 @@ def test_default_block_rows_follows_lowering_context(monkeypatch):
     from repro.kernels import moe_gmm, ops
     monkeypatch.setattr(moe_gmm.jax, "default_backend", lambda: "tpu")
     assert ops.default_block_rows() == 128
-    with jax.make_mesh((1, 1), ("data", "model")):
+    with jax.set_mesh(make_mesh((1, 1), ("data", "model"))):
         assert ops.default_block_rows() == 8         # CPU mesh: small tiles
 
 

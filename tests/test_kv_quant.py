@@ -35,6 +35,7 @@ import pytest
 from conftest import given, settings, st
 from repro.configs.registry import get_config
 from repro.core import quant as Q
+from repro.launch.mesh import make_mesh
 from repro.launch.serve import generate
 from repro.models.model import model_init
 from repro.serving import RequestStatus, ServingEngine
@@ -438,7 +439,7 @@ def test_quantized_engine_mesh_stream_parity(shape):
     kw = dict(num_slots=2, max_tokens=32, arrival_steps=[0, 1, 3],
               paged=True, page_size=8, kv_quant="int8")
     ref = serve_continuous(params, cfg, prompts, 5, **kw)
-    mesh = jax.make_mesh(shape, ("data", "model"))
+    mesh = make_mesh(shape, ("data", "model"))
     got = serve_continuous(params, cfg, prompts, 5, mesh=mesh, **kw)
     assert got["stats"]["kv_quant_dtype"] == "int8"
     for rid in ref["tokens"]:

@@ -22,6 +22,7 @@ import pytest
 from repro.configs.base import MoEConfig
 from repro.core import moe as MOE
 from repro.core.grouping import default_groups, group_of_expert_from_groups
+from repro.launch.mesh import make_mesh
 
 MULTI = jax.device_count() >= 4
 
@@ -33,7 +34,7 @@ MESHES = [(2, 2), (1, 4)]
 
 
 def _mesh(shape):
-    return jax.make_mesh(shape, ("data", "model"))
+    return make_mesh(shape, ("data", "model"))
 
 
 def _pallas(e: MoEConfig, **kw) -> MoEConfig:
@@ -59,7 +60,7 @@ def test_ep_token_choice_three_way_parity(setup, shape):
     ep = _pallas(e)
     y_uns = jnp.stack(
         [MOE.dispatch_forward(p, h[b], ep)[0] for b in range(h.shape[0])])
-    with _mesh(shape):
+    with jax.set_mesh(_mesh(shape)):
         y_x, a_x = jax.jit(lambda p, h: MOE.moe_forward_ep(p, h, e))(p, h)
         y_p, a_p = jax.jit(lambda p, h: MOE.moe_forward_ep(p, h, ep))(p, h)
     np.testing.assert_allclose(np.asarray(y_x), np.asarray(y_p),
@@ -79,7 +80,7 @@ def test_ep_capacity_drop_parity(setup, shape):
     (pallas realizes a drop as a zero combine weight) and agree on outputs."""
     e, p, h = setup
     et = dataclasses.replace(e, capacity_factor=0.5)
-    with _mesh(shape):
+    with jax.set_mesh(_mesh(shape)):
         y_x, a_x = jax.jit(lambda p, h: MOE.moe_forward_ep(p, h, et))(p, h)
         y_p, a_p = jax.jit(
             lambda p, h: MOE.moe_forward_ep(p, h, _pallas(et)))(p, h)
@@ -104,7 +105,7 @@ def test_group_forward_under_mesh_drop_parity(shape):
     goe = jnp.asarray(group_of_expert_from_groups(default_groups(e)))
     y_uns, a_uns = MOE.group_forward(p, x, _pallas(e), goe, pool_factor=0.7)
     mesh = _mesh(shape)
-    with mesh:
+    with jax.set_mesh(mesh):
         xs = jax.device_put(x, NamedSharding(mesh, P("data", None)))
         y_x, a_x = jax.jit(
             lambda p, x: MOE.group_forward(p, x, e, goe, pool_factor=0.7)
@@ -148,7 +149,7 @@ def test_go_decode_selected_under_mesh(shape):
         key, sub = jax.random.split(key)
         xt = jax.random.normal(sub, (B, d)) * 0.3
         r_u = step_s(cache_u, xt, t)                       # unsharded ref
-        with mesh:
+        with jax.set_mesh(mesh):
             xs = jax.device_put(xt, NamedSharding(mesh, P("data", None)))
             r_d = step_d(cache_d, xs, t)
             r_s = step_s(cache_s, xs, t)

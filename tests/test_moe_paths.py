@@ -269,8 +269,8 @@ key = jax.random.PRNGKey(0)
 p = MOE.moe_init(key, 64, e, jnp.float32)
 h = jax.random.normal(key, (4, 16, 64)) * 0.3
 y_ref = jnp.stack([MOE.dispatch_forward(p, h[b], e)[0] for b in range(4)])
-mesh = jax.make_mesh((2, 4), ("data", "model"))
-with mesh:
+from repro.launch.mesh import make_mesh
+with jax.set_mesh(make_mesh((2, 4), ("data", "model"))):
     y, aux = jax.jit(lambda p, h: MOE.moe_forward_ep(p, h, e))(p, h)
 assert float(jnp.max(jnp.abs(y - y_ref))) < 1e-4
 assert int(aux["counts"].sum()) == 4 * 16 * 2

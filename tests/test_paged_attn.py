@@ -31,6 +31,7 @@ import pytest
 
 from repro.configs.base import ModelConfig
 from repro.kernels import paged_attn as PA
+from repro.launch.mesh import make_mesh
 from repro.models import attention as ATT
 
 MULTI = jax.device_count() >= 4
@@ -45,7 +46,7 @@ P = 4           # logical pages per slot -> Smax = 32
 
 
 def _mesh(shape):
-    return jax.make_mesh(shape, ("data", "model"))
+    return make_mesh(shape, ("data", "model"))
 
 
 def _cfg(nkv=2, softcap=0.0, mode="auto"):
@@ -350,7 +351,7 @@ def test_kernel_mesh_decode_parity(shape):
     q = jnp.asarray(rng.normal(size=(B, cfg.num_heads, hd)), jnp.float32)
     t = jnp.asarray(RAGGED_T, jnp.int32)
     ref = PA.paged_attn_decode(q, kp, vp, bt, t, window=5)
-    with _mesh(shape):
+    with jax.set_mesh(_mesh(shape)):
         got = PA.paged_attn_decode(q, kp, vp, bt, t, window=5)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=1e-6, atol=1e-6)

@@ -161,6 +161,44 @@ def test_supervisor_straggler_flag():
     assert len(sup.stats.stragglers) == 1
 
 
+# ------------------------------------------------------------ compile cache
+
+_CACHE_PROBE = """
+import jax, jax.numpy as jnp
+from repro.launch.compile_cache import enable_compile_cache
+print(enable_compile_cache())
+print(jax.config.jax_compilation_cache_dir)
+jax.jit(lambda x: x * 2)(jnp.ones(3)).block_until_ready()
+"""
+
+
+@pytest.mark.parametrize("from_env", [True, False], ids=["env", "checkout"])
+def test_compile_cache_placement(tmp_path, from_env):
+    """An entry point's compile cache lives in JAX_COMPILATION_CACHE_DIR
+    when that is set (and compiles land there), otherwise at the fixed
+    `.jax_cache/` of the checkout. Run in a child: importing the helper
+    must not set anything in this test process."""
+    import subprocess
+    import sys
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    env = dict(os.environ, PYTHONPATH=src,
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if from_env:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path)
+        want = str(tmp_path)
+        probe = _CACHE_PROBE
+    else:
+        want = os.path.realpath(os.path.join(src, "..", ".jax_cache"))
+        probe = _CACHE_PROBE.rsplit("\n", 2)[0]     # nothing compiled
+    out = subprocess.run([sys.executable, "-c", probe], env=env,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.split() == [want, want]
+    if from_env:
+        assert any(p.name.startswith("jit_") for p in tmp_path.iterdir())
+
+
 # ------------------------------------------------------------------ elastic
 
 @pytest.mark.parametrize("n,expect", [
