@@ -71,27 +71,6 @@ def default_interpret() -> bool:
     return lowering_platform() != "tpu"
 
 
-def replicate_for_gspmd(*arrays):
-    """Pin arrays to a fully-replicated layout when tracing under a GSPMD
-    mesh (`jax.set_mesh(mesh)` + jit).
-
-    The grouped-GEMM pipeline — the planner's small scatter/gather chains
-    feeding a pallas_call — has no SPMD partitioning rule; letting the
-    partitioner guess per-op shardings for it is slow (collective chatter on
-    [N]-sized index vectors) and, for the interpret-mode lowering, produces
-    WRONG results on CPU host meshes (sharding-dependent gather/scatter
-    miscompiles — caught by tests/test_moe_mesh.py). Pinning the branch's
-    inputs replicated keeps every downstream op replicated, which matches
-    the unsharded numerics exactly.
-
-    Inside a shard_map body (every mesh axis manual — the EP path, where
-    data is already shard-local) the arrays pass through untouched."""
-    if _gspmd_mesh() is not None:
-        arrays = tuple(jax.lax.with_sharding_constraint(a, PartitionSpec())
-                       for a in arrays)
-    return arrays if len(arrays) > 1 else arrays[0]
-
-
 def _gspmd_mesh():
     """The ambient mesh while GSPMD partitions the traced program (a mesh
     with automatic axes), or None: no mesh, or a shard_map body."""

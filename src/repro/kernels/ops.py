@@ -50,8 +50,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.moe_gmm import (default_interpret, gmm, gmm_scaled,
-                                   gmm_swiglu, lowering_platform,
-                                   replicate_for_gspmd)
+                                   gmm_swiglu, lowering_platform)
 
 
 def default_block_rows() -> int:
@@ -355,9 +354,6 @@ def moe_ffn_fused(x_src: jax.Array, tok: jax.Array, ef: jax.Array,
     (gmm_scaled) and rows are scatter-added directly into the token buffer.
     """
     bn = bn or default_block_rows()
-    # under a GSPMD mesh the whole branch computes replicated (see
-    # replicate_for_gspmd; a no-op in the EP shard_map body)
-    x_src, tok, ef, wf = replicate_for_gspmd(x_src, tok, ef, wf)
     plan = plan_tile_dispatch(ef, num_experts, bn, expert_offset=expert_offset,
                               num_local=num_local, fuse=fuse)
     if capacity:

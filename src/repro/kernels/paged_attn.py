@@ -84,8 +84,10 @@ def resolve_mode(cfg) -> str:
 # queries as [R, hd], one row per (query position, q head). One 2-D matmul
 # scores every query row against every page row; `ok` keeps only the rows of
 # the query's own kv head (GQA grouping) at attendable positions, so masked
-# rows leave exactly 0 in the softmax. The matmul does Hkv x the useful
-# FLOPs, which the MXU absorbs: decode attention is bound by page bytes.
+# rows leave exactly 0 in the softmax. The matmuls, exp and masking do Hkv x
+# the useful work (32x for an MHA model like llama_moe_4_16). Decode absorbs
+# it, being bound by page bytes; chunked prefill is compute-bound and pays
+# it in full.
 
 NO_WINDOW = 1 << 30     # a window span no position reaches
 

@@ -29,11 +29,11 @@ def on_kernels(cfg):
 @pytest.mark.parametrize("arch", ["granite-moe-3b-a800m", "llama_moe_4_16"])
 def test_serving_phase(monkeypatch, capsys, arch):
     """Engine on the paged pool with chunked prefill, every request DONE
-    with no tick retries, and teacher-forced logits within the phase's
-    parity limit of the plain path."""
+    with no tick retries, and teacher-forced logits (one one-shot and one
+    chunked prefill) within the phase's parity limit of the plain path."""
     monkeypatch.setattr(CS, "check_chip_paths", lambda cfg, eng: None)
     CS.serving_phase(arch, on_kernels(get_config(arch, smoke=True)),
-                     lengths=CS.LENGTHS, gen=8, seed=0,
+                     lengths=CS.LENGTHS, gen=8, seed=CS.SEED,
                      clock=CS.CompileClock())
     out = capsys.readouterr().out
     assert "statuses {'DONE': 8}, tick retries 0" in out
@@ -49,7 +49,7 @@ from test_chip_smoke import on_kernels
 get = CS.get_config
 CS.get_config = lambda name: on_kernels(get(name, smoke=True))
 CS.check_chip_paths = lambda cfg, eng: None
-CS.sharded_phase(seed=0, clock=CS.CompileClock())
+CS.sharded_phase(clock=CS.CompileClock())
 """
 
 
