@@ -132,9 +132,10 @@ def _shared_out(params: dict, x: jax.Array) -> jax.Array:
     if "shared" not in params:
         return jnp.zeros_like(x)
     sh = params["shared"]
-    h = jax.nn.silu(jnp.einsum("td,sdf->stf", x, sh["wg"])) * jnp.einsum(
-        "td,sdf->stf", x, sh["wi"])
-    return jnp.einsum("stf,sfd->td", h, sh["wo"]).astype(x.dtype)
+    with jax.named_scope("shared"):
+        h = jax.nn.silu(jnp.einsum("td,sdf->stf", x, sh["wg"])) * \
+            jnp.einsum("td,sdf->stf", x, sh["wi"])
+        return jnp.einsum("stf,sfd->td", h, sh["wo"]).astype(x.dtype)
 
 
 def expert_ffn_all(params: dict, x: jax.Array) -> jax.Array:
@@ -231,13 +232,18 @@ def dispatch_forward(params: dict, x: jax.Array, e: MoEConfig,
     T = x.shape[0]
     E, k = e.num_experts, e.top_k
     C = capacity or max(1, int(math.ceil(T * k / E * e.capacity_factor)))
-    r = R.token_choice(x, params["gate"], k)
+    with jax.named_scope("router"):
+        r = R.token_choice(x, params["gate"], k)
     expert_flat = r.expert_idx.reshape(-1).astype(jnp.int32)
     weights_flat = r.weights.reshape(-1)
     token_flat = jnp.repeat(jnp.arange(T, dtype=jnp.int32), k)
-    plan = _plan_dispatch(x, expert_flat, weights_flat, token_flat, E, C)
-    y_disp = _expert_gemm(params["experts"], plan.x_disp)
-    y = _combine(y_disp, plan, T, x.dtype) + _shared_out(params, x)
+    with jax.named_scope("dispatch"):
+        plan = _plan_dispatch(x, expert_flat, weights_flat, token_flat, E, C)
+    with jax.named_scope("experts"):
+        y_disp = _expert_gemm(params["experts"], plan.x_disp)
+    shared = _shared_out(params, x)
+    with jax.named_scope("combine"):
+        y = _combine(y_disp, plan, T, x.dtype) + shared
     aux = {
         "counts": plan.counts,
         "balance_loss": R.load_balance_loss(r.scores, r.expert_idx, E),
@@ -250,13 +256,16 @@ def _dispatch_forward_pallas(params: dict, x: jax.Array, e: MoEConfig) -> tuple:
     """Token-choice through the tile-dispatch grouped GEMM (dropless)."""
     T = x.shape[0]
     E, k = e.num_experts, e.top_k
-    r = R.token_choice(x, params["gate"], k)
+    with jax.named_scope("router"):
+        r = R.token_choice(x, params["gate"], k)
     ef = r.expert_idx.reshape(-1).astype(jnp.int32)
     wf = r.weights.reshape(-1)
     tok = jnp.repeat(jnp.arange(T, dtype=jnp.int32), k)
     y, _, plan = OPS.moe_ffn_fused(x, tok, ef, wf, params["experts"], E, T,
                                    bn=_block_rows(e))
-    y = y.astype(x.dtype) + _shared_out(params, x)
+    shared = _shared_out(params, x)
+    with jax.named_scope("combine"):
+        y = y.astype(x.dtype) + shared
     aux = {
         "counts": plan.counts,
         "balance_loss": R.load_balance_loss(r.scores, r.expert_idx, E),

@@ -354,35 +354,39 @@ def moe_ffn_fused(x_src: jax.Array, tok: jax.Array, ef: jax.Array,
     (gmm_scaled) and rows are scatter-added directly into the token buffer.
     """
     bn = bn or default_block_rows()
-    plan = plan_tile_dispatch(ef, num_experts, bn, expert_offset=expert_offset,
-                              num_local=num_local, fuse=fuse)
-    if capacity:
-        wf = jnp.where(plan.pos < capacity, wf, 0.0)
-    te = (plan.tile_expert if expert_of_lane is None
-          else expert_of_lane[plan.tile_expert])
-    te2 = (plan.tile_expert2 if expert_of_lane is None
-           else expert_of_lane[plan.tile_expert2])
     fused = fuse is not None
-    N = ef.shape[0]
-    # one gather per operand through the plan's row_pair map (sentinel N ->
-    # the appended zero/sink entry)
-    tok_z = jnp.concatenate(
-        [tok.astype(jnp.int32), jnp.full((1,), num_tokens, jnp.int32)])
-    row_token = tok_z[plan.row_pair]
-    x_z = jnp.concatenate([x_src, jnp.zeros((1, x_src.shape[-1]), x_src.dtype)])
-    x_rows = x_z[row_token]
-    wf_z = jnp.concatenate([wf.astype(jnp.float32), jnp.zeros((1,))])
-    scale = wf_z[plan.row_pair][:, None]
-    h = gmm_swiglu(x_rows, bank["wg"], bank["wi"], te, plan.tile_valid,
-                   tile_expert2=te2 if fused else None,
-                   row_sel=plan.row_sel if fused else None,
-                   bn=bn, interpret=interpret)
-    y_rows = gmm_scaled(h, bank["wo"], te, plan.tile_valid, scale,
-                        tile_expert2=te2 if fused else None,
-                        row_sel=plan.row_sel if fused else None,
-                        bn=bn, interpret=interpret)
-    y = jnp.zeros((num_tokens, x_src.shape[-1]), jnp.float32).at[
-        row_token].add(y_rows, mode="drop")
+    with jax.named_scope("dispatch"):
+        plan = plan_tile_dispatch(ef, num_experts, bn,
+                                  expert_offset=expert_offset,
+                                  num_local=num_local, fuse=fuse)
+        if capacity:
+            wf = jnp.where(plan.pos < capacity, wf, 0.0)
+        te = (plan.tile_expert if expert_of_lane is None
+              else expert_of_lane[plan.tile_expert])
+        te2 = (plan.tile_expert2 if expert_of_lane is None
+               else expert_of_lane[plan.tile_expert2])
+        # one gather per operand through the plan's row_pair map (sentinel
+        # N -> the appended zero/sink entry)
+        tok_z = jnp.concatenate(
+            [tok.astype(jnp.int32), jnp.full((1,), num_tokens, jnp.int32)])
+        row_token = tok_z[plan.row_pair]
+        x_z = jnp.concatenate(
+            [x_src, jnp.zeros((1, x_src.shape[-1]), x_src.dtype)])
+        x_rows = x_z[row_token]
+        wf_z = jnp.concatenate([wf.astype(jnp.float32), jnp.zeros((1,))])
+        scale = wf_z[plan.row_pair][:, None]
+    with jax.named_scope("experts"):
+        h = gmm_swiglu(x_rows, bank["wg"], bank["wi"], te, plan.tile_valid,
+                       tile_expert2=te2 if fused else None,
+                       row_sel=plan.row_sel if fused else None,
+                       bn=bn, interpret=interpret)
+        y_rows = gmm_scaled(h, bank["wo"], te, plan.tile_valid, scale,
+                            tile_expert2=te2 if fused else None,
+                            row_sel=plan.row_sel if fused else None,
+                            bn=bn, interpret=interpret)
+    with jax.named_scope("combine"):
+        y = jnp.zeros((num_tokens, x_src.shape[-1]), jnp.float32).at[
+            row_token].add(y_rows, mode="drop")
     return y, y_rows, plan
 
 

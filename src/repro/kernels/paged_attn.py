@@ -419,11 +419,14 @@ def page_bytes(cfg, page_size: int) -> int:
 
 def decode_tick_pages(t_host, active, page_size: int, num_slots: int,
                       pages_per_slot: int) -> tuple[int, int]:
-    """Deterministic per-tick page-traffic model for one decode tick:
-    (kernel_pages, gather_pages). The kernel stages each active row's live
-    pages — floor(t/ps)+1 — while the gather re-materializes every block
-    table entry of every slot. Pure host arithmetic; what the
-    serve_throughput `paged_attn` section (and its regression gate) uses."""
+    """Deterministic per-layer page model for one decode tick:
+    (live_pages, grid_pages). The kernel stages each active row's live
+    pages — floor(t/ps)+1 — out of its grid of num_slots x pages_per_slot
+    steps, which walks retired rows and pages past each row's position
+    too; the gather re-materializes the same num_slots x pages_per_slot
+    block-table entries. Pure host arithmetic; what the serve_throughput
+    `paged_attn` section (and its regression gate) and the engine's tick
+    counters (`ServingEngine.last_tick`) use."""
     live = sum(int(t_host[i]) // page_size + 1
                for i in range(num_slots) if active[i])
     return live, num_slots * pages_per_slot

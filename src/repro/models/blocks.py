@@ -51,39 +51,45 @@ def _ffn_apply(params: dict, x: jax.Array, cfg, group_of_expert,
     h = rmsnorm(params["ln2"], x, cfg.norm_eps)
     aux = None
     if "moe" in params:
-        B, S, d = h.shape
-        backend = MOE.resolve_backend(cfg.moe, (h, params))
-        # XLA backend routes per sequence (vmap over batch), two reasons:
-        #  * the sort-based dispatch never crosses the batch dim, so GSPMD
-        #    keeps dispatch buffers batch-sharded (a global argsort over
-        #    B*S would gather the whole batch onto every device);
-        #  * expert-choice selection per sequence is what the GO cache
-        #    serves, so train == serve semantics.
-        # The pallas backend keeps ROUTING per sequence (same semantics) but
-        # flattens the FFN pairs of the whole batch into one tile plan, so
-        # the grouped GEMM pays its per-expert tile padding once, not B times.
-        if cfg.moe.routing == "expert_choice":
-            if backend == "pallas":
-                y, aux = MOE.expert_choice_forward_batched(
-                    params["moe"], h, cfg.moe, valid_len=valid_len)
+        with jax.named_scope("moe"):
+            B, S, d = h.shape
+            backend = MOE.resolve_backend(cfg.moe, (h, params))
+            # XLA backend routes per sequence (vmap over batch), two
+            # reasons:
+            #  * the sort-based dispatch never crosses the batch dim, so
+            #    GSPMD keeps dispatch buffers batch-sharded (a global
+            #    argsort over B*S would gather the whole batch onto every
+            #    device);
+            #  * expert-choice selection per sequence is what the GO cache
+            #    serves, so train == serve semantics.
+            # The pallas backend keeps ROUTING per sequence (same
+            # semantics) but flattens the FFN pairs of the whole batch into
+            # one tile plan, so the grouped GEMM pays its per-expert tile
+            # padding once, not B times.
+            if cfg.moe.routing == "expert_choice":
+                if backend == "pallas":
+                    y, aux = MOE.expert_choice_forward_batched(
+                        params["moe"], h, cfg.moe, valid_len=valid_len)
+                else:
+                    y, aux = jax.vmap(
+                        lambda xb: MOE.expert_choice_forward(
+                            params["moe"], xb, cfg.moe,
+                            valid_len=valid_len))(h)
+            elif MOE.ep_available(cfg.moe):
+                y, aux = MOE.moe_forward_ep(params["moe"], h, cfg.moe)
+            elif backend == "pallas":
+                y, aux = MOE.moe_forward(params["moe"],
+                                         h.reshape(B * S, d), cfg.moe,
+                                         group_of_expert, group_members)
+                y = y.reshape(B, S, d)
             else:
                 y, aux = jax.vmap(
-                    lambda xb: MOE.expert_choice_forward(
-                        params["moe"], xb, cfg.moe, valid_len=valid_len))(h)
-        elif MOE.ep_available(cfg.moe):
-            y, aux = MOE.moe_forward_ep(params["moe"], h, cfg.moe)
-        elif backend == "pallas":
-            y, aux = MOE.moe_forward(params["moe"], h.reshape(B * S, d),
-                                     cfg.moe, group_of_expert, group_members)
-            y = y.reshape(B, S, d)
-        else:
-            y, aux = jax.vmap(
-                lambda xb: MOE.moe_forward(params["moe"], xb, cfg.moe,
-                                           group_of_expert,
-                                           group_members))(h)
-            aux = {"counts": aux["counts"].sum(0),
-                   "balance_loss": aux["balance_loss"].mean(),
-                   "dropped": aux["dropped"].sum()}
+                    lambda xb: MOE.moe_forward(params["moe"], xb, cfg.moe,
+                                               group_of_expert,
+                                               group_members))(h)
+                aux = {"counts": aux["counts"].sum(0),
+                       "balance_loss": aux["balance_loss"].mean(),
+                       "dropped": aux["dropped"].sum()}
     elif "mlp" in params:
         w = params["mlp"]
         y = gelu_mlp(w, h) if "wg" not in w else mlp(w, h)
@@ -119,39 +125,41 @@ def attn_block_decode(params: dict, x_t: jax.Array, cache_k, cache_v, t, *,
     With `block_table`, cache_k/cache_v are the shared paged KV pool
     (attention.py::attn_decode paged path); the GO cache stays slot-resident
     either way — it is [E, k]-shaped, not sequence-shaped."""
-    h = rmsnorm(params["ln1"], x_t, cfg.norm_eps)
-    a, ck, cv = ATT.attn_decode(params["attn"], h, cache_k, cache_v, t,
-                                cfg=cfg, window=window,
-                                block_table=block_table)
-    x = x_t + a
+    with jax.named_scope("attn"):
+        h = rmsnorm(params["ln1"], x_t, cfg.norm_eps)
+        a, ck, cv = ATT.attn_decode(params["attn"], h, cache_k, cache_v, t,
+                                    cfg=cfg, window=window,
+                                    block_table=block_table)
+        x = x_t + a
     h2 = rmsnorm(params["ln2"], x, cfg.norm_eps)
     aux = None
     if "moe" in params:
-        B = h2.shape[0]
-        h2f = h2[:, 0]                                   # [B, d]
-        if go_cache is not None:
-            # C4: expert-choice decode through the GO cache. On the pallas
-            # backend only the SELECTED experts' tiles stream through the
-            # grouped GEMM (~B*k rows); the xla fallback computes all E
-            # expert FFNs per token and masks.
-            moe_p = params["moe"]
-            e = cfg.moe
-            if MOE.resolve_backend(e, (h2f, moe_p)) == "pallas":
-                res = go_cache_step(
-                    go_cache, h2f, t, moe_p["gate"],
-                    contrib_fn=lambda xt, sel, g: OPS.go_selected_ffn(
-                        xt, sel, g, moe_p["experts"], e.num_experts,
-                        bn=MOE._block_rows(e), topk_hint=e.top_k)[0])
+        with jax.named_scope("moe"):
+            B = h2.shape[0]
+            h2f = h2[:, 0]                                   # [B, d]
+            if go_cache is not None:
+                # C4: expert-choice decode through the GO cache. On the
+                # pallas backend only the SELECTED experts' tiles stream
+                # through the grouped GEMM (~B*k rows); the xla fallback
+                # computes all E expert FFNs per token and masks.
+                moe_p = params["moe"]
+                e = cfg.moe
+                if MOE.resolve_backend(e, (h2f, moe_p)) == "pallas":
+                    res = go_cache_step(
+                        go_cache, h2f, t, moe_p["gate"],
+                        contrib_fn=lambda xt, sel, g: OPS.go_selected_ffn(
+                            xt, sel, g, moe_p["experts"], e.num_experts,
+                            bn=MOE._block_rows(e), topk_hint=e.top_k)[0])
+                else:
+                    res = go_cache_step(
+                        go_cache, h2f, t, moe_p["gate"],
+                        lambda xt: MOE.expert_ffn_all(moe_p, xt))
+                y = res.y + MOE._shared_out(moe_p, h2f)
+                go_cache = res.cache
+                aux = {"selected": res.selected}
             else:
-                res = go_cache_step(
-                    go_cache, h2f, t, moe_p["gate"],
-                    lambda xt: MOE.expert_ffn_all(moe_p, xt))
-            y = res.y + MOE._shared_out(moe_p, h2f)
-            go_cache = res.cache
-            aux = {"selected": res.selected}
-        else:
-            y = MOE.token_choice_decode(params["moe"], h2f, cfg.moe)
-        x = x + y[:, None, :]
+                y = MOE.token_choice_decode(params["moe"], h2f, cfg.moe)
+            x = x + y[:, None, :]
     elif "mlp" in params:
         w = params["mlp"]
         y = gelu_mlp(w, h2) if "wg" not in w else mlp(w, h2)
@@ -173,11 +181,12 @@ def attn_block_chunk(params: dict, x: jax.Array, cache_k, cache_v, start, *,
     Returns (x, ck, cv, go_cache, aux)."""
     start = jnp.asarray(start, jnp.int32)
     vl = jnp.asarray(x.shape[1] if valid_len is None else valid_len, jnp.int32)
-    h = rmsnorm(params["ln1"], x, cfg.norm_eps)
-    a, ck, cv = ATT.attn_chunk(params["attn"], h, cache_k, cache_v, start,
-                               cfg=cfg, window=window, kv_len=start + vl,
-                               block_table=block_table)
-    x = x + a
+    with jax.named_scope("attn"):
+        h = rmsnorm(params["ln1"], x, cfg.norm_eps)
+        a, ck, cv = ATT.attn_chunk(params["attn"], h, cache_k, cache_v,
+                                   start, cfg=cfg, window=window,
+                                   kv_len=start + vl, block_table=block_table)
+        x = x + a
     x, aux = _ffn_apply(params, x, cfg, group_of_expert, group_members, vl)
     if go_cache is not None:
         from repro.core.go_cache import go_cache_merge, go_cache_prefill

@@ -624,7 +624,8 @@ def write_decode_slot(state: dict, slot, src: dict, page_ids=None) -> dict:
 
 def serve_step(params: dict, state: dict, tokens_t: jax.Array, cfg):
     """One decode step. tokens_t [B] int32 -> (logits [B, V] fp32, state)."""
-    x = embed_tokens(params, tokens_t, cfg)[:, None, :]  # [B, 1, d]
+    with jax.named_scope("embed"):
+        x = embed_tokens(params, tokens_t, cfg)[:, None, :]  # [B, 1, d]
     t = state["t"]
 
     if cfg.block == "attn" and cfg.encoder_layers > 0:
@@ -640,8 +641,9 @@ def serve_step(params: dict, state: dict, tokens_t: jax.Array, cfg):
     else:
         raise ValueError(cfg.block)
 
-    x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    logits = logits_from_hidden(params, x[:, 0, :], cfg)
+    with jax.named_scope("head"):
+        x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
+        logits = logits_from_hidden(params, x[:, 0, :], cfg)
     state["t"] = t + 1
     return logits, state
 
@@ -668,25 +670,28 @@ def _dec_attn(params, x, state, cfg):
         pick = lambda a: jax.lax.dynamic_index_in_dim(a, l, 0, keepdims=False)
         put = lambda full, new: jax.lax.dynamic_update_index_in_dim(
             full, new.astype(full.dtype), l, 0)
-        ck = jax.tree.map(pick, K)
-        cv = jax.tree.map(pick, V)
-        go_l = jax.tree.map(pick, go) if has_go else None
-        if qgo:
-            # layer boundary: int8 GO rows -> f32 (f32, NOT the cfg compute
-            # dtype: in f32 an unchanged row requantizes to its exact int8
-            # bits, so idle rows are bit-stable across ticks)
-            go_l, gsc = go_l
-            go_l = go_l._replace(outputs=Q.dequantize_rows(go_l.outputs, gsc))
+        with jax.named_scope("kv_read"):
+            ck = jax.tree.map(pick, K)
+            cv = jax.tree.map(pick, V)
+            go_l = jax.tree.map(pick, go) if has_go else None
+            if qgo:
+                # layer boundary: int8 GO rows -> f32 (f32, NOT the cfg
+                # compute dtype: in f32 an unchanged row requantizes to its
+                # exact int8 bits, so idle rows are bit-stable across ticks)
+                go_l, gsc = go_l
+                go_l = go_l._replace(
+                    outputs=Q.dequantize_rows(go_l.outputs, gsc))
         x, ck, cv, go_l, _ = B.attn_block_decode(
             lp, x, ck, cv, t, cfg=cfg, window=w, group_of_expert=goe,
             go_cache=go_l, block_table=bt)
-        if qgo:
-            qout, gsc = Q.quantize_rows(go_l.outputs)
-            go_l = (go_l._replace(outputs=qout), gsc)
-        K = jax.tree.map(put, K, ck)
-        V = jax.tree.map(put, V, cv)
-        if has_go:
-            go = jax.tree.map(put, go, go_l)
+        with jax.named_scope("kv_write"):
+            if qgo:
+                qout, gsc = Q.quantize_rows(go_l.outputs)
+                go_l = (go_l._replace(outputs=qout), gsc)
+            K = jax.tree.map(put, K, ck)
+            V = jax.tree.map(put, V, cv)
+            if has_go:
+                go = jax.tree.map(put, go, go_l)
         return (x, K, V, go, l + 1), None
 
     K0 = (state[kk], state["k_scales"]) if qkv else state[kk]
@@ -863,7 +868,8 @@ def prefill(params: dict, tokens: jax.Array, cfg, extras: dict | None = None,
     windows = jnp.asarray(layer_windows(cfg))
     goe = expert_groups(cfg)
     gm = expert_group_members(cfg)
-    x = embed_tokens(params, tokens, cfg)
+    with jax.named_scope("embed"):
+        x = embed_tokens(params, tokens, cfg)
     has_go = "go" in state
 
     def body(x, xs):
@@ -943,7 +949,8 @@ def prefill_chunk(params: dict, state: dict, tokens: jax.Array, cfg,
     windows = jnp.asarray(layer_windows(cfg))
     goe = expert_groups(cfg)
     gm = expert_group_members(cfg)
-    x = embed_tokens(params, tokens, cfg)
+    with jax.named_scope("embed"):
+        x = embed_tokens(params, tokens, cfg)
     has_go = "go" in state
     paged = "block_table" in state
     qkv = paged and "k_scales" in state
@@ -960,17 +967,19 @@ def prefill_chunk(params: dict, state: dict, tokens: jax.Array, cfg,
         pick = lambda a: jax.lax.dynamic_index_in_dim(a, l, 0, keepdims=False)
         put = lambda full, new: jax.lax.dynamic_update_index_in_dim(
             full, new.astype(full.dtype), l, 0)
-        ck = jax.tree.map(pick, K)
-        cv = jax.tree.map(pick, V)
-        go_l = jax.tree.map(pick, go) if has_go else None
+        with jax.named_scope("kv_read"):
+            ck = jax.tree.map(pick, K)
+            cv = jax.tree.map(pick, V)
+            go_l = jax.tree.map(pick, go) if has_go else None
         x, ck, cv, go_l, _ = B.attn_block_chunk(
             lp, x, ck, cv, start, cfg=cfg, window=w, valid_len=vl,
             group_of_expert=goe, group_members=gm, go_cache=go_l,
             block_table=bt)
-        K = jax.tree.map(put, K, ck)
-        V = jax.tree.map(put, V, cv)
-        if has_go:
-            go = jax.tree.map(put, go, go_l)
+        with jax.named_scope("kv_write"):
+            K = jax.tree.map(put, K, ck)
+            V = jax.tree.map(put, V, cv)
+            if has_go:
+                go = jax.tree.map(put, go, go_l)
         return (x, K, V, go, l + 1), None
 
     K0 = (state[kk], state["k_scales"]) if qkv else state[kk]
@@ -986,8 +995,9 @@ def prefill_chunk(params: dict, state: dict, tokens: jax.Array, cfg,
         state[kk], state[vk] = K, V
     if has_go:
         state["go"] = go
-    x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    logits = logits_from_hidden(params, jnp.take(x, vl - 1, axis=1), cfg)
+    with jax.named_scope("head"):
+        x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
+        logits = logits_from_hidden(params, jnp.take(x, vl - 1, axis=1), cfg)
     state["t"] = start + vl
     return state, logits
 

@@ -10,10 +10,11 @@ and stragglers (slow steps that stall the synchronous collective).
     steps are deterministic given (params, batch), so retry is safe);
   * raises `RestartRequired` after exhausting retries — the launcher catches
     it, restores the latest committed checkpoint, and resumes (train.py);
-  * records per-step wall times and flags stragglers at
-    median * straggler_factor; the hook is where a production deployment
-    would trigger hot-spare swap / re-sharding. At the MoE layer the C2
-    load-aware placement is itself the straggler *prevention* mechanism.
+  * records per-step wall times and flags stragglers at straggler_factor
+    times the median of the last STEP_WINDOW steps; the hook is where a
+    production deployment would trigger hot-spare swap / re-sharding. At
+    the MoE layer the C2 load-aware placement is itself the straggler
+    *prevention* mechanism.
 
 `ProcessSupervisor` is the serving analogue one level up: the engine runs
 in a CHILD process (launch/serve.py --supervise re-execs itself) that
@@ -31,7 +32,12 @@ from __future__ import annotations
 import os
 import subprocess
 import time
+from collections import deque
 from dataclasses import dataclass, field
+
+# step wall times kept for the straggler median: a rolling window, so a
+# long-running server pays a bounded sort per step
+STEP_WINDOW = 1024
 
 
 class RestartRequired(RuntimeError):
@@ -41,11 +47,12 @@ class RestartRequired(RuntimeError):
 
 @dataclass
 class StepStats:
-    times: list = field(default_factory=list)
+    times: deque = field(default_factory=lambda: deque(maxlen=STEP_WINDOW))
     retries: int = 0
     stragglers: list = field(default_factory=list)
 
     def median(self) -> float:
+        """Median wall time of the last STEP_WINDOW steps."""
         if not self.times:
             return 0.0
         s = sorted(self.times)

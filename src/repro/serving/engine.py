@@ -92,7 +92,7 @@ import signal
 import sys
 import time
 from collections import Counter
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from functools import partial
 
 import jax
@@ -100,10 +100,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import quant as Q
+from repro.kernels.paged_attn import decode_tick_pages
 from repro.models.model import (init_decode_state, paged_supported, prefill,
                                 prefill_chunk as _model_prefill_chunk,
                                 serve_step)
 from repro.runtime.fault import StepSupervisor
+from repro.runtime.trace import span
 from repro.serving.chaos import Chaos
 from repro.serving.journal import (EngineJournal, JournalError,
                                    request_from_record, request_record)
@@ -220,6 +222,30 @@ def expert_signature(params, prompt, cfg) -> np.ndarray:
     return np.asarray(_gate_probe(
         params, jnp.asarray(prompt, jnp.int32),
         jnp.asarray(valid, jnp.int32), cfg))
+
+
+@dataclass
+class TickRecord:
+    """What one engine tick asked of the device, from values the engine
+    already holds on the host (`engine.last_tick`). The same counters ride
+    as arguments on the tick's `engine.step` span (`counters()`).
+    Paged-attention pages are per layer: `live_pages` the decode rows' live
+    pages, `grid_pages` the decode kernel's grid, B x P steps, retired rows
+    included (both 0 on a tick without decode or on a dense pool)."""
+    step: int = 0                    # engine step count after the tick
+    decode_rows: int = 0             # rows the decode program advanced
+    chunks: list = field(default_factory=list)   # (start, valid) per run
+    oneshot: list = field(default_factory=list)  # one-shot prompt lengths
+    live_pages: int = 0
+    grid_pages: int = 0
+
+    def counters(self) -> dict:
+        return {"decode_rows": self.decode_rows,
+                "chunk_runs": len(self.chunks),
+                "chunk_valid": sum(v for _, v in self.chunks),
+                "oneshot_tokens": sum(self.oneshot),
+                "live_pages": self.live_pages,
+                "grid_pages": self.grid_pages}
 
 
 @dataclass
@@ -377,6 +403,9 @@ class ServingEngine:
         # as the training loop's. max_retries must exceed the chaos
         # injector's max consecutive faults or the lane DoSes itself.
         self.supervisor = StepSupervisor(max_retries=3)
+        # the counters of the newest tick (None before the first)
+        self.last_tick: TickRecord | None = None
+        self._tick = TickRecord()
         self._preempted: dict[int, dict] = {}   # rid -> eviction snapshot
         self.preempted_total = 0
         self.resumed_total = 0
@@ -536,54 +565,40 @@ class ServingEngine:
         into free slots (evicting lower-priority streams under page
         pressure when preemption is on), then advance every occupied slot
         one token under the tick supervisor. Returns requests finished on
-        this tick."""
-        done: list[Request] = []
+        this tick.
 
-        self._expire(time.monotonic(), done)
+        Each phase is a host span (runtime/trace.py) inside `engine.step`:
+        `engine.expire`, `engine.chunk`, `engine.admit`,
+        `engine.decode.dispatch` (holding `engine.decode.wait`),
+        `engine.commit` and `engine.journal`; `engine.prefill.wait` sits in
+        the phase that installs a prefilled request. The tick's counters
+        (`TickRecord`) end up in `last_tick` and on the `engine.step`
+        span."""
+        done: list[Request] = []
+        self._tick = tick = TickRecord()
+        with span("engine.step") as step_span:
+            self._step_phases(done, tick)
+            tick.step = self.step_count
+            step_span.set_metadata(**tick.counters())
+        self.last_tick = tick
+        return done
+
+    def _step_phases(self, done: list[Request], tick: TickRecord) -> None:
+        with span("engine.expire"):
+            self._expire(time.monotonic(), done)
 
         for req in self.scheduler.poll(self.step_count):
             req.arrival_time = time.monotonic()
 
         if self._chunk_job is not None:
-            self._advance_chunk_job(done)
+            with span("engine.chunk"):
+                self._advance_chunk_job(done)
 
         # admission loop; a chaos pressure event skips it for one tick
         # (delays admissions without reordering them)
         if self.chaos is None or not self.chaos.pressure_event():
-            while True:
-                free = self.pool.free_slots()
-                if self._chunk_job is not None and \
-                        self._chunk_job.slot in free:
-                    free.remove(self._chunk_job.slot)
-                busy = self.pool.num_active() + \
-                    (1 if self._chunk_job is not None else 0)
-                if self.expert_aware:
-                    # refresh the cost model's view of the active batch —
-                    # each admission changes it, so re-note every iteration
-                    self.scheduler.note_active(
-                        [o.expert_sig for o in self.pool.owner
-                         if o is not None])
-                req = self.scheduler.next_admission(
-                    busy, can_admit=self._can_admit)
-                if req is None:
-                    # blocked head + preemption on: evict a lower-priority
-                    # active stream and retry the admission
-                    if self.preemption and self._preempt_for_head():
-                        continue
-                    break
-                if req.request_id in self._preempted:
-                    self._resume(free[0], req)
-                elif self.prefill_chunk and \
-                        req.prompt_len > self.prefill_chunk and \
-                        self._full_hit(req) is None and \
-                        self._ext_hit(req) is None:
-                    # long prompt with no cached prefix: chunked prefill.
-                    # A prefix hit skips (part of) the prefill, so it takes
-                    # the synchronous admission path below instead of
-                    # queueing behind the single chunk lane.
-                    self._start_chunk_job(free[0], req)
-                else:
-                    self._admit_any(free[0], req, done)
+            with span("engine.admit"):
+                self._admission_loop(done)
 
         self._note_occupancy()
 
@@ -591,37 +606,17 @@ class ServingEngine:
             self._inject_state_faults()
 
         if self.pool.any_active():
-            self.pool.grow_active()
-            toks, state, ok, new_keys = self._supervised_decode()
-            self.pool.state = self.pool._pin(state)
-            if new_keys is not None:
-                # keys advance only after the tick COMMITS — a supervisor
-                # retry must re-run with the same keys or sampled streams
-                # would silently fork
-                self.pool.keys = np.array(new_keys, dtype=np.uint32)
-            self.pool.note_decoded()
-            toks = np.asarray(toks)
-            ok = np.asarray(ok)
-            self.step_count += 1
-            for slot, req in enumerate(self.pool.owner):
-                if req is None:
-                    continue
-                if not ok[slot]:
-                    # quarantine: this row's logits went non-finite; retire
-                    # it FAILED (no garbage token appended) — cohabiting
-                    # rows are untouched (every batched op is row-wise
-                    # independent)
-                    self._retire_slot(slot, RequestStatus.FAILED, done,
-                                      reason="non-finite logits")
-                    continue
-                tok = int(toks[slot])
-                req.tokens.append(tok)
-                self._journal_token(req, tok)
-                self.pool.pending[slot] = tok
-                self.pool.remaining[slot] -= 1
-                if self.pool.remaining[slot] <= 0 or \
-                        (req.eos_id is not None and tok == req.eos_id):
-                    self._finish(slot, done)
+            with span("engine.decode.dispatch"):
+                self.pool.grow_active()
+                active = self.pool.active_mask()
+                tick.decode_rows = int(active.sum())
+                if self.pool.paged:
+                    tick.live_pages, tick.grid_pages = decode_tick_pages(
+                        self.pool.t_host, active, self.pool.page_size,
+                        self.pool.num_slots, self.pool.block_table.shape[1])
+                toks, state, ok, new_keys = self._supervised_decode()
+            with span("engine.commit"):
+                self._commit_decode(toks, state, ok, new_keys, done)
         elif self._chunk_job is not None:
             self.step_count += 1              # prefill-only tick
         else:
@@ -631,16 +626,18 @@ class ServingEngine:
                                   nxt if nxt is not None else 0)
 
         if self.journal is not None:
-            if self._tick_toks:
-                # ONE durable record per decode tick — the token watermark
-                # every recovered stream is prefix-asserted against
-                self.journal.append("tick", step=self.step_count,
-                                    toks=dict(self._tick_toks))
-                self._tick_toks.clear()
-            if self.step_count - self.journal.last_snapshot_step >= \
-                    self.journal.snapshot_every:
-                self.journal.commit_snapshot(self._snapshot_payload(),
-                                             self.step_count)
+            with span("engine.journal"):
+                if self._tick_toks:
+                    # ONE durable record per decode tick — the token
+                    # watermark every recovered stream is prefix-asserted
+                    # against
+                    self.journal.append("tick", step=self.step_count,
+                                        toks=dict(self._tick_toks))
+                    self._tick_toks.clear()
+                if self.step_count - self.journal.last_snapshot_step >= \
+                        self.journal.snapshot_every:
+                    self.journal.commit_snapshot(self._snapshot_payload(),
+                                                 self.step_count)
         if self._heartbeat:
             # liveness signal for the process supervisor (mtime staleness)
             with open(self._heartbeat, "a"):
@@ -649,7 +646,82 @@ class ServingEngine:
 
         if self.audit_every_tick:
             self._audit()
-        return done
+
+    def _admission_loop(self, done: list[Request]) -> None:
+        """Admit queued requests into free slots until the head blocks
+        (preempting for it where preemption is on). A request leaves the
+        queue (`Request.start_time`) when its slot is claimed for a
+        one-shot prefill or its chunked prefill starts."""
+        while True:
+            free = self.pool.free_slots()
+            if self._chunk_job is not None and \
+                    self._chunk_job.slot in free:
+                free.remove(self._chunk_job.slot)
+            busy = self.pool.num_active() + \
+                (1 if self._chunk_job is not None else 0)
+            if self.expert_aware:
+                # refresh the cost model's view of the active batch —
+                # each admission changes it, so re-note every iteration
+                self.scheduler.note_active(
+                    [o.expert_sig for o in self.pool.owner
+                     if o is not None])
+            req = self.scheduler.next_admission(
+                busy, can_admit=self._can_admit)
+            if req is None:
+                # blocked head + preemption on: evict a lower-priority
+                # active stream and retry the admission
+                if self.preemption and self._preempt_for_head():
+                    continue
+                return
+            if req.request_id in self._preempted:
+                self._resume(free[0], req)
+                continue
+            req.start_time = time.monotonic()
+            if self.prefill_chunk and \
+                    req.prompt_len > self.prefill_chunk and \
+                    self._full_hit(req) is None and \
+                    self._ext_hit(req) is None:
+                # long prompt with no cached prefix: chunked prefill.
+                # A prefix hit skips (part of) the prefill, so it takes
+                # the synchronous admission path below instead of
+                # queueing behind the single chunk lane.
+                self._start_chunk_job(free[0], req)
+            else:
+                self._admit_any(free[0], req, done)
+
+    def _commit_decode(self, toks, state, ok, new_keys,
+                       done: list[Request]) -> None:
+        """Commit a finished decode tick: the new pool state and sampling
+        keys, then each row's token, finishes and quarantines."""
+        self.pool.state = self.pool._pin(state)
+        if new_keys is not None:
+            # keys advance only after the tick COMMITS — a supervisor
+            # retry must re-run with the same keys or sampled streams
+            # would silently fork
+            self.pool.keys = np.array(new_keys, dtype=np.uint32)
+        self.pool.note_decoded()
+        toks = np.asarray(toks)
+        ok = np.asarray(ok)
+        self.step_count += 1
+        for slot, req in enumerate(self.pool.owner):
+            if req is None:
+                continue
+            if not ok[slot]:
+                # quarantine: this row's logits went non-finite; retire
+                # it FAILED (no garbage token appended) — cohabiting
+                # rows are untouched (every batched op is row-wise
+                # independent)
+                self._retire_slot(slot, RequestStatus.FAILED, done,
+                                  reason="non-finite logits")
+                continue
+            tok = int(toks[slot])
+            req.tokens.append(tok)
+            self._journal_token(req, tok)
+            self.pool.pending[slot] = tok
+            self.pool.remaining[slot] -= 1
+            if self.pool.remaining[slot] <= 0 or \
+                    (req.eos_id is not None and tok == req.eos_id):
+                self._finish(slot, done)
 
     def has_work(self) -> bool:
         """Anything left to do — queued/deferred requests, occupied slots,
@@ -837,7 +909,9 @@ class ServingEngine:
         def tick():
             if self.chaos is not None:
                 self.chaos.maybe_tick_fault(self.step_count)
-            return self._run_decode_step()
+            out = self._run_decode_step()
+            with span("engine.decode.wait"):
+                return jax.block_until_ready(out)
         return self.supervisor.run(tick, step=self.step_count)
 
     def _mesh_scope(self):
@@ -906,6 +980,7 @@ class ServingEngine:
         prompt, valid_len = (self._bucketed(req.prompt) if self.prompt_buckets
                              else (req.prompt, None))
         self.prefill_lengths.add(int(prompt.shape[0]))
+        self._tick.oneshot.append(req.prompt_len)
         with self._mesh_scope():
             slot_state, logits = _jit_prefill(
                 self.params, jnp.asarray(prompt, jnp.int32)[None, :],
@@ -927,7 +1002,9 @@ class ServingEngine:
         chunk runs — a chunked expert-choice prefill routes at per-chunk
         capacities, so its GO rows and logits are not the one-shot
         artifacts the cache promises)."""
-        if not bool(np.isfinite(np.asarray(logits)).all()):
+        with span("engine.prefill.wait"):
+            finite = bool(np.isfinite(np.asarray(logits)).all())
+        if not finite:
             if page_row is not None and self.pool.paged:
                 self.pool.release_pages(req.request_id)  # claimed run pages
             self._mark_finished(req, RequestStatus.FAILED, done,
@@ -1083,6 +1160,7 @@ class ServingEngine:
         args = (self.params, state, jnp.asarray(chunk, jnp.int32)[None, :],
                 self.cfg, jnp.asarray(start, jnp.int32),
                 jnp.asarray(rem, jnp.int32))
+        self._tick.chunks.append((start, rem))
         with self._mesh_scope():
             state, logits = _jit_prefill_chunk(*args)
         self.pool.state["k_pages"] = state.pop("k_pages")
@@ -1199,6 +1277,7 @@ class ServingEngine:
         args = (self.params, job.state,
                 jnp.asarray(chunk, jnp.int32)[None, :], self.cfg,
                 jnp.asarray(job.pos, jnp.int32), jnp.asarray(valid, jnp.int32))
+        self._tick.chunks.append((job.pos, valid))
         with self._mesh_scope():
             job.state, job.logits = _jit_prefill_chunk(*args)
         if paged:
@@ -1586,7 +1665,6 @@ class ServingEngine:
             "rejected": {"queue_full": self.rejected_full,
                          "oversized": self.rejected_oversized},
             "tick_retries": self.supervisor.stats.retries,
-            "tick_ms_median": round(self.supervisor.stats.median() * 1e3, 3),
             "tick_stragglers": [
                 {"step": s, "wall_ms": round(dt * 1e3, 3),
                  "median_ms": round(med * 1e3, 3)}

@@ -177,7 +177,8 @@ def request_from_record(rec: dict) -> Request:
         req.preemptions = rec["preemptions"]
         req.slot = rec["slot"]
         if req.admit_step >= 0:
-            req.admit_time = now             # max_wall_s re-anchors too
+            # max_wall_s re-anchors too; start_time with it
+            req.start_time = req.admit_time = now
     return req
 
 

@@ -118,8 +118,14 @@ class Request:
     # --- filled in by the engine ---
     status: RequestStatus = RequestStatus.QUEUED
     fail_reason: str | None = None   # set on FAILED/TIMEOUT/CANCELLED
+    # Host clock stamps (time.monotonic): arrival_time <= start_time <=
+    # admit_time. start_time marks when the request leaves the queue (its
+    # slot claimed for a one-shot prefill, or its chunked prefill begun);
+    # admit_time marks its first token (the end of its prefill). A journal
+    # recovery re-anchors all of them at the recovery's clock.
     arrival_time: float = 0.0        # wall-clock when it joined the queue
     submit_time: float = 0.0         # wall-clock at submit (deadline_s anchor)
+    start_time: float = 0.0          # wall-clock when it left the queue
     admit_time: float = 0.0          # wall-clock at FIRST admission
     admit_step: int = -1
     finish_step: int = -1
