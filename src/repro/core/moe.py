@@ -122,6 +122,7 @@ def moe_init(key, d_model: int, e: MoEConfig, dtype) -> dict:
 
 def _expert_gemm(bank: dict, x: jax.Array) -> jax.Array:
     """x [E, C, d] -> [E, C, d] through each expert's SwiGLU FFN."""
+    bank = OPS.ExpertStack.of(bank).sliced()
     h = jax.nn.silu(jnp.einsum("ecd,edf->ecf", x, bank["wg"])) * jnp.einsum(
         "ecd,edf->ecf", x, bank["wi"])
     return jnp.einsum("ecf,efd->ecd", h, bank["wo"])
@@ -141,7 +142,7 @@ def _shared_out(params: dict, x: jax.Array) -> jax.Array:
 def expert_ffn_all(params: dict, x: jax.Array) -> jax.Array:
     """All-expert outputs for a token batch. x [B, d] -> [B, E, d].
     Used by the GO-cache decode step (dense fallback) and the oracle."""
-    b = params["experts"]
+    b = OPS.ExpertStack.of(params["experts"]).sliced()
     h = jax.nn.silu(jnp.einsum("td,edf->etf", x, b["wg"])) * jnp.einsum(
         "td,edf->etf", x, b["wi"])
     return jnp.einsum("etf,efd->ted", h, b["wo"])
@@ -317,7 +318,7 @@ def group_forward(params: dict, x: jax.Array, e: MoEConfig,
         expert_flat, mode="drop")[:-1].reshape(G, C_grp)
 
     # XLA fallback: accumulate each member's masked contribution
-    bank = params["experts"]
+    bank = OPS.ExpertStack.of(params["experts"]).sliced()
     y_disp = jnp.zeros(x_disp.shape, jnp.float32)
     for j in range(g):
         eid = members[:, j]                                      # [G]
@@ -628,7 +629,7 @@ def moe_forward_ep(params: dict, h: jax.Array, e: MoEConfig) -> tuple:
         bal = jax.lax.pmean(bal.mean(), dp) if dp else bal.mean()
         return (y, bal, cnt, dropped)
 
-    bank = params["experts"]
+    bank = OPS.ExpertStack.of(params["experts"]).sliced()
     y, bal, cnt, dropped = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(dp, None, None), P(), P("model", None, None),

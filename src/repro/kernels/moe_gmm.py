@@ -23,9 +23,26 @@ Kernels:
 Grid: (num_row_tiles, F/bf, K/bk); fp32 VMEM scratch accumulates over k.
 Block shapes default to MXU-aligned (128, 512, 128).
 
-Alignment: non-tile-aligned shapes are zero-padded to block multiples — K and
-F on both operands (dot products unchanged; extra output columns sliced off),
-rows up to the row-tile boundary. `tile_valid` marks row tiles that carry at
+Layer operand: each kernel also takes the STACKED banks of a layer scan,
+[L, E, K, F], with `layer` as one more scalar-prefetch operand; weight
+blocks are read in place at (layer, e(i), k, j), the layer axis squeezed,
+so no per-layer copy of the bank is made (a Mosaic call needs each operand
+as a buffer of its own: a sliced layer would be copied whole, every layer
+of every program).
+
+Block rule: for each bank dimension the K and F blocks divide it — the
+default where it divides, else the whole dimension (a full-extent block,
+which Mosaic accepts: deepseek's d_expert of 1408 is one K block, llama's
+688 one F block). Where a whole-dimension block would not fit VMEM
+(`_FULL_BLOCK_BYTES`), the default stays and the bank is zero-padded to
+block multiples; a stacked bank is then sliced to its layer first. Under a
+GSPMD mesh a stacked bank is sliced too: `mosaic_call` replicates every
+operand there, and a stacked one would gather all L layers onto every
+device.
+
+Alignment: rows are zero-padded up to the row-tile boundary, and a padded
+bank's K and F on both operands (dot products unchanged; extra output
+columns sliced off). `tile_valid` marks row tiles that carry at
 least one real dispatched row: invalid tiles (alignment padding, empty expert
 runs, the drop lane of the selected-decode path) SKIP the MXU work entirely
 via `pl.when`, so the executed FLOPs track the planner's occupied tiles, not
@@ -90,6 +107,14 @@ def mosaic_call(kernel, *operands):
         return kernel(*operands)
     return jax.shard_map(kernel, mesh=m, in_specs=PartitionSpec(),
                          out_specs=PartitionSpec(), check_vma=False)(*operands)
+
+
+def layer_slice(w: jax.Array, layer) -> jax.Array:
+    """Layer `layer` of a stacked [L, ...] bank; the bank itself when
+    `layer` is None."""
+    if layer is None:
+        return w
+    return jax.lax.dynamic_index_in_dim(w, layer, 0, keepdims=False)
 
 
 def _pad_to(a: jax.Array, axis: int, size: int) -> jax.Array:
@@ -249,53 +274,124 @@ def _gmm_swiglu_kernel(te_ref, tv_ref, x_ref, wg_ref, wi_ref, o_ref,
         o_ref[...] = h.astype(o_ref.dtype)
 
 
+# Where the default block does not divide a bank dimension, the kernel takes
+# the whole dimension as one block, provided one weight block stays within
+# this size: the fused SwiGLU kernel stages four weight streams,
+# double-buffered, and they must stay well inside the 16 MiB of scoped VMEM.
+_FULL_BLOCK_BYTES = 1 << 20
+
+
+def _bank_layout(banks: tuple, layer, bk: int, bf: int):
+    """The (K, F) blocks for weight banks of one shape, and the banks as the
+    kernel reads them: (banks, layer, bk, bf).
+
+    Blocks divide the bank: the default where it divides, else the whole
+    dimension. Where that block would not fit VMEM, the default stays and
+    the bank is zero-padded to block multiples. A stacked bank ([L, E, K, F]
+    with `layer`) is read in place, except where it needs that padding, or
+    under a GSPMD mesh, where `mosaic_call` replicates every operand and
+    would gather all L layers onto every device: there it is sliced to the
+    layer's [E, K, F] first."""
+    if (layer is None) != (banks[0].ndim == 3):
+        raise ValueError(f"bank of shape {banks[0].shape} with layer={layer}:"
+                         " a stacked [L, E, K, F] bank takes a layer index, "
+                         "an [E, K, F] bank none")
+    K, F = banks[0].shape[-2:]
+    fk, ff = (bk if K % bk == 0 else K), (bf if F % bf == 0 else F)
+    if fk * ff * banks[0].dtype.itemsize <= _FULL_BLOCK_BYTES:
+        bk, bf = fk, ff
+    else:
+        bk, bf = min(bk, K), min(bf, F)
+    if layer is not None and (K % bk or F % bf or _gspmd_mesh() is not None):
+        banks = tuple(layer_slice(w, layer) for w in banks)
+        layer = None
+    if layer is not None:
+        layer = jnp.asarray(layer, jnp.int32).reshape(1)
+    return banks, layer, bk, bf
+
+
+def _launch(kernel, x, banks, w_maps, te, te2, tv, layer, rows, *, n_acc,
+            bn, bk, bf, interpret, out_dtype):
+    """One grouped-GEMM pallas_call over (row tile i, F block j, K block k).
+
+    Scalar prefetch: [layer,] te, [te2,] tv. Weight stream s reads
+    `banks[s]` by tile map `w_maps[s]` (0: te, 1: te2); `rows` are per-row
+    [N, 1] operands. With `layer`, the banks are the stacked [L, E, K, F]
+    and each block index leads with the layer, squeezed, so the kernel
+    bodies see the same (1, bk, bf) weight refs either way."""
+    N, K = x.shape
+    F = banks[0].shape[-1]
+    ni = te.shape[0]
+    Kp, Fp = -(-K // bk) * bk, -(-F // bf) * bf
+    xp = _pad_to(_pad_to(x, 0, ni * bn), 1, Kp)
+    banks = [_pad_to(_pad_to(w, w.ndim - 2, Kp), w.ndim - 1, Fp)
+             for w in banks]
+    rows = [_pad_to(r.astype(jnp.float32), 0, ni * bn) for r in rows]
+    scalars = (te,) + (() if te2 is None else (te2,)) + (tv,)
+    nk = Kp // bk
+    if layer is None:
+        w_block = (1, bk, bf)
+        body = functools.partial(kernel, nk=nk)
+
+        def w_index(m):
+            return lambda i, j, k, *s: (s[m][i], k, j)
+    else:
+        # the layer leads the scalar prefetch; only the index maps read it
+        scalars = (layer,) + scalars
+        w_block = (pl.squeezed, 1, bk, bf)
+
+        def body(lyr_ref, *refs):
+            return kernel(*refs, nk=nk)
+
+        def w_index(m):
+            return lambda i, j, k, lyr, *s: (lyr[0], s[m][i], k, j)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(scalars),
+        grid=(ni, Fp // bf, nk),
+        in_specs=[pl.BlockSpec((bn, bk), lambda i, j, k, *s: (i, k))]
+        + [pl.BlockSpec(w_block, w_index(m)) for m in w_maps]
+        + [pl.BlockSpec((bn, 1), lambda i, j, k, *s: (i, 0)) for _ in rows],
+        out_specs=pl.BlockSpec((bn, bf), lambda i, j, k, *s: (i, j)),
+        scratch_shapes=[pltpu.VMEM((bn, bf), jnp.float32)] * n_acc,
+    )
+    y = mosaic_call(pl.pallas_call(
+        body,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((ni * bn, Fp), out_dtype),
+        interpret=interpret,
+    ), *scalars, xp, *banks, *rows)
+    return y[:N, :F]
+
+
 def gmm(x: jax.Array, w: jax.Array, tile_expert: jax.Array,
-        tile_valid: jax.Array | None = None, *, bn: int = 128, bk: int = 512,
-        bf: int = 128, interpret: bool | None = None,
+        tile_valid: jax.Array | None = None, *, layer=None, bn: int = 128,
+        bk: int = 512, bf: int = 128, interpret: bool | None = None,
         out_dtype=None) -> jax.Array:
-    """x [N, K] (rows tile-aligned by expert), w [E, K, F],
-    tile_expert [n_tiles] int32, tile_valid [n_tiles] optional -> y [N, F]."""
+    """x [N, K] (rows tile-aligned by expert), w [E, K, F] (or the stacked
+    [L, E, K, F] with `layer`), tile_expert [n_tiles] int32, tile_valid
+    [n_tiles] optional -> y [N, F]."""
     if interpret is None:
         interpret = default_interpret()
-    return _gmm(x, w, tile_expert, tile_valid, bn=bn, bk=bk, bf=bf,
+    (w,), layer, bk, bf = _bank_layout((w,), layer, bk, bf)
+    return _gmm(x, w, tile_expert, tile_valid, layer, bn=bn, bk=bk, bf=bf,
                 interpret=interpret, out_dtype=out_dtype)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("bn", "bk", "bf", "interpret", "out_dtype"))
-def _gmm(x, w, tile_expert, tile_valid, *, bn, bk, bf, interpret, out_dtype):
-    N, K = x.shape
-    E, _, F = w.shape
-    bk, bf = min(bk, K), min(bf, F)
-    ni, te, tv = _row_tiles(N, bn, tile_expert, tile_valid)
-    Kp, Fp = -(-K // bk) * bk, -(-F // bf) * bf
-    xp = _pad_to(_pad_to(x, 0, ni * bn), 1, Kp)
-    wp = _pad_to(_pad_to(w, 1, Kp), 2, Fp)
-    nk, nf = Kp // bk, Fp // bf
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(ni, nf, nk),
-        in_specs=[
-            pl.BlockSpec((bn, bk), lambda i, j, k, te, tv: (i, k)),
-            pl.BlockSpec((1, bk, bf), lambda i, j, k, te, tv: (te[i], k, j)),
-        ],
-        out_specs=pl.BlockSpec((bn, bf), lambda i, j, k, te, tv: (i, j)),
-        scratch_shapes=[pltpu.VMEM((bn, bf), jnp.float32)],
-    )
-    y = mosaic_call(pl.pallas_call(
-        functools.partial(_gmm_kernel, nk=nk),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((ni * bn, Fp), out_dtype or x.dtype),
-        interpret=interpret,
-    ), te, tv, xp, wp)
-    return y[:N, :F]
+def _gmm(x, w, tile_expert, tile_valid, layer, *, bn, bk, bf, interpret,
+         out_dtype):
+    _, te, tv = _row_tiles(x.shape[0], bn, tile_expert, tile_valid)
+    return _launch(_gmm_kernel, x, (w,), (0,), te, None, tv, layer, (),
+                   n_acc=1, bn=bn, bk=bk, bf=bf, interpret=interpret,
+                   out_dtype=out_dtype or x.dtype)
 
 
 def gmm_scaled(x: jax.Array, w: jax.Array, tile_expert: jax.Array,
                tile_valid: jax.Array | None, row_scale: jax.Array, *,
                tile_expert2: jax.Array | None = None,
-               row_sel: jax.Array | None = None,
+               row_sel: jax.Array | None = None, layer=None,
                bn: int = 128, bk: int = 512, bf: int = 128,
                interpret: bool | None = None,
                out_dtype=jnp.float32) -> jax.Array:
@@ -307,181 +403,76 @@ def gmm_scaled(x: jax.Array, w: jax.Array, tile_expert: jax.Array,
 
     With `tile_expert2`/`row_sel` (fused lane pairs), a straddle tile's rows
     split between two experts: rows where row_sel==1 hit tile_expert's
-    weights, the complement hits tile_expert2's."""
+    weights, the complement hits tile_expert2's. With `layer`, w is the
+    stacked [L, E, K, F] bank."""
     if interpret is None:
         interpret = default_interpret()
+    (w,), layer, bk, bf = _bank_layout((w,), layer, bk, bf)
     if tile_expert2 is None:
-        return _gmm_scaled(x, w, tile_expert, tile_valid, row_scale, bn=bn,
-                           bk=bk, bf=bf, interpret=interpret,
+        return _gmm_scaled(x, w, tile_expert, tile_valid, row_scale, layer,
+                           bn=bn, bk=bk, bf=bf, interpret=interpret,
                            out_dtype=out_dtype)
     return _gmm_scaled_fused(x, w, tile_expert, tile_expert2, tile_valid,
-                             row_scale, row_sel, bn=bn, bk=bk, bf=bf,
+                             row_scale, row_sel, layer, bn=bn, bk=bk, bf=bf,
                              interpret=interpret, out_dtype=out_dtype)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("bn", "bk", "bf", "interpret", "out_dtype"))
-def _gmm_scaled(x, w, tile_expert, tile_valid, row_scale, *, bn, bk, bf,
-                interpret, out_dtype):
-    N, K = x.shape
-    E, _, F = w.shape
-    bk, bf = min(bk, K), min(bf, F)
-    ni, te, tv = _row_tiles(N, bn, tile_expert, tile_valid)
-    Kp, Fp = -(-K // bk) * bk, -(-F // bf) * bf
-    xp = _pad_to(_pad_to(x, 0, ni * bn), 1, Kp)
-    wp = _pad_to(_pad_to(w, 1, Kp), 2, Fp)
-    sp = _pad_to(row_scale.astype(jnp.float32), 0, ni * bn)
-    nk, nf = Kp // bk, Fp // bf
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(ni, nf, nk),
-        in_specs=[
-            pl.BlockSpec((bn, bk), lambda i, j, k, te, tv: (i, k)),
-            pl.BlockSpec((1, bk, bf), lambda i, j, k, te, tv: (te[i], k, j)),
-            pl.BlockSpec((bn, 1), lambda i, j, k, te, tv: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((bn, bf), lambda i, j, k, te, tv: (i, j)),
-        scratch_shapes=[pltpu.VMEM((bn, bf), jnp.float32)],
-    )
-    y = mosaic_call(pl.pallas_call(
-        functools.partial(_gmm_scaled_kernel, nk=nk),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((ni * bn, Fp), out_dtype),
-        interpret=interpret,
-    ), te, tv, xp, wp, sp)
-    return y[:N, :F]
+def _gmm_scaled(x, w, tile_expert, tile_valid, row_scale, layer, *, bn, bk,
+                bf, interpret, out_dtype):
+    _, te, tv = _row_tiles(x.shape[0], bn, tile_expert, tile_valid)
+    return _launch(_gmm_scaled_kernel, x, (w,), (0,), te, None, tv, layer,
+                   (row_scale,), n_acc=1, bn=bn, bk=bk, bf=bf,
+                   interpret=interpret, out_dtype=out_dtype)
 
 
 def gmm_swiglu(x: jax.Array, wg: jax.Array, wi: jax.Array,
                tile_expert: jax.Array, tile_valid: jax.Array | None = None, *,
                tile_expert2: jax.Array | None = None,
-               row_sel: jax.Array | None = None,
+               row_sel: jax.Array | None = None, layer=None,
                bn: int = 128, bk: int = 512, bf: int = 128,
                interpret: bool | None = None) -> jax.Array:
     """Fused per-expert SwiGLU up-projection: silu(x@wg[e]) * (x@wi[e]).
     One x-tile staging feeds BOTH weight streams (multiplexed operand reuse).
-    `tile_expert2`/`row_sel` resolve fused-pair straddle tiles per row."""
+    `tile_expert2`/`row_sel` resolve fused-pair straddle tiles per row. With
+    `layer`, wg and wi are the stacked [L, E, K, F] banks."""
     if interpret is None:
         interpret = default_interpret()
+    (wg, wi), layer, bk, bf = _bank_layout((wg, wi), layer, bk, bf)
     if tile_expert2 is None:
-        return _gmm_swiglu(x, wg, wi, tile_expert, tile_valid, bn=bn, bk=bk,
-                           bf=bf, interpret=interpret)
+        return _gmm_swiglu(x, wg, wi, tile_expert, tile_valid, layer, bn=bn,
+                           bk=bk, bf=bf, interpret=interpret)
     return _gmm_swiglu_fused(x, wg, wi, tile_expert, tile_expert2, tile_valid,
-                             row_sel, bn=bn, bk=bk, bf=bf, interpret=interpret)
+                             row_sel, layer, bn=bn, bk=bk, bf=bf,
+                             interpret=interpret)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("bn", "bk", "bf", "interpret", "out_dtype"))
 def _gmm_scaled_fused(x, w, tile_expert, tile_expert2, tile_valid, row_scale,
-                      row_sel, *, bn, bk, bf, interpret, out_dtype):
-    N, K = x.shape
-    E, _, F = w.shape
-    bk, bf = min(bk, K), min(bf, F)
-    ni, te, tv = _row_tiles(N, bn, tile_expert, tile_valid)
-    te2 = tile_expert2.astype(jnp.int32)
-    Kp, Fp = -(-K // bk) * bk, -(-F // bf) * bf
-    xp = _pad_to(_pad_to(x, 0, ni * bn), 1, Kp)
-    wp = _pad_to(_pad_to(w, 1, Kp), 2, Fp)
-    sp = _pad_to(row_scale.astype(jnp.float32), 0, ni * bn)
-    selp = _pad_to(row_sel.astype(jnp.float32), 0, ni * bn)
-    nk, nf = Kp // bk, Fp // bf
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(ni, nf, nk),
-        in_specs=[
-            pl.BlockSpec((bn, bk), lambda i, j, k, te, te2, tv: (i, k)),
-            pl.BlockSpec((1, bk, bf),
-                         lambda i, j, k, te, te2, tv: (te[i], k, j)),
-            pl.BlockSpec((1, bk, bf),
-                         lambda i, j, k, te, te2, tv: (te2[i], k, j)),
-            pl.BlockSpec((bn, 1), lambda i, j, k, te, te2, tv: (i, 0)),
-            pl.BlockSpec((bn, 1), lambda i, j, k, te, te2, tv: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((bn, bf), lambda i, j, k, te, te2, tv: (i, j)),
-        scratch_shapes=[pltpu.VMEM((bn, bf), jnp.float32)],
-    )
-    y = mosaic_call(pl.pallas_call(
-        functools.partial(_gmm_scaled_fused_kernel, nk=nk),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((ni * bn, Fp), out_dtype),
-        interpret=interpret,
-    ), te, te2, tv, xp, wp, wp, selp, sp)
-    return y[:N, :F]
+                      row_sel, layer, *, bn, bk, bf, interpret, out_dtype):
+    _, te, tv = _row_tiles(x.shape[0], bn, tile_expert, tile_valid)
+    return _launch(_gmm_scaled_fused_kernel, x, (w, w), (0, 1), te,
+                   tile_expert2.astype(jnp.int32), tv, layer,
+                   (row_sel, row_scale), n_acc=1, bn=bn, bk=bk, bf=bf,
+                   interpret=interpret, out_dtype=out_dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("bn", "bk", "bf", "interpret"))
 def _gmm_swiglu_fused(x, wg, wi, tile_expert, tile_expert2, tile_valid,
-                      row_sel, *, bn, bk, bf, interpret):
-    N, K = x.shape
-    E, _, F = wg.shape
-    bk, bf = min(bk, K), min(bf, F)
-    ni, te, tv = _row_tiles(N, bn, tile_expert, tile_valid)
-    te2 = tile_expert2.astype(jnp.int32)
-    Kp, Fp = -(-K // bk) * bk, -(-F // bf) * bf
-    xp = _pad_to(_pad_to(x, 0, ni * bn), 1, Kp)
-    wgp = _pad_to(_pad_to(wg, 1, Kp), 2, Fp)
-    wip = _pad_to(_pad_to(wi, 1, Kp), 2, Fp)
-    selp = _pad_to(row_sel.astype(jnp.float32), 0, ni * bn)
-    nk, nf = Kp // bk, Fp // bf
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(ni, nf, nk),
-        in_specs=[
-            pl.BlockSpec((bn, bk), lambda i, j, k, te, te2, tv: (i, k)),
-            pl.BlockSpec((1, bk, bf),
-                         lambda i, j, k, te, te2, tv: (te[i], k, j)),
-            pl.BlockSpec((1, bk, bf),
-                         lambda i, j, k, te, te2, tv: (te[i], k, j)),
-            pl.BlockSpec((1, bk, bf),
-                         lambda i, j, k, te, te2, tv: (te2[i], k, j)),
-            pl.BlockSpec((1, bk, bf),
-                         lambda i, j, k, te, te2, tv: (te2[i], k, j)),
-            pl.BlockSpec((bn, 1), lambda i, j, k, te, te2, tv: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((bn, bf), lambda i, j, k, te, te2, tv: (i, j)),
-        scratch_shapes=[pltpu.VMEM((bn, bf), jnp.float32),
-                        pltpu.VMEM((bn, bf), jnp.float32)],
-    )
-    y = mosaic_call(pl.pallas_call(
-        functools.partial(_gmm_swiglu_fused_kernel, nk=nk),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((ni * bn, Fp), x.dtype),
-        interpret=interpret,
-    ), te, te2, tv, xp, wgp, wip, wgp, wip, selp)
-    return y[:N, :F]
+                      row_sel, layer, *, bn, bk, bf, interpret):
+    _, te, tv = _row_tiles(x.shape[0], bn, tile_expert, tile_valid)
+    return _launch(_gmm_swiglu_fused_kernel, x, (wg, wi, wg, wi),
+                   (0, 0, 1, 1), te, tile_expert2.astype(jnp.int32), tv,
+                   layer, (row_sel,), n_acc=2, bn=bn, bk=bk, bf=bf,
+                   interpret=interpret, out_dtype=x.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("bn", "bk", "bf", "interpret"))
-def _gmm_swiglu(x, wg, wi, tile_expert, tile_valid, *, bn, bk, bf, interpret):
-    N, K = x.shape
-    E, _, F = wg.shape
-    bk, bf = min(bk, K), min(bf, F)
-    ni, te, tv = _row_tiles(N, bn, tile_expert, tile_valid)
-    Kp, Fp = -(-K // bk) * bk, -(-F // bf) * bf
-    xp = _pad_to(_pad_to(x, 0, ni * bn), 1, Kp)
-    wgp = _pad_to(_pad_to(wg, 1, Kp), 2, Fp)
-    wip = _pad_to(_pad_to(wi, 1, Kp), 2, Fp)
-    nk, nf = Kp // bk, Fp // bf
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(ni, nf, nk),
-        in_specs=[
-            pl.BlockSpec((bn, bk), lambda i, j, k, te, tv: (i, k)),
-            pl.BlockSpec((1, bk, bf), lambda i, j, k, te, tv: (te[i], k, j)),
-            pl.BlockSpec((1, bk, bf), lambda i, j, k, te, tv: (te[i], k, j)),
-        ],
-        out_specs=pl.BlockSpec((bn, bf), lambda i, j, k, te, tv: (i, j)),
-        scratch_shapes=[pltpu.VMEM((bn, bf), jnp.float32),
-                        pltpu.VMEM((bn, bf), jnp.float32)],
-    )
-    y = mosaic_call(pl.pallas_call(
-        functools.partial(_gmm_swiglu_kernel, nk=nk),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((ni * bn, Fp), x.dtype),
-        interpret=interpret,
-    ), te, tv, xp, wgp, wip)
-    return y[:N, :F]
+def _gmm_swiglu(x, wg, wi, tile_expert, tile_valid, layer, *, bn, bk, bf,
+                interpret):
+    _, te, tv = _row_tiles(x.shape[0], bn, tile_expert, tile_valid)
+    return _launch(_gmm_swiglu_kernel, x, (wg, wi), (0, 0), te, None, tv,
+                   layer, (), n_acc=2, bn=bn, bk=bk, bf=bf,
+                   interpret=interpret, out_dtype=x.dtype)

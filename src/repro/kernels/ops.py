@@ -50,13 +50,39 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.moe_gmm import (default_interpret, gmm, gmm_scaled,
-                                   gmm_swiglu, lowering_platform)
+                                   gmm_swiglu, layer_slice, lowering_platform)
 
 
 def default_block_rows() -> int:
     """Row-tile height: MXU-aligned when lowering for TPU; small otherwise so
     the interpreted correctness path does not drown in padding tiles."""
     return 128 if lowering_platform() == "tpu" else 8
+
+
+class ExpertStack(NamedTuple):
+    """One layer's routed-expert banks as the grouped-GEMM kernels take
+    them: wg, wi [L, E, d, f] and wo [L, E, f, d], left in the model's layer
+    stacks, with the layer index; or one layer's [E, ...] banks with
+    layer None. The kernels read a stacked layer's blocks in place; every
+    other consumer takes the layer's {"wg", "wi", "wo"} through `sliced`."""
+    wg: jax.Array
+    wi: jax.Array
+    wo: jax.Array
+    layer: jax.Array | None = None
+
+    @classmethod
+    def of(cls, bank) -> "ExpertStack":
+        """`bank` as an ExpertStack: as given, or a {"wg", "wi", "wo"}
+        dict's banks with no layer."""
+        if isinstance(bank, ExpertStack):
+            return bank
+        return cls(bank["wg"], bank["wi"], bank["wo"])
+
+    def sliced(self) -> dict:
+        """The layer's {"wg", "wi", "wo"} banks."""
+        return {"wg": layer_slice(self.wg, self.layer),
+                "wi": layer_slice(self.wi, self.layer),
+                "wo": layer_slice(self.wo, self.layer)}
 
 
 class TilePlan(NamedTuple):
@@ -332,7 +358,9 @@ def moe_ffn_fused(x_src: jax.Array, tok: jax.Array, ef: jax.Array,
     x_src [T_src, d] source rows; tok [N] source row per pair; ef [N] lane id
     per pair (expert id, or a group-major lane rank when `expert_of_lane`
     maps lanes back to weight indices); wf [N] combine weights (zeroed pairs
-    contribute nothing — capacity drops reduce to zero weights).
+    contribute nothing — capacity drops reduce to zero weights). `bank` is
+    the {"wg", "wi", "wo"} dict or an ExpertStack, whose layer the kernels
+    read in place from the stacks.
 
     With `num_local > 0`, `bank` holds only the LOCAL expert slice and `ef`
     carries GLOBAL ids: pairs outside [expert_offset, expert_offset +
@@ -375,15 +403,16 @@ def moe_ffn_fused(x_src: jax.Array, tok: jax.Array, ef: jax.Array,
         x_rows = x_z[row_token]
         wf_z = jnp.concatenate([wf.astype(jnp.float32), jnp.zeros((1,))])
         scale = wf_z[plan.row_pair][:, None]
+    wg, wi, wo, layer = ExpertStack.of(bank)
     with jax.named_scope("experts"):
-        h = gmm_swiglu(x_rows, bank["wg"], bank["wi"], te, plan.tile_valid,
+        h = gmm_swiglu(x_rows, wg, wi, te, plan.tile_valid,
                        tile_expert2=te2 if fused else None,
                        row_sel=plan.row_sel if fused else None,
-                       bn=bn, interpret=interpret)
-        y_rows = gmm_scaled(h, bank["wo"], te, plan.tile_valid, scale,
+                       layer=layer, bn=bn, interpret=interpret)
+        y_rows = gmm_scaled(h, wo, te, plan.tile_valid, scale,
                             tile_expert2=te2 if fused else None,
                             row_sel=plan.row_sel if fused else None,
-                            bn=bn, interpret=interpret)
+                            layer=layer, bn=bn, interpret=interpret)
     with jax.named_scope("combine"):
         y = jnp.zeros((num_tokens, x_src.shape[-1]), jnp.float32).at[
             row_token].add(y_rows, mode="drop")
@@ -418,7 +447,8 @@ def go_selected_ffn(x: jax.Array, selected: jax.Array, g: jax.Array,
                     interpret: bool | None = None, topk_hint: int = 0,
                     executor: str = "auto"):
     """C4 decode FFN over ONLY the (token, expert) pairs the TopKUpdate
-    selected. x [B, d]; selected [B, E] bool; g [B, E] softmax affinities.
+    selected. x [B, d]; selected [B, E] bool; g [B, E] softmax affinities;
+    `bank` as `moe_ffn_fused` takes it.
 
     The decode tick's shape is FIXED ([B, E] mask, at most B rows per
     expert), so the plan is static per-lane capacity slots: lane e owns rows
@@ -460,12 +490,13 @@ def go_selected_ffn(x: jax.Array, selected: jax.Array, g: jax.Array,
         idx = jax.lax.top_k(keys, C)[1]                  # [E, C]
         w = jnp.take_along_axis(gsel, idx, axis=1)       # 0 on invalid slots
         if executor == "xla":
+            b = ExpertStack.of(bank).sliced()
             x_disp = x[idx]                              # [E, C, d]
             h = jax.nn.silu(
-                jnp.einsum("ecd,edf->ecf", x_disp, bank["wg"])) * jnp.einsum(
-                "ecd,edf->ecf", x_disp, bank["wi"])
+                jnp.einsum("ecd,edf->ecf", x_disp, b["wg"])) * jnp.einsum(
+                "ecd,edf->ecf", x_disp, b["wi"])
             y = jnp.einsum("ecf,efd->ecd", h,
-                           bank["wo"]).astype(jnp.float32) * w[..., None]
+                           b["wo"]).astype(jnp.float32) * w[..., None]
         else:
             Cp = -(-C // bn) * bn
             idx_p = jnp.pad(idx, ((0, 0), (0, Cp - C)))
@@ -474,9 +505,10 @@ def go_selected_ffn(x: jax.Array, selected: jax.Array, g: jax.Array,
             te = jnp.repeat(jnp.arange(E, dtype=jnp.int32), Cp // bn)
             slot = jnp.arange(Cp // bn, dtype=jnp.int32) * bn
             tv = (slot[None, :] < counts[:, None]).reshape(-1)
-            h = gmm_swiglu(x_rows, bank["wg"], bank["wi"], te, tv, bn=bn,
+            wg, wi, wo, layer = ExpertStack.of(bank)
+            h = gmm_swiglu(x_rows, wg, wi, te, tv, layer=layer, bn=bn,
                            interpret=interpret)
-            y_rows = gmm_scaled(h, bank["wo"], te, tv, scale, bn=bn,
+            y_rows = gmm_scaled(h, wo, te, tv, scale, layer=layer, bn=bn,
                                 interpret=interpret)
             y = y_rows.reshape(E, Cp, d)[:, :C]
         # scatter straight into the token-major contrib buffer (invalid

@@ -52,6 +52,7 @@ from repro.core import quant as Q
 from repro.core.go_cache import (GOCache, go_cache_init, go_cache_init_slot,
                                  go_cache_prefill, go_cache_write_slot)
 from repro.core.grouping import default_groups, group_of_expert_from_groups
+from repro.kernels.ops import ExpertStack
 from repro.models import attention as ATT
 from repro.models import blocks as B
 from repro.models.layers import (dense_init, dtype_of, embed_init, rmsnorm,
@@ -98,6 +99,26 @@ def expert_group_members(cfg) -> jax.Array | None:
     if cfg.moe is None:
         return None
     return jnp.asarray(_moe_deployment(cfg.moe)[1])
+
+
+def _split_experts(layers: dict):
+    """A layer stack without its routed-expert banks, for a serving scan's
+    `xs`, and the [L, E, K, F] banks, which the scan body closes over (None
+    without MoE). Scanned, each step's banks would be a slice of the stacks,
+    copied whole for the grouped GEMM, which reads them in place instead."""
+    moe = layers.get("moe")
+    if moe is None or "experts" not in moe:
+        return layers, None
+    rest = {k: v for k, v in moe.items() if k != "experts"}
+    return {**layers, "moe": rest}, moe["experts"]
+
+
+def _with_experts(lp: dict, banks, l) -> dict:
+    """Layer `l`'s params with its routed experts as an ExpertStack."""
+    if banks is None:
+        return lp
+    return {**lp, "moe": {**lp["moe"], "experts": ExpertStack(
+        banks["wg"], banks["wi"], banks["wo"], l)}}
 
 
 def _maybe_remat(fn, cfg):
@@ -658,6 +679,7 @@ def _dec_attn(params, x, state, cfg):
     qgo = has_go and "go_scales" in state
     kk, vk = ("k_pages", "v_pages") if paged else ("k", "v")
     bt = state["block_table"] if paged else None
+    layers, banks = _split_experts(params["layers"])
 
     # The full KV (and GO) caches ride in the scan CARRY and are updated
     # layer-by-layer with dynamic_update_index — XLA keeps them in place
@@ -667,6 +689,7 @@ def _dec_attn(params, x, state, cfg):
     def body(carry, xs):
         x, K, V, go, l = carry
         lp, w = xs
+        lp = _with_experts(lp, banks, l)
         pick = lambda a: jax.lax.dynamic_index_in_dim(a, l, 0, keepdims=False)
         put = lambda full, new: jax.lax.dynamic_update_index_in_dim(
             full, new.astype(full.dtype), l, 0)
@@ -700,8 +723,7 @@ def _dec_attn(params, x, state, cfg):
     if qgo:
         go0 = (go0, state["go_scales"])
     carry0 = (x, K0, V0, go0, jnp.zeros((), jnp.int32))
-    (x, K, V, go, _), _ = jax.lax.scan(
-        body, carry0, (params["layers"], windows))
+    (x, K, V, go, _), _ = jax.lax.scan(body, carry0, (layers, windows))
     if qkv:
         (state[kk], state["k_scales"]) = K
         (state[vk], state["v_scales"]) = V
@@ -871,10 +893,13 @@ def prefill(params: dict, tokens: jax.Array, cfg, extras: dict | None = None,
     with jax.named_scope("embed"):
         x = embed_tokens(params, tokens, cfg)
     has_go = "go" in state
+    layers, banks = _split_experts(params["layers"])
 
-    def body(x, xs):
+    def body(carry, xs):
+        x, l = carry
         lp, w = xs
-        out = B.attn_block(lp, x, cfg=cfg, positions=positions, window=w,
+        out = B.attn_block(_with_experts(lp, banks, l), x, cfg=cfg,
+                           positions=positions, window=w,
                            group_of_expert=goe, group_members=gm,
                            return_kv=True, valid_len=vl)
         x, aux, k, v = out
@@ -884,14 +909,15 @@ def prefill(params: dict, tokens: jax.Array, cfg, extras: dict | None = None,
             go = go_cache_prefill(
                 None, None, aux["weighted_outputs"], aux["chosen_tokens"],
                 aux["chosen_scores"], e.top_k)
-            return x, (k, v, go)
-        return x, (k, v)
+            return (x, l + 1), (k, v, go)
+        return (x, l + 1), (k, v)
 
     if cfg.cross_attn_every > 0:
         assert valid_len is None, "bucketed prefill: cross-attn archs TODO"
         state, x = _prefill_vlm(params, x, positions, state, cfg)
     else:
-        x, ys = jax.lax.scan(body, x, (params["layers"], windows))
+        (x, _), ys = jax.lax.scan(body, (x, jnp.zeros((), jnp.int32)),
+                                  (layers, windows))
         k, v = ys[0], ys[1]
         L = cfg.num_layers
         state["k"] = jax.lax.dynamic_update_slice(
@@ -956,6 +982,7 @@ def prefill_chunk(params: dict, state: dict, tokens: jax.Array, cfg,
     qkv = paged and "k_scales" in state
     kk, vk = ("k_pages", "v_pages") if paged else ("k", "v")
     bt = state["block_table"] if paged else None
+    layers, banks = _split_experts(params["layers"])
 
     # Quantized pools bundle (pages, scales) in the carry — same tree.map
     # generalization as _dec_attn. The chunk job's GO cache stays full
@@ -964,6 +991,7 @@ def prefill_chunk(params: dict, state: dict, tokens: jax.Array, cfg,
     def body(carry, xs):
         x, K, V, go, l = carry
         lp, w = xs
+        lp = _with_experts(lp, banks, l)
         pick = lambda a: jax.lax.dynamic_index_in_dim(a, l, 0, keepdims=False)
         put = lambda full, new: jax.lax.dynamic_update_index_in_dim(
             full, new.astype(full.dtype), l, 0)
@@ -985,8 +1013,7 @@ def prefill_chunk(params: dict, state: dict, tokens: jax.Array, cfg,
     K0 = (state[kk], state["k_scales"]) if qkv else state[kk]
     V0 = (state[vk], state["v_scales"]) if qkv else state[vk]
     carry0 = (x, K0, V0, state.get("go"), jnp.zeros((), jnp.int32))
-    (x, K, V, go, _), _ = jax.lax.scan(
-        body, carry0, (params["layers"], windows))
+    (x, K, V, go, _), _ = jax.lax.scan(body, carry0, (layers, windows))
     state = dict(state)
     if qkv:
         (state[kk], state["k_scales"]) = K

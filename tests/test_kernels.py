@@ -8,7 +8,7 @@ import pytest
 from conftest import given, settings, st
 from repro.kernels import ref
 from repro.kernels.go_topk import go_topk_update
-from repro.kernels.moe_gmm import gmm, gmm_swiglu
+from repro.kernels.moe_gmm import gmm, gmm_scaled, gmm_swiglu
 from repro.launch.mesh import make_mesh
 
 SWEEP = [
@@ -21,16 +21,50 @@ SWEEP = [
     # non-tile-aligned K/F (registry d=48/96-style dims + K > bk non-divisible)
     (128, 48, 96, 4, 32, jnp.float32),
     (64, 688, 172, 4, 32, jnp.float32),
+    # deepseek's d_expert: K not a multiple of the default bk (a full block)
+    (64, 1408, 128, 4, 32, jnp.float32),
 ]
 
 
-@pytest.mark.parametrize("N,K,F,E,bn,dtype", SWEEP)
-def test_gmm_sweep(N, K, F, E, bn, dtype):
+def _with_layers(cases):
+    """Each case on its own [E, K, F] bank (layer None, the case's own id),
+    then read from a stacked [L, E, K, F] bank at layer 0 and at L - 1."""
+    out = []
+    for c in cases:
+        cid = "-".join(getattr(v, "__name__", str(v)) for v in c)
+        out.append(pytest.param(*c, None, id=cid))
+        out += [pytest.param(*c, l, id=f"{cid}-{n}")
+                for l, n in ((0, "first"), (2, "last"))]
+    return out
+
+
+def _stack(w, layer, key, L=3):
+    """w as layer `layer` of an L-layer stack of random banks (None: w)."""
+    if layer is None:
+        return w
+    stack = jax.random.normal(key, (L,) + w.shape).astype(w.dtype)
+    return stack.at[layer].set(w)
+
+
+def _same_as_bank(fn, banks, layer):
+    """fn on the stacked banks at `layer`, required bit-equal to fn on the
+    layer's own banks; returns the stacked call's result."""
+    out = fn(*banks, layer=layer)
+    if layer is not None:
+        want = fn(*(w[layer] for w in banks), layer=None)
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
+    return out
+
+
+@pytest.mark.parametrize("N,K,F,E,bn,dtype,layer", _with_layers(SWEEP))
+def test_gmm_sweep(N, K, F, E, bn, dtype, layer):
     k1, k2, k3 = jax.random.split(jax.random.PRNGKey(N + K), 3)
     x = (jax.random.normal(k1, (N, K)) * 0.1).astype(dtype)
     w = (jax.random.normal(k2, (E, K, F)) * 0.05).astype(dtype)
     te = jax.random.randint(k3, (N // bn,), 0, E)
-    y = gmm(x, w, te, bn=bn, interpret=True)
+    y = _same_as_bank(
+        lambda w_, layer: gmm(x, w_, te, layer=layer, bn=bn, interpret=True),
+        (_stack(w, layer, k1),), layer)
     y_ref = ref.gmm_ref(x, w, te, bn)
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
     np.testing.assert_allclose(np.asarray(y, np.float32),
@@ -38,14 +72,17 @@ def test_gmm_sweep(N, K, F, E, bn, dtype):
                                rtol=tol, atol=tol)
 
 
-@pytest.mark.parametrize("N,K,F,E,bn,dtype", SWEEP)
-def test_gmm_swiglu_sweep(N, K, F, E, bn, dtype):
+@pytest.mark.parametrize("N,K,F,E,bn,dtype,layer", _with_layers(SWEEP))
+def test_gmm_swiglu_sweep(N, K, F, E, bn, dtype, layer):
     k1, k2, k3, k4 = jax.random.split(jax.random.PRNGKey(N + F), 4)
     x = (jax.random.normal(k1, (N, K)) * 0.1).astype(dtype)
     wg = (jax.random.normal(k2, (E, K, F)) * 0.05).astype(dtype)
     wi = (jax.random.normal(k3, (E, K, F)) * 0.05).astype(dtype)
     te = jax.random.randint(k4, (N // bn,), 0, E)
-    h = gmm_swiglu(x, wg, wi, te, bn=bn, interpret=True)
+    h = _same_as_bank(
+        lambda g, i, layer: gmm_swiglu(x, g, i, te, layer=layer, bn=bn,
+                                       interpret=True),
+        (_stack(wg, layer, k1), _stack(wi, layer, k2)), layer)
     h_ref = ref.gmm_swiglu_ref(x, wg, wi, te, bn)
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
     np.testing.assert_allclose(np.asarray(h, np.float32),
@@ -118,7 +155,6 @@ def test_tile_plan_adversarial(case):
 
 def test_gmm_scaled_matches_ref():
     """Fused-combine gmm: per-row weights applied in-kernel, fp32 out."""
-    from repro.kernels.moe_gmm import gmm_scaled
     k1, k2, k3, k4 = jax.random.split(jax.random.PRNGKey(9), 4)
     N, K, F, E, bn = 128, 96, 80, 4, 32
     x = jax.random.normal(k1, (N, K)) * 0.1
@@ -130,6 +166,67 @@ def test_gmm_scaled_matches_ref():
     y_ref = ref.gmm_scaled_ref(x, w, te, s, bn)
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
                                rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("layer", [0, 2], ids=["first", "last"])
+@pytest.mark.parametrize("K", [96, 1408])
+@pytest.mark.parametrize("kernel,fused", [("scaled", False),
+                                          ("scaled", True),
+                                          ("swiglu", True)])
+def test_gmm_stacked_bank(kernel, fused, K, layer):
+    """The combine and lane-pair variants read layer `layer` of a stacked
+    [L, E, K, F] bank bit-equal to the same call on that layer's bank,
+    including K = 1408, which the default bk (512) does not divide."""
+    N, F, E, bn = 64, 128, 4, 16
+    ks = jax.random.split(jax.random.PRNGKey(K + layer), 6)
+    x = jax.random.normal(ks[0], (N, K)) * 0.1
+    wa = jax.random.normal(ks[1], (3, E, K, F)) * 0.05
+    wb = jax.random.normal(ks[2], (3, E, K, F)) * 0.05
+    te = jnp.sort(jax.random.randint(ks[3], (N // bn,), 0, E))
+    kw = dict(bn=bn, interpret=True)
+    if fused:
+        # a straddle tile wherever the next tile starts on another expert
+        kw.update(tile_expert2=jnp.roll(te, -1),
+                  row_sel=(jnp.arange(N) % bn < bn // 2)[:, None])
+    if kernel == "scaled":
+        s = jax.random.normal(ks[4], (N, 1))
+        y = _same_as_bank(lambda w, layer: gmm_scaled(
+            x, w, te, None, s, layer=layer, **kw), (wa,), layer)
+        if not fused:
+            np.testing.assert_allclose(
+                np.asarray(y), np.asarray(ref.gmm_scaled_ref(
+                    x, wa[layer], te, s, bn)), rtol=2e-5, atol=2e-5)
+    else:
+        _same_as_bank(lambda g, i, layer: gmm_swiglu(
+            x, g, i, te, layer=layer, **kw), (wa, wb), layer)
+
+
+@pytest.mark.parametrize("K,F,mesh,want", [
+    (1536, 512, False, (512, 128, True)),     # defaults divide: in place
+    (1408, 2048, False, (1408, 128, True)),   # K whole: one 1408 block
+    (4096, 688, False, (512, 688, True)),     # F whole: one 688 block
+    (5000, 4096, False, (512, 128, False)),   # too big whole: slice, pad
+    (1536, 512, True, (512, 128, False)),     # GSPMD mesh: slice
+])
+def test_bank_layout_rule(K, F, mesh, want):
+    """Blocks divide the bank: the default, else the whole dimension; a
+    whole-dimension block that would not fit VMEM, or an ambient GSPMD
+    mesh, leaves the stacked bank sliced to its layer."""
+    from repro.kernels.moe_gmm import _bank_layout
+    got = []
+
+    def layout(w):
+        banks, layer, bk, bf = _bank_layout((w,), 1, 512, 128)
+        got.append((bk, bf, banks[0].ndim == 4 and layer is not None))
+        return banks[0]
+
+    w = jax.ShapeDtypeStruct((2, 4, K, F), jnp.bfloat16)
+    if mesh:
+        with jax.set_mesh(make_mesh((1, 1), ("data", "model"))):
+            jax.eval_shape(layout, w)
+    else:
+        jax.eval_shape(layout, w)
+    assert got == [want]
 
 
 def test_gmm_tile_valid_skips_compute():
