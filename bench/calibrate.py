@@ -45,6 +45,10 @@ def main() -> int:
     peaks = load_json(os.path.join(BENCH, "peaks.json"))["devices"][
         dev.device_kind]
     cell = load_cell(args.workload)
+    if len(jax.devices()) < cell.chips:
+        print(f"calibrate: {cell.name} needs {cell.chips} chips",
+              file=sys.stderr)
+        return 2
     for seed in (int(s) for s in args.seeds.split(",")):
         out = run_cell(cell, seed, args.seconds, False, peaks=peaks,
                        t_proc=time.monotonic(), control=True)

@@ -3,7 +3,10 @@
 After the window has closed and the engine's state is freed, a sample of
 the requests the timed path finished, drawn from the seed and holding the
 longest one, goes through the plain reference in float32: prompt and served
-tokens together. Each served token was the program's greedy choice, so the
+tokens together. Where the finished requests hold fewer served tokens than
+the sample asks for (a cell whose requests outlast the window), the
+requests still being served at the close fill it with the tokens they had
+served by then, the longest of them first. Each served token was the program's greedy choice, so the
 reference should rank it at or near its best; the numbers read are how far,
 in logits, the served tokens lie below the reference's best at their
 positions, and the configuration's `limits` name those compared. Requests
@@ -28,19 +31,33 @@ def finished(run) -> list:
             and getattr(r.req.status, "value", r.req.status) == "DONE"]
 
 
+def in_flight(run) -> list:
+    """Requests still being served at the close, with a token served."""
+    return [r for r in run.recs if r.req is not None and r.req.tokens
+            and getattr(r.req.status, "value", r.req.status)
+            not in ("DONE",) + BAD]
+
+
 def sample(run, seed: int) -> list:
-    done = finished(run)
-    if not done:
-        return []
-    longest = max(done, key=lambda r: (r.prompt_len + len(r.req.tokens),
-                                       -r.rid))
-    chosen, served = [longest], len(longest.req.tokens)
-    for i in rng_for(seed, SAMPLE_STREAM).permutation(len(done)):
-        if served >= SAMPLE_TOKENS or len(chosen) >= MAX_SEQUENCES:
-            break
-        if done[i] is not longest:
-            chosen.append(done[i])
-            served += len(done[i].req.tokens)
+    """The finished requests' longest, then a seeded draw of the rest, until
+    SAMPLE_TOKENS served tokens or MAX_SEQUENCES requests; short of that,
+    the requests in flight at the close alike."""
+    rng = rng_for(seed, SAMPLE_STREAM)
+    chosen, served = [], 0
+    for pool in (finished(run), in_flight(run)):
+        if not pool or served >= SAMPLE_TOKENS or \
+                len(chosen) >= MAX_SEQUENCES:
+            continue
+        longest = max(pool, key=lambda r: (r.prompt_len + len(r.req.tokens),
+                                           -r.rid))
+        chosen.append(longest)
+        served += len(longest.req.tokens)
+        for i in rng.permutation(len(pool)):
+            if served >= SAMPLE_TOKENS or len(chosen) >= MAX_SEQUENCES:
+                break
+            if pool[i] is not longest:
+                chosen.append(pool[i])
+                served += len(pool[i].req.tokens)
     return chosen
 
 

@@ -8,7 +8,13 @@ routing, plus the rows read and written (bf16). Shared experts run outside
 these kernels and count for nothing here. The least time per tick is the
 larger of operations / peak and bytes / bandwidth; the share is the least
 time of the traced ticks over the kernels' device time inside the
-`_decode_step` programs, in %."""
+`_decode_step` programs, in %.
+
+Over several chips the least time is the work over all their peaks
+(`run.chips` x one chip's), and the kernels' time is the first chip's
+(every chip runs the same program): a kernel that every chip runs whole,
+on replicated operands, reads at most 1 / chips of what it would on one
+chip. That is the waste such a cell shows."""
 PATTERN = r"gmm"
 PROGRAM = "_decode_step"
 
@@ -32,4 +38,4 @@ def read(run):
         return None
     per_tick = sum(least_s(run.sizes, len(s.decode), run.peaks)
                    for s in ticks) / len(ticks)
-    return 100.0 * per_tick * n / secs
+    return 100.0 * per_tick / run.chips * n / secs

@@ -1,6 +1,7 @@
-"""The whole step's share of the chip's bf16 peak: the model's useful
+"""The whole step's share of the chips' bf16 peak: the model's useful
 operations in the ticks of the traced window (bench/modelflops.py) over
-the window's length times the peak, in %."""
+the window's length times the peak of all the cell's chips (`run.chips` x
+one chip's), in %. Work that every chip repeats counts once."""
 from bench.modelflops import step_flops
 
 
@@ -10,4 +11,5 @@ def read(run):
         return None
     a, b = run.trace_span
     flops = sum(step_flops(run.sizes, s) for s in steps)
-    return 100.0 * flops / ((b - a) * run.peaks["flops_bf16"]) or None
+    return 100.0 * flops / ((b - a) * run.peaks["flops_bf16"]
+                            * run.chips) or None

@@ -7,7 +7,13 @@ the query (bf16) and output (f32). Dead pages and masked key-value heads
 count for nothing. The least time per tick is the larger of operations /
 peak and bytes / bandwidth; the share is the least time of the traced
 ticks over the kernel's device time inside the `_decode_step` programs, in
-%."""
+%.
+
+Over several chips the least time is the work over all their peaks
+(`run.chips` x one chip's), and the kernel's time is the first chip's
+(every chip runs the same program): a kernel that every chip runs whole,
+on replicated operands, reads at most 1 / chips of what it would on one
+chip. That is the waste such a cell shows."""
 PATTERN = r"decode_kernel|paged_attn_decode"
 PROGRAM = "_decode_step"
 
@@ -32,4 +38,4 @@ def read(run):
     ps = run.engine["page_size"]
     per_tick = sum(least_s(run.sizes, ps, s.decode, run.peaks)
                    for s in ticks) / len(ticks)
-    return 100.0 * per_tick * n / secs
+    return 100.0 * per_tick / run.chips * n / secs

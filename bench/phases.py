@@ -60,8 +60,8 @@ class ProgramTrace(Trace):
         spans = sorted(((n, int(s), int(du), dict(a))
                         for n, s, du, a in d.get("spans", [])),
                        key=lambda e: (e[1], -e[2], e[0]))
-        return cls(base.ops, base.modules, base.host, spans,
-                   dict(d.get("scopes", {})))
+        return cls(base.ops, base.modules, base.host, spans=spans,
+                   scopes=dict(d.get("scopes", {})))
 
     @classmethod
     def from_xplane(cls, log_dir: str, hlo_text: str) -> "ProgramTrace":
@@ -78,7 +78,7 @@ class ProgramTrace(Trace):
                         for line in plane.lines for e in line.events
                         if e.name.startswith(PREFIX)),
                        key=lambda e: (e[1], -e[2], e[0]))
-        tr = cls(base.ops, base.modules, base.host, spans, {})
+        tr = cls(base.ops, base.modules, base.host, base.chips, spans)
         hlo = hlo_scopes(hlo_text)
         tr.scopes = {n: hlo[n] for n, *_ in tr.program_ops() if n in hlo}
         return tr

@@ -5,8 +5,12 @@ device sat idle.
 A `Trace` keeps three lists of (name, start_ns, duration_ns) events on one
 clock: `ops` (the operations of the first TPU, line "XLA Ops" of plane
 `/device:TPU:0`, each by its own name), `modules` (its compiled programs,
-line "XLA Modules") and `host` (the harness's own spans). Every cell runs
-on one chip, so the first TPU is the chip used. `from_xplane` reads a profiler output file;
+line "XLA Modules") and `host` (the harness's own spans), and `chips`, the
+"XLA Ops" of every TPU plane in device order (the first is `ops`). A cell
+runs one program on each of its chips, the same SPMD program over a mesh,
+so the readers read the first TPU's plane and it stands for each chip:
+`chip_busy_s` gives each chip's busy time, to see whether it does.
+`from_xplane` reads a profiler output file;
 `from_dict` reads the same lists as JSON, which is how a small recorded
 trace is kept for the tests.
 """
@@ -16,7 +20,7 @@ import bisect
 import glob
 import os
 import re
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 
 HOST_SPANS = ("bench.window", "step", "submit", "observe", "wait")
 WINDOW = "bench.window"
@@ -27,6 +31,9 @@ class Trace:
     ops: list
     modules: list
     host: list
+    # every TPU plane's ops in device order, the first being `ops`
+    # (from_xplane; empty in a recorded trace, which keeps `ops` alone)
+    chips: list = field(default_factory=list)
 
     @classmethod
     def from_dict(cls, d: dict) -> "Trace":
@@ -42,26 +49,39 @@ class Trace:
             raise RuntimeError(f"expected one trace under {log_dir}, found "
                                f"{len(paths)}")
         pd = ProfileData.from_file(paths[0])
-        ops, modules, host = [], [], []
-        chips = sorted((p.name for p in pd.planes
+        modules, host = [], []
+        names = sorted((p.name for p in pd.planes
                         if re.fullmatch(r"/device:TPU:\d+", p.name)),
                        key=lambda n: int(n.rsplit(":", 1)[1]))
+        chips = {n: [] for n in names}
         for plane in pd.planes:
-            if chips and plane.name == chips[0]:
+            if plane.name in chips:
                 for line in plane.lines:
-                    dst = {"XLA Ops": ops, "XLA Modules": modules}.get(
-                        line.name)
-                    if dst is ops:
-                        ops.extend((op_name(e.name), int(e.start_ns),
-                                    int(e.duration_ns)) for e in line.events)
-                    elif dst is not None:
-                        dst.extend((e.name, int(e.start_ns),
-                                    int(e.duration_ns)) for e in line.events)
+                    if line.name == "XLA Ops":
+                        chips[plane.name].extend(
+                            (op_name(e.name), int(e.start_ns),
+                             int(e.duration_ns)) for e in line.events)
+                    elif line.name == "XLA Modules" and \
+                            plane.name == names[0]:
+                        modules.extend((e.name, int(e.start_ns),
+                                        int(e.duration_ns))
+                                       for e in line.events)
             elif plane.name.startswith("/host:"):
                 for line in plane.lines:
                     host.extend((e.name, int(e.start_ns), int(e.duration_ns))
                                 for e in line.events if e.name in HOST_SPANS)
-        return cls(sorted(ops), sorted(modules), sorted(host))
+        ops = [sorted(chips[n]) for n in names]
+        return cls(ops[0] if ops else [], sorted(modules), sorted(host), ops)
+
+    def name_kernels(self, kernels: dict) -> None:
+        """Rename the operations `kernels` maps ({operation: kernel}, from
+        `kernel_names`) after their kernel, keeping the numeric suffix."""
+        def named(name):
+            _, dot, n = name.rpartition(".")
+            k = kernels.get(name)
+            return name if k is None else (k + dot + n if dot else k)
+        for ops in self.chips or [self.ops]:   # in place: `ops` is chips[0]
+            ops[:] = [(named(n), s, d) for n, s, d in ops]
 
     # ------------------------------------------------------------ window
 
@@ -79,12 +99,13 @@ class Trace:
             if b > a:
                 yield name, a, b
 
-    def busy_intervals(self) -> list:
-        """The union of the device's operation intervals in the window."""
+    def busy_intervals(self, ops: list | None = None) -> list:
+        """The union of the device's operation intervals in the window (of
+        `ops`, another chip's, where given)."""
         lo, hi = self.window()
         out = []
-        for _, a, b in sorted(self._clip(self.ops, lo, hi),
-                              key=lambda e: e[1]):
+        for _, a, b in sorted(self._clip(self.ops if ops is None else ops,
+                                         lo, hi), key=lambda e: e[1]):
             if out and a <= out[-1][1]:
                 out[-1][1] = max(out[-1][1], b)
             else:
@@ -93,6 +114,11 @@ class Trace:
 
     def busy_s(self) -> float:
         return sum(b - a for a, b in self.busy_intervals()) / 1e9
+
+    def chip_busy_s(self) -> list:
+        """Each chip's busy seconds in the window, in device order."""
+        return [sum(b - a for a, b in self.busy_intervals(ops)) / 1e9
+                for ops in (self.chips or [self.ops])]
 
     def window_s(self) -> float:
         lo, hi = self.window()
@@ -168,6 +194,20 @@ class Trace:
             tot[key] = tot.get(key, 0) + (b - a)
         return [[k, v / 1e9] for k, v in
                 sorted(tot.items(), key=lambda kv: -kv[1])[:n]]
+
+
+_KERNEL = re.compile(r'^\s*(?:ROOT\s+)?%?([\w.\-]+) = .*custom_call_target='
+                     r'"tpu_custom_call".*op_name="[^"]*jit\((\w+)\)'
+                     r'(?:/shard_map)?/pallas_call"', re.M)
+
+
+def kernel_names(hlo_text: str) -> dict:
+    """{operation: kernel} for the Mosaic kernels of a compiled program's
+    HLO text, the kernel read from the `jit(<kernel>)` its `op_name` metadata
+    ends in. On one chip a kernel's operation bears the kernel's name; under
+    a mesh it runs in a shard_map and its operation is named after that
+    (`shard_map.443`), and a trace's op events carry only names."""
+    return {m.group(1): m.group(2) for m in _KERNEL.finditer(hlo_text)}
 
 
 def op_name(event_name: str) -> str:

@@ -37,6 +37,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 HIGHEST = jax.lax.Precision.HIGHEST
 WEIGHT_STREAM = 2       # the seed's stream for weights (traffic uses 1)
@@ -90,14 +91,21 @@ def seed_key(seed: int, stream: int = WEIGHT_STREAM):
     return jax.random.fold_in(key, s >> 32)
 
 
-def make_weights(sz: dict, seed: int) -> dict:
+def make_weights(sz: dict, seed: int, shardings=None) -> dict:
     """Random weights in the served dtype: matrices N(0, 1/fan_in), the
     embedding N(0, 0.02^2), norm scales 1 + N(0, 0.1^2), router in float32
-    (the program keeps its router in float32)."""
-    return _init(seed_key(seed), tuple(sorted(sz.items())))
+    (the program keeps its router in float32). `shardings`, a tree of
+    shardings in the weights' layout, has the same call make each leaf
+    already laid out so over its devices: the same key gives the same bits
+    (JAX's partitionable random bits), and no leaf is ever whole on one
+    device."""
+    frozen = tuple(sorted(sz.items()))
+    if shardings is None:
+        return _init_jit(seed_key(seed), frozen)
+    return jax.jit(_init, static_argnums=1, out_shardings=shardings)(
+        seed_key(seed), frozen)
 
 
-@functools.partial(jax.jit, static_argnums=1)
 def _init(key, frozen):
     sz = dict(frozen)
     dt = jnp.dtype(sz["dtype"])
@@ -135,6 +143,9 @@ def _init(key, frozen):
     return {"embed": normal(k_embed, (V, d), 0.02),
             "final_norm": norm(k_norm),
             "layers": jax.lax.map(layer, jax.random.split(k_layers, L))}
+
+
+_init_jit = jax.jit(_init, static_argnums=1)
 
 
 # ------------------------------------------------------------- reference
@@ -179,15 +190,13 @@ def _att(spec, a, b, fp8):
     return jnp.einsum(spec, a, b, precision=HIGHEST)
 
 
-@functools.partial(jax.jit, static_argnames=("frozen", "fp8"))
-def _layer(x, lp, *, frozen, fp8):
-    sz = dict(frozen)
+def _attend(x, lp, sz, fp8):
+    """x plus the block's attention."""
     S = x.shape[0]
     hd, hq, hkv = sz["head_dim"], sz["heads"], sz["kv_heads"]
-    eps, k = sz["norm_eps"], sz["top_k"]
     pos = jnp.arange(S)
     a = lp["attn"]
-    h = _rms(x, lp["ln1"]["scale"], eps)
+    h = _rms(x, lp["ln1"]["scale"], sz["norm_eps"])
     q = _rope(_mm(h, a["wq"], fp8).reshape(S, hq, hd), pos, sz["rope_theta"])
     kk = _rope(_mm(h, a["wk"], fp8).reshape(S, hkv, hd), pos,
                sz["rope_theta"])
@@ -197,29 +206,87 @@ def _layer(x, lp, *, frozen, fp8):
     s = _att("shd,thd->hst", q, kk, fp8) * sz["attn_scale"]
     s = jnp.where(pos[None, :, None] >= pos[None, None, :], s, -jnp.inf)
     o = _att("hst,thd->shd", jax.nn.softmax(s, -1), v, fp8)
-    x = x + sz["res_mult"] * _mm(o.reshape(S, hq * hd), a["wo"], fp8)
+    return x + sz["res_mult"] * _mm(o.reshape(S, hq * hd), a["wo"], fp8)
 
-    m = lp["moe"]
-    h = _rms(x, lp["ln2"]["scale"], eps)
-    logits = _mm(h, m["gate"], fp8)
-    top, idx = jax.lax.top_k(jax.nn.softmax(logits, -1), k)
+
+def _route(x, lp, sz, fp8):
+    """The MoE input and each token's weight per expert, [S, E]: zero
+    outside its top k."""
+    h = _rms(x, lp["ln2"]["scale"], sz["norm_eps"])
+    logits = _mm(h, lp["moe"]["gate"], fp8)
+    top, idx = jax.lax.top_k(jax.nn.softmax(logits, -1), sz["top_k"])
     if sz["norm_topk"]:
         top = top / top.sum(-1, keepdims=True)
-    comb = jnp.zeros_like(logits).at[pos[:, None], idx].set(top)  # [S, E]
-    ex = m["experts"]
+    pos = jnp.arange(x.shape[0])
+    return h, jnp.zeros_like(logits).at[pos[:, None], idx].set(top)
 
+
+def _routed(h, comb, ex, n, fp8, first=None):
+    """The routed experts' weighted sum, expert by expert in order, over the
+    n experts of the bank `ex`; they are experts first.. of the router's
+    (`first` None: the bank holds them all)."""
     def expert(e, y):
-        return y + comb[:, e, None] * _ffn(h, ex["wg"][e], ex["wi"][e],
-                                           ex["wo"][e], fp8)
+        col = e if first is None else first + e
+        return y + comb[:, col, None] * _ffn(h, ex["wg"][e], ex["wi"][e],
+                                             ex["wo"][e], fp8)
 
-    y = jax.lax.fori_loop(0, sz["experts"], expert, jnp.zeros_like(x))
+    return jax.lax.fori_loop(0, n, expert, jnp.zeros_like(h))
+
+
+def _shared(h, m, sz, fp8, y):
+    """y plus the shared experts, unweighted."""
     if "shared" in m:
         sh = m["shared"]
         y = jax.lax.fori_loop(
             0, sz["shared_experts"],
             lambda e, y: y + _ffn(h, sh["wg"][e], sh["wi"][e], sh["wo"][e],
                                   fp8), y)
-    return x + sz["res_mult"] * y
+    return y
+
+
+@functools.partial(jax.jit, static_argnames=("frozen", "fp8"))
+def _layer(x, lp, *, frozen, fp8):
+    sz = dict(frozen)
+    x = _attend(x, lp, sz, fp8)
+    h, comb = _route(x, lp, sz, fp8)
+    y = _routed(h, comb, lp["moe"]["experts"], sz["experts"], fp8)
+    return x + sz["res_mult"] * _shared(h, lp["moe"], sz, fp8, y)
+
+
+@functools.partial(jax.jit, static_argnames=("frozen", "fp8", "mesh", "axis"))
+def _layer_split(x, lp, *, frozen, fp8, mesh, axis):
+    """`_layer` with the routed experts split over the mesh axis `axis`, as
+    the weights hold them: each device sums its own experts over every
+    token, the partial sums are added across devices, and everything else
+    is computed whole on each device. The same sums as `_layer`, added in
+    another order."""
+    sz = dict(frozen)
+    n = sz["experts"] // mesh.shape[axis]
+
+    def body(x, lp):
+        x = _attend(x, lp, sz, fp8)
+        h, comb = _route(x, lp, sz, fp8)
+        y = _routed(h, comb, lp["moe"]["experts"], n, fp8,
+                    first=jax.lax.axis_index(axis) * n)
+        y = jax.lax.psum(y, axis)
+        return x + sz["res_mult"] * _shared(h, lp["moe"], sz, fp8, y)
+
+    specs = jax.tree.map(lambda _: P(), lp)
+    specs["moe"]["experts"] = jax.tree.map(lambda _: P(axis),
+                                           lp["moe"]["experts"])
+    return jax.shard_map(body, mesh=mesh, in_specs=(P(), specs),
+                         out_specs=P(), check_vma=False)(x, lp)
+
+
+def _expert_split(weights: dict):
+    """(mesh, axis) where the weights hold the routed experts split over a
+    mesh axis, else None."""
+    sh = getattr(weights["layers"]["moe"]["experts"]["wg"], "sharding", None)
+    spec = getattr(sh, "spec", ())
+    if len(spec) > 1 and isinstance(spec[1], str) and \
+            sh.mesh.shape[spec[1]] > 1:
+        return sh.mesh, spec[1]
+    return None
 
 
 @functools.partial(jax.jit, static_argnames=("frozen", "fp8"))
@@ -237,14 +304,21 @@ def _gap_of(ref, tokens):
 
 def logits(weights: dict, sz: dict, tokens: np.ndarray, *,
            fp8: bool = False) -> jax.Array:
-    """Logits [S, V] (f32) of a token sequence, layer by layer."""
+    """Logits [S, V] (f32) of a token sequence, layer by layer; where the
+    weights hold the routed experts split over a mesh, each layer splits its
+    expert sum alike (`_layer_split`)."""
     frozen = tuple(sorted(sz.items()))
     x = weights["embed"][jnp.asarray(tokens)].astype(jnp.float32) * \
         sz["emb_mult"]
     layers = weights["layers"]
+    split = _expert_split(weights)
     for i in range(sz["layers"]):
         lp = jax.tree.map(lambda a: a[i], layers)
-        x = _layer(x, lp, frozen=frozen, fp8=fp8)
+        if split is None:
+            x = _layer(x, lp, frozen=frozen, fp8=fp8)
+        else:
+            x = _layer_split(x, lp, frozen=frozen, fp8=fp8, mesh=split[0],
+                             axis=split[1])
     return _head(x, weights["final_norm"]["scale"], weights["embed"],
                  frozen=frozen, fp8=fp8)
 

@@ -16,6 +16,11 @@ correctness check compared, with its limit (also the last lines of
 standard error). It exits non-zero, printing no result, when JAX finds no
 TPU or fewer chips than the cell asks for, or the device is not in
 `bench/peaks.json`.
+
+A cell on more than one chip runs over the first `chips` devices: the
+weights made already laid out by the program's serve-mode rules, the
+engine over the program's serving mesh, and the reference's expert sum
+split alike. Memory is the fullest chip's peak.
 """
 from __future__ import annotations
 
@@ -66,6 +71,26 @@ def checkout_cache() -> str:
     return enable_compile_cache()
 
 
+def build(cell, seed: int, ref, sz: dict, pcfg) -> tuple:
+    """The weights from the seed and the program's engine over them, on the
+    cell's chips: (weights, engine)."""
+    import jax
+    if cell.chips == 1:
+        weights = jax.block_until_ready(ref.make_weights(sz, seed))
+        return weights, SV.make_engine(weights, pcfg, cell.traffic["engine"])
+    # over the cell's chips: the engine first, since the program makes its
+    # page pool whole on the first chip before laying it out over the mesh,
+    # and a chip that already holds its share of the weights has no room
+    # for that; then the weights, made already laid out by the program's
+    # serve-mode rules
+    mesh = SV.make_mesh(cell.chips)
+    eng = SV.make_engine(None, pcfg, cell.traffic["engine"], mesh=mesh)
+    shapes = jax.eval_shape(lambda: ref.make_weights(sz, seed))
+    eng.params = jax.block_until_ready(ref.make_weights(
+        sz, seed, SV.weight_shardings(shapes, pcfg, mesh)))
+    return eng.params, eng
+
+
 def run_cell(cell, seed: int, seconds: float, trace: bool, *, peaks: dict,
              t_proc: float, chip: bool = True, control: bool = False,
              keep: list | None = None) -> dict:
@@ -86,8 +111,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *, peaks: dict,
     sz = ref.sizes(conf)
     pcfg = SV.program_config(conf)
     SV.check_program_matches(pcfg, sz)
-    weights = jax.block_until_ready(ref.make_weights(sz, seed))
-    eng = SV.make_engine(weights, pcfg, traffic["engine"])
+    weights, eng = build(cell, seed, ref, sz, pcfg)
     if chip:
         log(f"chip paths: {SV.check_chip_paths(eng)}")
     plan = TR.plan(traffic, seed, seconds, sz["vocab"])
@@ -98,7 +122,8 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *, peaks: dict,
     gc.freeze()
 
     run = SV.Run(sizes=sz, engine=traffic["engine"], peaks=peaks,
-                 seconds=seconds, t_proc=t_proc, compiles=clock)
+                 seconds=seconds, t_proc=t_proc, chips=cell.chips,
+                 compiles=clock)
     trace_dir = tempfile.mkdtemp(prefix="bench-trace-") if trace else None
     drv = SV.Driver(eng, plan, run, trace_dir=trace_dir,
                     trace_s=min(TRACE_SECONDS, seconds) if trace else 0.0)
@@ -106,12 +131,22 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *, peaks: dict,
     if keep is not None:
         keep.append(run)
     stats = eng.stats()
-    dev = jax.devices()[0]
-    peak = (dev.memory_stats() or {}).get("peak_bytes_in_use", 0)
+    devs = jax.devices()[:cell.chips]
+    dev = devs[0]
+    # the fullest chip's peak
+    peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
+               for d in devs)
     if trace:
-        from bench.profile import Trace
+        from bench.profile import Trace, kernel_names
         run.trace = Trace.from_xplane(trace_dir)
         shutil.rmtree(trace_dir, ignore_errors=True)
+        if cell.chips > 1:
+            # under a mesh each kernel's operation is named after the
+            # shard_map it runs in: name it from the compiled tick
+            run.trace.name_kernels(kernel_names(SV.decode_hlo(eng)))
+        share = [b / run.trace.window_s() for b in run.trace.chip_busy_s()]
+        print("device busy share of the traced window by chip: "
+              + ", ".join(f"{x:.4f}" for x in share))
 
     metrics = {}
     for m in (cell.per_layer if trace else cell.end_to_end):
@@ -143,7 +178,8 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *, peaks: dict,
     t_ref = time.monotonic()
     got = (CHK.gap_numbers(CHK.gaps(chosen, weights, sz, ref)) if chosen
            else dict.fromkeys(CHK.NUMBERS))
-    log(f"reference: {len(chosen)} requests, "
+    log(f"reference: {len(chosen)} requests "
+        f"({sum(r.req.status != 'DONE' for r in chosen)} in flight), "
         f"{sum(len(r.req.tokens) for r in chosen)} served tokens, "
         f"{time.monotonic() - t_ref:.1f} s")
     log("gaps: " + ", ".join(f"{k} {v!r}" for k, v in got.items()))

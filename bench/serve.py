@@ -83,9 +83,10 @@ class Run:
     """Everything the end-to-end and per-layer readers read."""
     sizes: dict
     engine: dict
-    peaks: dict
+    peaks: dict           # one chip's
     seconds: float
     t_proc: float
+    chips: int = 1        # the chips the cell runs over
     t_open: float = 0.0
     t_close: float = 0.0
     recs: list = field(default_factory=list)
@@ -195,7 +196,6 @@ def check_chip_paths(eng) -> dict:
     from repro.core.moe import resolve_backend
     from repro.kernels import paged_attn
     from repro.kernels.moe_gmm import default_interpret
-    from repro.serving import engine as ENG
     got = {"moe_backend": resolve_backend(eng.cfg.moe),
            "paged_attention": paged_attn.resolve_mode(eng.cfg),
            "interpret": default_interpret()}
@@ -203,23 +203,53 @@ def check_chip_paths(eng) -> dict:
             "interpret": False}
     if got != want:
         raise RuntimeError(f"chip paths resolved to {got}, want {want}")
-    pool = eng.pool
-    hlo = ENG._decode_step.lower(
-        eng.params, pool.state, jnp.asarray(pool.pending),
-        jnp.asarray(pool.active_mask()), eng.cfg).as_text()
+    hlo = lower_tick(eng).as_text()
     got["decode_tpu_custom_calls"] = hlo.count("tpu_custom_call")
     if not got["decode_tpu_custom_calls"]:
         raise RuntimeError("the decode tick holds no tpu_custom_call")
     return got
 
 
-def make_engine(params, cfg, eng_spec: dict):
+def lower_tick(eng):
+    """The engine's decode tick, lowered as the engine runs it: in its mesh
+    scope, where under a mesh each Mosaic kernel is put in a shard_map
+    (kernels/moe_gmm.py) and outside which the tick does not lower."""
+    from repro.serving import engine as ENG
+    pool = eng.pool
+    with eng._mesh_scope():
+        return ENG._decode_step.lower(
+            eng.params, pool.state, jnp.asarray(pool.pending),
+            jnp.asarray(pool.active_mask()), eng.cfg)
+
+
+def decode_hlo(eng) -> str:
+    """The compiled decode tick's HLO text; the persistent compile cache
+    holds it from the run."""
+    return lower_tick(eng).compile().as_text()
+
+
+def make_mesh(chips: int):
+    """The program's serving mesh over the first `chips` devices: one
+    replica, the model axis over every chip (experts split over it)."""
+    from repro.launch.mesh import make_mesh as program_mesh
+    return program_mesh((1, chips), ("data", "model"),
+                        devices=jax.devices()[:chips])
+
+
+def weight_shardings(shapes, cfg, mesh):
+    """The program's serve-mode layout of the weights over `mesh`."""
+    from repro.launch.sharding import param_shardings
+    return param_shardings(shapes, cfg, mesh, mode="serve")
+
+
+def make_engine(params, cfg, eng_spec: dict, mesh=None):
     from repro.serving import ServingEngine
     return ServingEngine(
         params, cfg, num_slots=eng_spec["slots"],
         max_tokens=eng_spec["max_tokens"], paged=True,
         page_size=eng_spec["page_size"], num_pages=eng_spec.get("num_pages"),
-        prefill_chunk=eng_spec["prefill_chunk"], prompt_buckets=True)
+        prefill_chunk=eng_spec["prefill_chunk"], prompt_buckets=True,
+        mesh=mesh)
 
 
 def prefill_bucket(n: int, max_tokens: int) -> int:

@@ -77,6 +77,10 @@ def main() -> int:
     peaks = load_json(os.path.join(BENCH, "peaks.json"))["devices"][
         dev.device_kind]
     cell = load_cell(args.workload)
+    if len(jax.devices()) < cell.chips:
+        print(f"sweep: {cell.name} needs {cell.chips} chips",
+              file=sys.stderr)
+        return 2
     for i, rate in enumerate(float(r) for r in args.rates.split(",")):
         at = dataclasses.replace(
             cell, traffic={**cell.traffic, "rate_per_s": rate})

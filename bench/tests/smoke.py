@@ -6,6 +6,8 @@ from __future__ import annotations
 import dataclasses
 import json
 import os
+import subprocess
+import sys
 
 from bench.spec import BENCH, Cell
 
@@ -82,3 +84,24 @@ def smoke_of(cell: Cell, *, kernels: bool = True, dtype: str = "float32",
         cell, config=smoke_config(cell.config_name, kernels=kernels,
                                   dtype=dtype),
         traffic={**TRAFFIC, **kind, **traffic})
+
+
+def ran_on_more_devices(chips: int, request) -> bool:
+    """Where this process has fewer devices than `chips`, run the calling
+    test in a child process with that many forced host devices
+    (`--xla_force_host_platform_device_count`, as tests/test_moe_mesh.py
+    does), see it pass, and say so; otherwise leave it to this process."""
+    import jax
+    if chips <= jax.device_count():
+        return False
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " --xla_force_host_"
+                        f"platform_device_count={chips}").strip()
+    out = subprocess.run(
+        [sys.executable, "-m", "pytest", "-rA", "-p", "no:cacheprovider",
+         request.node.nodeid], cwd=str(request.config.rootpath), env=env,
+        capture_output=True, text=True, timeout=1200)
+    assert out.returncode == 0, out.stdout[-3000:] + out.stderr[-2000:]
+    assert out.stdout.count("PASSED ") == 1, out.stdout[-3000:]
+    return True
+

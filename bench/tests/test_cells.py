@@ -14,7 +14,7 @@ import pytest
 
 from bench.run import run_cell
 from bench.spec import BENCH, ROOT, load_cell, load_json
-from bench.tests.smoke import PEAKS, smoke_of
+from bench.tests.smoke import PEAKS, ran_on_more_devices, smoke_of
 
 BENCHMARK = load_json(os.path.join(ROOT, "BENCHMARK.json"))
 CELLS = [w["name"] for w in BENCHMARK["workloads"]]
@@ -27,8 +27,10 @@ def run(cell, seed=SEED, trace=False, **kw):
 
 
 @pytest.mark.parametrize("name", CELLS)
-def test_cell_end_to_end_at_smoke_size(name, capsys):
+def test_cell_end_to_end_at_smoke_size(name, capsys, request):
     cell = load_cell(name)
+    if ran_on_more_devices(cell.chips, request):
+        return
     out = run(smoke_of(cell))
     assert out["correct"] is True, out["compared"]
     assert out["attempted"] > 0 and out["failed"] == 0
@@ -112,6 +114,38 @@ def test_a_dropped_request_is_not_correct(monkeypatch):
     assert out["compared"]["failed_requests"]["value"] >= 1
 
 
+def _rec(rid, prompt, served, status):
+    from types import SimpleNamespace
+
+    from bench import serve as SV
+    from bench import traffic as TR
+    r = SV.Rec(planned=TR.Planned(index=rid, prompt=np.zeros(prompt, np.int32),
+                                  max_new=served, due_s=0.0), due=0.0, rid=rid)
+    r.req = SimpleNamespace(status=status, tokens=[1] * served)
+    return r
+
+
+def test_requests_in_flight_fill_a_short_sample():
+    """Where the finished requests serve fewer tokens than the sample asks
+    for, the requests in flight at the close fill it, their longest first;
+    where they serve enough, the sample holds finished requests alone."""
+    from types import SimpleNamespace
+
+    from bench import check as CHK
+    done = [_rec(i, 10, 30, "DONE") for i in range(4)]
+    flying = [_rec(4 + i, 10 + i, 50, "ACTIVE") for i in range(6)]
+    others = [_rec(10, 900, 0, "ACTIVE"), _rec(11, 900, 40, "FAILED"),
+              _rec(12, 900, 0, "QUEUED")]
+    chosen = CHK.sample(SimpleNamespace(recs=done + flying + others), SEED)
+    assert sorted(r.rid for r in chosen[:4]) == [0, 1, 2, 3]
+    assert chosen[4] is flying[-1]
+    assert {r.rid for r in chosen[4:]} <= {r.rid for r in flying}
+    assert len(chosen) == CHK.MAX_SEQUENCES
+    long_done = [_rec(i, 10, 300, "DONE") for i in range(3)]
+    chosen = CHK.sample(SimpleNamespace(recs=long_done + flying), SEED)
+    assert len(chosen) == 2 and all(r.req.status == "DONE" for r in chosen)
+
+
 # ------------------------------------------------------------ the control
 
 # Served in bfloat16 as the configurations state, at a smoke size with
@@ -128,9 +162,11 @@ LONG = {"output_tokens": {"dist": "uniform", "min": 20, "max": 40},
 
 @pytest.mark.parametrize("name", sorted({load_cell(c).config_name
                                          for c in CELLS}))
-def test_control_is_not_correct(name):
+def test_control_is_not_correct(name, request):
     cell = next(load_cell(c) for c in CELLS
                 if load_cell(c).config_name == name)
+    if ran_on_more_devices(cell.chips, request):
+        return
     cell = smoke_of(cell, dtype="bfloat16", **LONG)
     for seed in (SEED, SEED + 1, SEED + 2):
         out = run(cell, seed=seed, control=True)
@@ -177,6 +213,9 @@ def test_a_new_cell_traffic_and_metric_are_files_and_entries(tmp_path):
         f.write("def read(run):\n    return float(len(run.steps)) or None\n")
     bench["workloads"].append({**base, "name": "new.cell",
                                "traffic": "new-mix"})
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if base["name"] in m.get("workloads", ()):
+            m["workloads"].append("new.cell")       # the metrics it reports
     bench["per_layer"].append({
         "name": "ticks_run", "unit": "count", "better": "higher",
         "source": "program_counter", "layer": "serving engine",

@@ -115,3 +115,27 @@ def test_mfu_reader_never_reads_zero():
                           traced_steps=lambda: [], sizes=GRANITE,
                           peaks=PEAKS)
     assert reader("mfu.decode")(run) is None
+
+
+@pytest.mark.parametrize("name", ["gmm_roofline.decode",
+                                  "paged_attn_decode_roofline", "mfu.decode"])
+def test_device_readers_count_every_chips_peak(name):
+    """Over four chips the least time, or the peak, is four chips': the
+    same trace and ticks read a quarter of what they read over one."""
+    import json
+    from types import SimpleNamespace
+
+    from bench.profile import Trace
+    from bench.serve import Step
+    with open(os.path.join(BENCH, "tests", "data", "trace_excerpt.json")) as f:
+        tr = Trace.from_dict(json.load(f))
+    lo, hi = tr.window()
+    steps = [Step(t0=0, t1=1, decode=[300 + 7 * i for i in range(8)])]
+
+    def read(chips):
+        return reader(name)(SimpleNamespace(
+            trace=tr, traced_steps=lambda: steps, trace_span=(lo, hi),
+            sizes=GRANITE, peaks=PEAKS, chips=chips,
+            engine={"page_size": 16, "slots": 16, "max_tokens": 4096}))
+    one = read(1)
+    assert one > 0 and read(4) == pytest.approx(one / 4, rel=1e-12)

@@ -48,8 +48,8 @@ sys.path[:0] = [ROOT, os.path.join(ROOT, "src")]
 from bench import phases as PH  # noqa: E402
 from bench import serve as SV  # noqa: E402
 from bench import traffic as TR  # noqa: E402
-from bench.run import (TRACE_SECONDS, checkout_cache, log,  # noqa: E402
-                       reference)
+from bench.run import (TRACE_SECONDS, build, checkout_cache,  # noqa: E402
+                       log, reference)
 from bench.spec import BENCH, load_cell, load_json, reader  # noqa: E402
 
 
@@ -74,17 +74,6 @@ def span_cost_us(n: int = 200_000) -> float:
         with span("engine.step"):
             pass
     return (time.perf_counter() - t0) / n * 1e6
-
-
-def decode_hlo(eng) -> str:
-    """The compiled decode tick's HLO text (the persistent compile cache
-    holds it from the run)."""
-    import jax.numpy as jnp
-    from repro.serving import engine as ENG
-    pool = eng.pool
-    return ENG._decode_step.lower(
-        eng.params, pool.state, jnp.asarray(pool.pending),
-        jnp.asarray(pool.active_mask()), eng.cfg).compile().as_text()
 
 
 def median_ms(xs: list) -> float | None:
@@ -152,8 +141,7 @@ def trace_run(cell, seed: int, seconds: float, *, peaks: dict,
     sz = ref.sizes(conf)
     pcfg = SV.program_config(conf)
     SV.check_program_matches(pcfg, sz)
-    weights = jax.block_until_ready(ref.make_weights(sz, seed))
-    eng = SV.make_engine(weights, pcfg, traffic["engine"])
+    _, eng = build(cell, seed, ref, sz, pcfg)
     if chip:
         log(f"chip paths: {SV.check_chip_paths(eng)}")
     plan = TR.plan(traffic, seed, seconds, sz["vocab"])
@@ -161,14 +149,15 @@ def trace_run(cell, seed: int, seconds: float, *, peaks: dict,
     gc.collect()
     gc.freeze()
     run = SV.Run(sizes=sz, engine=traffic["engine"], peaks=peaks,
-                 seconds=seconds, t_proc=T_PROC, compiles=clock)
+                 seconds=seconds, t_proc=T_PROC, chips=cell.chips,
+                 compiles=clock)
     trace_dir = tempfile.mkdtemp(prefix="bench-trace-")
     drv = TickDriver(eng, plan, run, trace_dir=trace_dir,
                      trace_s=min(TRACE_SECONDS, seconds))
     drv.loop()
     gc.unfreeze()
     cost_us = span_cost_us()
-    tr = PH.ProgramTrace.from_xplane(trace_dir, decode_hlo(eng))
+    tr = PH.ProgramTrace.from_xplane(trace_dir, SV.decode_hlo(eng))
     shutil.rmtree(trace_dir, ignore_errors=True)
     run.trace = tr
 
@@ -255,6 +244,9 @@ def main(argv=None) -> int:
         dev.device_kind)
     if dev.platform != "tpu" or peaks is None:
         log(f"trace_cell: no TPU with known peaks ({dev.device_kind})")
+        return 2
+    if len(jax.devices()) < cell.chips:
+        log(f"trace_cell: {cell.name} needs {cell.chips} chips")
         return 2
     out, exc = trace_run(cell, args.seed, args.seconds, peaks=peaks)
     text = json.dumps(out)
