@@ -19,6 +19,12 @@ class MoEConfig:
     d_expert: int                     # hidden width of each expert FFN
     num_shared_experts: int = 0       # deepseek-style always-on experts
     routing: str = "token_choice"     # "token_choice" | "expert_choice"
+    # token-choice scores: "softmax" keeps the top-k of softmax(x W_G);
+    # "sigmoid" (DeepSeek-V3 noaux_tc) picks the top-k of sigmoid(x W_G)
+    # plus a per-expert bias, weights them by the unbiased scores,
+    # renormalised; both then scale the routed sum by `routed_scale`
+    scoring: str = "softmax"          # "softmax" | "sigmoid"
+    routed_scale: float = 1.0
     # --- paper technique knobs (C1-C3) ---
     group_size: int = 1               # crossbar-multiplexing analogue: experts per shared lane
     grouping: str = "sorted"          # "uniform" | "sorted" (load-aware, C2)
@@ -56,6 +62,15 @@ class ModelConfig:
 
     # attention details
     qkv_bias: bool = False            # qwen2
+    # multi-head latent attention (DeepSeek-V2/V3): kv_lora_rank > 0 caches
+    # one normalised latent of this width and one shared rotary key of
+    # qk_rope_dim per token; head_dim is then the query/key width per head
+    # (its last qk_rope_dim dims rotary), v_head_dim the value width
+    kv_lora_rank: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    first_dense_layers: int = 0       # leading SwiGLU layers (width d_ff)
+                                      # before the MoE stack
     rope_theta: float = 10000.0
     sliding_window: int = 0           # >0 enables local attention layers
     local_global_ratio: int = 0       # gemma3: N local layers per 1 global
@@ -112,33 +127,58 @@ class ModelConfig:
     def with_overrides(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)
 
+    @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    def latent_width(self) -> int:
+        """Values cached per token and layer by latent attention: the
+        latent and the shared rotary key."""
+        return self.kv_lora_rank + self.qk_rope_dim
+
+    def latent_row_width(self) -> int:
+        """A latent cache row as stored: `latent_width` values, then zeros
+        up to the next 128 lanes. A page of rows is then a whole number of
+        TPU lane tiles, as the page must be for the kernel to stage it in
+        one copy: a row of 576 would leave the device free to store the
+        pool with the page index innermost, and every kernel call would
+        first copy the whole pool back into rows."""
+        return -(-self.latent_width() // 128) * 128
+
     def param_count(self) -> int:
         """Analytic parameter count (used for MODEL_FLOPS in the roofline)."""
         d, hd = self.d_model, self.resolved_head_dim()
         nq, nkv = self.num_heads, self.num_kv_heads
-        attn = d * hd * nq + 2 * d * hd * nkv + hd * nq * d
+        if self.mla:
+            # W_q, W_kva (latent + rotary key), W_kvb (keys, values), W_o
+            r, nope = self.kv_lora_rank, hd - self.qk_rope_dim
+            attn = (d * nq * hd + d * self.latent_width()
+                    + r * nq * (nope + self.v_head_dim)
+                    + nq * self.v_head_dim * d)
+        else:
+            attn = d * hd * nq + 2 * d * hd * nkv + hd * nq * d
         if self.block == "attn":
             mix = attn
         elif self.block == "xlstm":
             # mLSTM block: up(2x) + q/k/v (on 2d inner) + gates + down
             di = 2 * d
-            mix = d * di * 2 + 3 * di * di // max(1, nq) * nq // max(1, nq) + di * d
-            mix = d * di * 2 + 3 * di * hd * nq // max(nq, 1) + di * d  # approx
             mix = 2 * d * di + 3 * di * di + di * d
         elif self.block == "mamba2":
             di = 2 * d
             mix = d * (2 * di + 2 * self.ssm_state) + di * d
         else:
             raise ValueError(self.block)
+        nd = self.first_dense_layers
+        dense_ffn = 3 * d * self.d_ff
         if self.moe is not None:
             e = self.moe
             ffn = (e.num_experts + e.num_shared_experts) * 3 * d * e.d_expert
             ffn += d * e.num_experts  # gate
         elif self.d_ff > 0:
-            ffn = 3 * d * self.d_ff  # gated SwiGLU
+            ffn = dense_ffn  # gated SwiGLU
         else:
             ffn = 0
-        layers = self.num_layers * (mix + ffn)
+        layers = (self.num_layers - nd) * (mix + ffn) + nd * (mix + dense_ffn)
         if self.attn_every:
             layers += attn  # zamba2 shared attention block params
         if self.cross_attn_every:
@@ -156,9 +196,9 @@ class ModelConfig:
             return self.param_count()
         e = self.moe
         d = self.d_model
-        full_ffn = self.num_layers * (e.num_experts + e.num_shared_experts) * 3 * d * e.d_expert
-        act_ffn = self.num_layers * (e.top_k + e.num_shared_experts) * 3 * d * e.d_expert
-        return self.param_count() - full_ffn + act_ffn
+        moe_layers = self.num_layers - self.first_dense_layers
+        idle = moe_layers * (e.num_experts - e.top_k) * 3 * d * e.d_expert
+        return self.param_count() - idle
 
 
 @dataclass(frozen=True)

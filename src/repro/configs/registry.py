@@ -17,6 +17,7 @@ _MODULES = {
     "gemma3-27b": "gemma3_27b",
     "deepseek-moe-16b": "deepseek_moe_16b",
     "granite-moe-3b-a800m": "granite_moe_3b_a800m",
+    "moonlight-16b-a3b": "moonlight_16b_a3b",
     "zamba2-1.2b": "zamba2_1_2b",
     "llama-3.2-vision-90b": "llama_3_2_vision_90b",
     "whisper-base": "whisper_base",

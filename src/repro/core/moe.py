@@ -115,6 +115,9 @@ def moe_init(key, d_model: int, e: MoEConfig, dtype) -> dict:
         "gate": dense_init(ks[0], d_model, E, jnp.float32),
         "experts": bank(ks[1], ks[2], ks[3], E),
     }
+    if e.scoring == "sigmoid":
+        # DeepSeek-V3's e_score_correction_bias: chooses, never weights
+        p["bias"] = jnp.zeros((E,), jnp.float32)
     if e.num_shared_experts:
         p["shared"] = bank(ks[4], ks[5], ks[6], e.num_shared_experts)
     return p
@@ -156,7 +159,7 @@ def dense_forward(params: dict, x: jax.Array, e: MoEConfig) -> jax.Array:
     T = x.shape[0]
     eo = expert_ffn_all(params, x)                       # [T, E, d]
     if e.routing == "token_choice":
-        r = R.token_choice(x, params["gate"], e.top_k)
+        r = R.route(x, params, e)
         mask = jnp.zeros((T, e.num_experts), jnp.float32)
         mask = jax.vmap(lambda m, i, w: m.at[i].add(w))(mask, r.expert_idx, r.weights)
     else:
@@ -234,7 +237,7 @@ def dispatch_forward(params: dict, x: jax.Array, e: MoEConfig,
     E, k = e.num_experts, e.top_k
     C = capacity or max(1, int(math.ceil(T * k / E * e.capacity_factor)))
     with jax.named_scope("router"):
-        r = R.token_choice(x, params["gate"], k)
+        r = R.route(x, params, e)
     expert_flat = r.expert_idx.reshape(-1).astype(jnp.int32)
     weights_flat = r.weights.reshape(-1)
     token_flat = jnp.repeat(jnp.arange(T, dtype=jnp.int32), k)
@@ -258,7 +261,7 @@ def _dispatch_forward_pallas(params: dict, x: jax.Array, e: MoEConfig) -> tuple:
     T = x.shape[0]
     E, k = e.num_experts, e.top_k
     with jax.named_scope("router"):
-        r = R.token_choice(x, params["gate"], k)
+        r = R.route(x, params, e)
     ef = r.expert_idx.reshape(-1).astype(jnp.int32)
     wf = r.weights.reshape(-1)
     tok = jnp.repeat(jnp.arange(T, dtype=jnp.int32), k)
@@ -300,7 +303,7 @@ def group_forward(params: dict, x: jax.Array, e: MoEConfig,
     if resolve_backend(e, (x, params)) == "pallas":
         return _group_forward_pallas(params, x, e, group_of_expert, members,
                                      C_grp)
-    r = R.token_choice(x, params["gate"], k)
+    r = R.route(x, params, e)
     expert_flat = r.expert_idx.reshape(-1).astype(jnp.int32)
     grp_flat = group_of_expert[expert_flat]
     weights_flat = r.weights.reshape(-1)
@@ -403,7 +406,7 @@ def _group_forward_pallas(params: dict, x: jax.Array, e: MoEConfig,
     T = x.shape[0]
     E, k, g = e.num_experts, e.top_k, e.group_size
     G = E // g
-    r = R.token_choice(x, params["gate"], k)
+    r = R.route(x, params, e)
     ef = r.expert_idx.reshape(-1).astype(jnp.int32)
     grp = group_of_expert[ef]
     wf = r.weights.reshape(-1)
@@ -589,12 +592,12 @@ def moe_forward_ep(params: dict, h: jax.Array, e: MoEConfig) -> tuple:
     use_pallas = resolve_backend(e, (h, params)) == "pallas"
     bn = _block_rows(e)
 
-    def body(h_loc, gate, wg, wi, wo):
+    def body(h_loc, router, wg, wi, wo):
         i = jax.lax.axis_index("model")
         lo = i * E_loc
 
         def per_seq(xb):
-            r = R.token_choice(xb, gate, k)
+            r = R.route(xb, router, e)
             ef = r.expert_idx.reshape(-1).astype(jnp.int32)
             wf = r.weights.reshape(-1)
             tok = jnp.repeat(jnp.arange(S, dtype=jnp.int32), k)
@@ -636,7 +639,8 @@ def moe_forward_ep(params: dict, h: jax.Array, e: MoEConfig) -> tuple:
                   P("model", None, None), P("model", None, None)),
         out_specs=(P(dp, None, None), P(), P("model"), P()),
         check_vma=False,
-    )(h, params["gate"], bank["wg"], bank["wi"], bank["wo"])
+    )(h, {k: params[k] for k in ("gate", "bias") if k in params},
+      bank["wg"], bank["wi"], bank["wo"])
 
     y = y.astype(h.dtype) + jax.vmap(lambda xb: _shared_out(params, xb))(h)
     aux = {"counts": cnt, "balance_loss": bal, "dropped": dropped}

@@ -36,6 +36,41 @@ def token_choice(x: jax.Array, w_gate: jax.Array, k: int) -> TokenChoiceRouting:
     return TokenChoiceRouting(top_i.astype(jnp.int32), w, s)
 
 
+def sigmoid_choice(x: jax.Array, w_gate: jax.Array, bias: jax.Array,
+                   k: int) -> TokenChoiceRouting:
+    """DeepSeek-V3 `noaux_tc` with one expert group: the top-k of
+    sigmoid(x W_G) + bias, weighted by the unbiased sigmoid scores — the
+    bias steers which experts are chosen, never how much each adds — and
+    renormalised to sum to 1. `scores` holds the raw logits, as in
+    `token_choice`. The logits are computed in float32 at full precision,
+    as the source computes its gate: the sigmoid narrows their spread, and
+    a one-pass bfloat16 product (the TPU's default for float32) would flip
+    the biased top-k choice often."""
+    s = jnp.matmul(x.astype(jnp.float32), w_gate.astype(jnp.float32),
+                   precision=jax.lax.Precision.HIGHEST)      # [T, E]
+    p = jax.nn.sigmoid(s)
+    _, top_i = jax.lax.top_k(p + bias.astype(jnp.float32), k)
+    w = jnp.take_along_axis(p, top_i, axis=-1)
+    w = w / jnp.sum(w, axis=-1, keepdims=True)
+    return TokenChoiceRouting(top_i.astype(jnp.int32), w, s)
+
+
+def route(x: jax.Array, router: dict, e) -> TokenChoiceRouting:
+    """Token-choice routing as the MoE config `e` scores it. `router` holds
+    the layer's `gate` [d, E] and, for sigmoid scoring, its `bias` [E]. The
+    weights carry `e.routed_scale`; the softmax router with scale 1 is
+    `token_choice` as it is."""
+    if e.scoring == "softmax":
+        r = token_choice(x, router["gate"], e.top_k)
+    elif e.scoring == "sigmoid":
+        r = sigmoid_choice(x, router["gate"], router["bias"], e.top_k)
+    else:
+        raise ValueError(f"unknown MoE scoring {e.scoring!r}")
+    if e.routed_scale != 1.0:
+        r = r._replace(weights=r.weights * e.routed_scale)
+    return r
+
+
 def expert_choice(x: jax.Array, w_gate: jax.Array, capacity: int,
                   valid_len=None) -> ExpertChoiceRouting:
     """Zhou et al. expert-choice: G = softmax over experts; each expert takes

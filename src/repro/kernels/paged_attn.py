@@ -23,6 +23,11 @@ Kernels:
       chunked prefill: a [B, Cs] query chunk attends over the prefix's
       pages (causal within the chunk) without re-materializing the dense
       layout per chunk.
+  mla_paged_decode(q, pages, layer, block_table, t)        -> [B, H, rank]
+      latent attention: every head's absorbed query scores a block of
+      128 // ps pages of latent rows of the stacked [L, NP, ps, W] pool,
+      read in place with the layer scalar-prefetched; the rows' first
+      `rank` columns are the values.
 
 Masking rules (matching the gather path exactly):
   decode   k_pos <= t,              and k_pos > t - window     (window > 0)
@@ -275,6 +280,114 @@ def _paged_attn_decode(q, k_pages, v_pages, block_table, t_vec, window,
     ), bt, tv, wv, q, *_page_operands(k_pages, v_pages, k_scales, v_scales))
 
 
+# --------------------------------------------------- latent-attention decode
+
+def _mla_decode_kernel(bt_ref, tv_ref, lv_ref, q_ref, *refs, ps: int,
+                       rank: int, steps: int, scale: float):
+    """Grid (b, j): batch row b, step j over `len(page_refs)` consecutive
+    logical pages (innermost, the online-softmax axis). Scalar-prefetched:
+    block table [B, P], positions [B], layer [1]. The step's pages [ps, W]
+    each are stacked into one block of latent rows; every query head scores
+    them as keys and takes their first `rank` columns as values, so a page
+    is staged once for all heads."""
+    *page_refs, o_ref, m_ref, l_ref, acc_ref = refs
+    b, j = pl.program_id(0), pl.program_id(1)
+    t = tv_ref[b]
+
+    @pl.when(j == 0)
+    def _init():
+        _reset(m_ref, l_ref, acc_ref)
+
+    base = j * len(page_refs) * ps
+
+    @pl.when(base <= t)
+    def _attend():
+        q = q_ref[...]
+        c = jnp.concatenate([r[...] for r in page_refs], axis=0)
+        s = jax.lax.dot_general(q, c, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        k_pos = base + jax.lax.broadcasted_iota(jnp.int32, (1, c.shape[0]), 1)
+        s = jnp.where(k_pos <= t, s, NEG_INF)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_ref[...] = l_ref[...] * corr + p.sum(axis=-1, keepdims=True)
+        pv = jnp.dot(p.astype(c.dtype), c[:, :rank],
+                     preferred_element_type=jnp.float32)
+        acc_ref[...] = acc_ref[...] * corr + pv
+        m_ref[...] = m_new
+
+    @pl.when(j == steps - 1)
+    def _done():
+        _finish(o_ref, l_ref, acc_ref)
+
+
+def mla_paged_decode(q, pages, layer, block_table, t, *, rank: int,
+                     scale: float, interpret: bool | None = None) -> jax.Array:
+    """Single-token latent attention over a paged latent pool, read in place.
+
+    q [B, H, W] (the absorbed query: latent part, rotary part, zeros);
+    pages [L, NP, ps, W] the stacked pool of every layer (the new token's
+    row already written), W a multiple of 128; `layer` the traced layer to
+    read, a scalar-prefetch operand, so no layer of the pool is sliced or
+    copied;
+    block_table [B, P] int32; t scalar or [B] int32. Keys are a row's W
+    columns, values its first `rank`. A grid step takes 128 // ps pages
+    (one 128-position block of rows), so the per-step cost is paid once for
+    several pages. Returns fp32 [B, H, rank]: the attended latent, before
+    W_UV."""
+    if interpret is None:
+        interpret = default_interpret()
+    B = q.shape[0]
+    t_vec = jnp.broadcast_to(jnp.asarray(t, jnp.int32).reshape(-1), (B,))
+    return _mla_paged_decode(q, pages, jnp.asarray(layer, jnp.int32),
+                             block_table, t_vec, rank=int(rank),
+                             scale=float(scale), interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("rank", "scale", "interpret"))
+def _mla_paged_decode(q, pages, layer, block_table, t_vec, *, rank, scale,
+                      interpret):
+    B, H, W = q.shape
+    ps = pages.shape[2]
+    P = block_table.shape[1]
+    per = min(P, max(1, 128 // ps))       # pages a grid step stages
+    steps = -(-P // per)
+    bt = block_table.astype(jnp.int32)
+    tv = t_vec.astype(jnp.int32)
+    lv = layer.astype(jnp.int32).reshape(1)
+
+    def page_index(i):
+        # pages past the row's position repeat its last live page, so the
+        # pipeline skips their copies (their compute is skipped in-kernel,
+        # or masked within the step that holds the row's position)
+        def index(b, j, bt, tv, lv):
+            return lv[0], bt[b, jnp.minimum(j * per + i, tv[b] // ps)], 0, 0
+        return pl.BlockSpec((pl.squeezed, pl.squeezed, ps, W), index)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(B, steps),
+        in_specs=[pl.BlockSpec((pl.squeezed, H, W),
+                               lambda b, j, bt, tv, lv: (b, 0, 0))]
+        + [page_index(i) for i in range(per)],
+        out_specs=pl.BlockSpec((pl.squeezed, H, rank),
+                               lambda b, j, bt, tv, lv: (b, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((H, 1), jnp.float32),
+                        pltpu.VMEM((H, 1), jnp.float32),
+                        pltpu.VMEM((H, rank), jnp.float32)],
+    )
+    return mosaic_call(pl.pallas_call(
+        functools.partial(_mla_decode_kernel, ps=ps, rank=rank, steps=steps,
+                          scale=scale),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, rank), jnp.float32),
+        interpret=interpret,
+        name="_mla_paged_decode",
+    ), bt, tv, lv, q, *[pages] * per)
+
+
 # -------------------------------------------------------------- chunk prefill
 
 SCORE_BUDGET = 1 << 18  # f32 elements of one chunk step's [rows, ps*Hkv] scores
@@ -406,9 +519,13 @@ def _paged_attn_chunk(q, k_pages, v_pages, block_table, start, kv_len,
 # ------------------------------------------------------------ traffic model
 
 def page_bytes(cfg, page_size: int) -> int:
-    """HBM bytes one physical page costs to stage (K + V), per layer.
-    Quantized pools pay int8 values plus one f32 scale per (page, kv
-    head) — the scale operand the kernel gathers alongside the page."""
+    """HBM bytes one physical page costs to stage (K + V, or a latent
+    page), per layer. Quantized pools pay int8 values plus one f32 scale
+    per (page, kv head) — the scale operand the kernel gathers alongside
+    the page."""
+    if cfg.mla:
+        return (page_size * cfg.latent_row_width()
+                * jnp.dtype(cfg.dtype).itemsize)
     hd = cfg.resolved_head_dim()
     if getattr(cfg, "kv_quant", "none") == "int8":
         return 2 * (page_size * cfg.num_kv_heads * hd

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 
 from repro.core import quant as Q
 from repro.models.layers import (apply_rope, dense_init, dp_spec, mesh_axis,
+                                 rmsnorm, rmsnorm_init, rope_angles,
                                  shard_hint, split)
 
 NEG_INF = -1e30
@@ -615,3 +616,196 @@ def cross_attn_decode(params: dict, x_t: jax.Array, memory: jax.Array, *, cfg):
     mask = jnp.ones((Sk,), bool)
     out = _decode_sdpa(q, k, v, mask, cfg.logit_softcap)
     return out.astype(x_t.dtype).reshape(B, 1, nq * hd) @ params["wo"]
+
+
+# ------------------------------------------- multi-head latent attention
+
+def mla_init(key, cfg) -> dict:
+    """Latent-attention weights (DeepSeek-V2/V3, no query LoRA). `wq`
+    [d, H*hd]: each head's `hd - rope` plain then `rope` rotary query
+    columns; `wkva` [d, rank + rope]: the latent, then the shared rotary
+    key; `kv_norm` the latent's RMSNorm; `wkv_b` [rank, H, nope + v]: each
+    head's key (first `nope`) and value up-projections of the latent; `wo`
+    [H*v, d]. The rotary columns are in this module's half-split layout
+    (`layers.apply_rope`): a checkpoint in the interleaved-pair layout of
+    the published model has its rotary columns of `wq` and `wkva` permuted
+    to [0, 2, .., rope-2, 1, 3, .., rope-1] on loading."""
+    d, H, hd = cfg.d_model, cfg.num_heads, cfg.resolved_head_dim()
+    r, vd = cfg.kv_lora_rank, cfg.v_head_dim
+    nope = hd - cfg.qk_rope_dim
+    dt = jnp.dtype(cfg.dtype)
+    k1, k2, k3, k4 = split(key, 4)
+    return {
+        "wq": dense_init(k1, d, H * hd, dt),
+        "wkva": dense_init(k2, d, cfg.latent_width(), dt),
+        "kv_norm": rmsnorm_init(r),
+        "wkv_b": dense_init(k3, r, H * (nope + vd), dt).reshape(
+            r, H, nope + vd),
+        "wo": dense_init(k4, H * vd, d, dt),
+    }
+
+
+def _mla_project(params, x, positions, cfg):
+    """x [B, S, d] at `positions` ([S], or [B, S]) -> (plain query part
+    [B, S, H, nope], rotated query part [B, S, H, rope], latent rows
+    [B, S, cfg.latent_row_width()]: the normalised latent, the rotated
+    shared key, zeros — what the cache holds)."""
+    B, S, _ = x.shape
+    H, hd, rd = cfg.num_heads, cfg.resolved_head_dim(), cfg.qk_rope_dim
+    r = cfg.kv_lora_rank
+    cos, sin = rope_angles(positions, rd, cfg.rope_theta)
+    cos, sin = cos[..., None, :], sin[..., None, :]
+    with jax.named_scope("mla.q"):
+        q = (x @ params["wq"]).reshape(B, S, H, hd)
+        q_rope = apply_rope(q[..., hd - rd:], cos, sin)
+    with jax.named_scope("mla.kv_a"):
+        kv = x @ params["wkva"]
+        c = rmsnorm(params["kv_norm"], kv[..., :r], cfg.norm_eps)
+        k_rope = apply_rope(kv[..., None, r:], cos, sin)[..., 0, :]
+        pad = jnp.zeros((*c.shape[:-1], cfg.latent_row_width()
+                         - cfg.latent_width()), c.dtype)
+        row = jnp.concatenate([c, k_rope.astype(c.dtype), pad], axis=-1)
+    return q[..., :hd - rd], q_rope, row
+
+
+def _mla_plain(params, q_nope, q_rope, lat, q_pos, k_pos, kv_len, cfg, *,
+               flash: bool):
+    """The published (non-absorbed) form over latent rows `lat` [B, Sk, W]:
+    per head k = [c W_UK | k_rope], v = c W_UV, scores (q . k) / sqrt(hd),
+    causal softmax. Returns [B, Sq, H*v]. The values ride the blocked
+    attention padded to the query width and are cut back after it."""
+    B, Sk, _ = lat.shape
+    H, hd, r, vd = (cfg.num_heads, cfg.resolved_head_dim(), cfg.kv_lora_rank,
+                    cfg.v_head_dim)
+    nope = hd - cfg.qk_rope_dim
+    if vd > hd:
+        raise ValueError(f"v_head_dim={vd} > head_dim={hd}")
+    kvb = jnp.einsum("bsc,chn->bshn", lat[..., :r], params["wkv_b"])
+    k_rope = jnp.broadcast_to(lat[:, :, None, r:r + hd - nope],
+                              (B, Sk, H, hd - nope))
+    k = jnp.concatenate([kvb[..., :nope], k_rope.astype(kvb.dtype)], -1)
+    v = jnp.pad(kvb[..., nope:], ((0, 0), (0, 0), (0, 0), (0, hd - vd)))
+    q = jnp.concatenate([q_nope, q_rope.astype(q_nope.dtype)], -1)
+    if flash:
+        out = sdpa_flash(q, k, v, q_pos, k_pos, jnp.asarray(0, jnp.int32),
+                         kv_len, True, 0.0, 1024)
+    else:
+        out = sdpa_chunked(q, k, v, q_pos, k_pos, jnp.asarray(0, jnp.int32),
+                           kv_len, causal=True)
+    Sq = q.shape[1]
+    return out[..., :vd].reshape(B, Sq, H * vd)
+
+
+def mla_forward(params: dict, x: jax.Array, *, cfg,
+                positions: jax.Array) -> tuple:
+    """Full-sequence causal latent attention, x [B, S, d] -> (out
+    [B, S, d], latent rows [B, S, cfg.latent_row_width()] for the
+    cache)."""
+    S = x.shape[1]
+    q_nope, q_rope, row = _mla_project(params, x, positions, cfg)
+    pos = positions.astype(jnp.int32)
+    with jax.named_scope("mla.attend"):
+        out = _mla_plain(params, q_nope, q_rope, row, pos, pos,
+                         jnp.asarray(S + 10**9, jnp.int32), cfg, flash=True)
+    with jax.named_scope("mla.out"):
+        out = out @ params["wo"]
+    return out, row
+
+
+def _gather_latent(cache, l, block_table):
+    """Layer `l`'s latent rows of each batch row [B, Smax, W]: a dense
+    [L, B, Smax, W] cache's slice, or a paged [L, NP, ps, W] pool's pages
+    gathered through the block table (no slice of the layer's pool)."""
+    if block_table is None:
+        return jax.lax.dynamic_index_in_dim(cache, l, 0, keepdims=False)
+    B, P = block_table.shape
+    return cache[l, block_table].reshape(B, P * cache.shape[2], -1)
+
+
+def mla_chunk(params: dict, x: jax.Array, cache, l, start, *, cfg, kv_len,
+              block_table=None) -> tuple:
+    """Chunked-prefill latent attention: append the chunk's latent rows (x
+    [B, Cs, d] at start..start+Cs-1) to layer `l` of the stacked cache —
+    dense [L, B, Smax, W], or with `block_table` the page pool
+    [L, NP, ps, W] — and attend over everything cached so far in the
+    published form. `kv_len` masks the last chunk's pads. Returns (out,
+    cache)."""
+    B, Cs, _ = x.shape
+    positions = jnp.asarray(start, jnp.int32) + jnp.arange(Cs, dtype=jnp.int32)
+    q_nope, q_rope, row = _mla_project(params, x, positions, cfg)
+    with jax.named_scope("kv_write"):
+        row = row.astype(cache.dtype)
+        if block_table is None:
+            cache = jax.lax.dynamic_update_slice(
+                cache, row[None], (l, 0, positions[0], 0))
+        else:
+            ps = cache.shape[2]
+            rows = jnp.arange(B)
+            pages = block_table[rows[:, None], positions[None, :] // ps]
+            cache = cache.at[l, pages, positions[None, :] % ps].set(row)
+    with jax.named_scope("mla.attend"):
+        lat = _gather_latent(cache, l, block_table)
+        k_pos = jnp.arange(lat.shape[1], dtype=jnp.int32)
+        out = _mla_plain(params, q_nope, q_rope, lat.astype(x.dtype),
+                         positions, k_pos, jnp.asarray(kv_len, jnp.int32),
+                         cfg, flash=False)
+    with jax.named_scope("mla.out"):
+        out = out.astype(x.dtype) @ params["wo"]
+    return out, cache
+
+
+def mla_decode(params: dict, x_t: jax.Array, cache, l, t, *, cfg,
+               block_table=None) -> tuple:
+    """Single-token latent attention in the absorbed form. x_t [B, 1, d] at
+    positions t (scalar or [B]); `cache` is the stacked latent cache (dense
+    [L, B, Smax, W], or with `block_table` the page pool [L, NP, ps, W]) and
+    `l` the layer. Only the new token's latent row is written. The query's
+    plain part is taken into the latent space (q W_UK^T), so every head
+    scores the cached rows directly — keys are all W columns of a row,
+    values its first `rank` — and the attended latent leaves through W_UV:
+    the same sums as the published form, in another order. The query is
+    padded with zeros to the stored row width, so the rows' zero tail adds
+    nothing to a score. Returns (out [B, 1, d], cache)."""
+    B = x_t.shape[0]
+    H, hd, r, vd = (cfg.num_heads, cfg.resolved_head_dim(), cfg.kv_lora_rank,
+                    cfg.v_head_dim)
+    nope = hd - cfg.qk_rope_dim
+    t_vec = jnp.broadcast_to(jnp.asarray(t, jnp.int32).reshape(-1), (B,))
+    q_nope, q_rope, row = _mla_project(params, x_t, t_vec[:, None], cfg)
+    rows = jnp.arange(B)
+    with jax.named_scope("kv_write"):
+        new = row[:, 0].astype(cache.dtype)
+        if block_table is None:
+            cache = cache.at[l, rows, t_vec].set(new)
+        else:
+            ps = cache.shape[2]
+            cache = cache.at[l, block_table[rows, t_vec // ps],
+                             t_vec % ps].set(new)
+    w = params["wkv_b"]
+    with jax.named_scope("mla.absorb"):
+        q_lat = jnp.einsum("bhn,chn->bhc", q_nope[:, 0], w[..., :nope],
+                           preferred_element_type=jnp.float32)
+        pad = jnp.zeros((B, H, cfg.latent_row_width() - cfg.latent_width()),
+                        x_t.dtype)
+        q = jnp.concatenate([q_lat.astype(x_t.dtype),
+                             q_rope[:, 0].astype(x_t.dtype), pad], -1)
+    scale = hd ** -0.5
+    with jax.named_scope("mla.attend"):
+        from repro.kernels import paged_attn as PAGED
+        if block_table is not None and PAGED.resolve_mode(cfg) == "kernel":
+            o = PAGED.mla_paged_decode(q, cache, l, block_table, t_vec,
+                                       rank=r, scale=scale)
+        else:
+            lat = _gather_latent(cache, l, block_table)        # [B, S, W]
+            s = jnp.einsum("bhw,bsw->bhs", q, lat.astype(q.dtype),
+                           preferred_element_type=jnp.float32) * scale
+            k_pos = jnp.arange(lat.shape[1], dtype=jnp.int32)
+            s = jnp.where((k_pos[None, :] <= t_vec[:, None])[:, None, :], s,
+                          NEG_INF)
+            p = jax.nn.softmax(s, axis=-1)
+            o = jnp.einsum("bhs,bsc->bhc", p.astype(lat.dtype), lat[..., :r],
+                           preferred_element_type=jnp.float32)
+    with jax.named_scope("mla.out"):
+        o = jnp.einsum("bhc,chv->bhv", o.astype(x_t.dtype), w[..., nope:])
+        out = o.reshape(B, 1, H * vd) @ params["wo"]
+    return out, cache

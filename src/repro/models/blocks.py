@@ -27,7 +27,8 @@ def attn_block_init(key, cfg, *, use_moe: bool = False, cross: bool = False,
     k1, k2 = jax.random.split(key)
     p = {
         "ln1": rmsnorm_init(cfg.d_model),
-        "attn": ATT.attn_init(k1, cfg, cross=cross),
+        "attn": (ATT.mla_init(k1, cfg) if cfg.mla
+                 else ATT.attn_init(k1, cfg, cross=cross)),
         "ln2": rmsnorm_init(cfg.d_model),
     }
     if use_moe:
@@ -198,6 +199,60 @@ def attn_block_chunk(params: dict, x: jax.Array, cache_k, cache_v, start, *,
     return x, ck, cv, go_cache, aux
 
 
+# ------------------------------------------- latent-attention (+FFN) block
+
+def mla_block(params: dict, x: jax.Array, *, cfg, positions,
+              group_of_expert=None, group_members=None,
+              valid_len=None) -> tuple:
+    """Full-sequence latent-attention block: (x, aux, latent rows
+    [B, S, rank + rope] for the cache)."""
+    with jax.named_scope("attn"):
+        h = rmsnorm(params["ln1"], x, cfg.norm_eps)
+        a, rows = ATT.mla_forward(params["attn"], h, cfg=cfg,
+                                  positions=positions)
+        x = x + a
+    x, aux = _ffn_apply(params, x, cfg, group_of_expert, group_members,
+                        valid_len)
+    return x, aux, rows
+
+
+def mla_block_chunk(params: dict, x: jax.Array, cache, l, start, *, cfg,
+                    valid_len, group_of_expert=None, group_members=None,
+                    block_table=None) -> tuple:
+    """Chunked-prefill latent-attention block over layer `l` of the stacked
+    latent cache (attention.py::mla_chunk). Returns (x, cache)."""
+    start = jnp.asarray(start, jnp.int32)
+    with jax.named_scope("attn"):
+        h = rmsnorm(params["ln1"], x, cfg.norm_eps)
+        a, cache = ATT.mla_chunk(params["attn"], h, cache, l, start, cfg=cfg,
+                                 kv_len=start + valid_len,
+                                 block_table=block_table)
+        x = x + a
+    x, _ = _ffn_apply(params, x, cfg, group_of_expert, group_members,
+                      valid_len)
+    return x, cache
+
+
+def mla_block_decode(params: dict, x_t: jax.Array, cache, l, t, *, cfg,
+                     block_table=None) -> tuple:
+    """One-token latent-attention block over layer `l` of the stacked latent
+    cache (attention.py::mla_decode), then its dense or token-choice MoE
+    FFN. Returns (x, cache)."""
+    with jax.named_scope("attn"):
+        h = rmsnorm(params["ln1"], x_t, cfg.norm_eps)
+        a, cache = ATT.mla_decode(params["attn"], h, cache, l, t, cfg=cfg,
+                                  block_table=block_table)
+        x = x_t + a
+    h2 = rmsnorm(params["ln2"], x, cfg.norm_eps)
+    if "moe" in params:
+        with jax.named_scope("moe"):
+            y = MOE.token_choice_decode(params["moe"], h2[:, 0], cfg.moe)
+            x = x + y[:, None, :]
+    else:
+        x = x + mlp(params["mlp"], h2)
+    return x, cache
+
+
 def cross_block_decode(params: dict, x_t: jax.Array, memory, *, cfg) -> jax.Array:
     """Cross-attention block decode (static memory, no cache growth)."""
     h = rmsnorm(params["ln1"], x_t, cfg.norm_eps)
@@ -226,7 +281,7 @@ def mamba2_block_decode(params: dict, x_t: jax.Array, state, *, cfg) -> tuple:
 
 __all__ = [
     "attn_block_init", "attn_block", "attn_block_decode", "attn_block_chunk",
-    "cross_block_decode",
+    "cross_block_decode", "mla_block", "mla_block_chunk", "mla_block_decode",
     "mamba2_block_init", "mamba2_block", "mamba2_block_decode",
     "mlstm_block_init", "mlstm_block", "slstm_block_init", "slstm_block",
 ]

@@ -3,7 +3,8 @@
 One schema (`ModelConfig`) drives five structural families:
 
   attn      dense / MoE decoder-only transformers (starcoder2, granite-8b,
-            qwen2, gemma3, deepseek-moe, granite-moe, llama_moe_4_16)
+            qwen2, gemma3, deepseek-moe, granite-moe, llama_moe_4_16;
+            moonlight with latent attention and a leading dense layer)
   attn+enc  whisper-style encoder-decoder (encoder_layers > 0)
   attn+x    llama-3.2-vision: cross-attention image layers every Nth layer
   xlstm     mLSTM stacks with interleaved sLSTM blocks
@@ -26,7 +27,10 @@ Decode state comes in two layouts: DENSE (per-slot KV rows
 page_size))` — a shared page pool [L, NP, ps, h, hd] plus a per-slot
 block_table of physical page ids; serving/pool.py owns the host-side page
 allocator). serve_step picks the attention path from the state's keys, so
-both layouts run through the same engine.
+both layouts run through the same engine. A latent-attention model
+(cfg.kv_lora_rank > 0) caches one latent row a token and layer instead of
+K and V — `c` [L, B, max_len, W] dense, `c_pages` [L, NP, ps, W] paged —
+and each layer writes and reads its rows in place through its layer index.
 
 Decode positions: `state["t"]` is either a scalar (static batch — every row in
 lock-step, the classic generate() path) or an int32 vector [B] (per-slot —
@@ -181,8 +185,15 @@ def model_init(key, cfg) -> dict:
                 ks[4], n_sup,
                 lambda k: B.attn_block_init(k, cfg, cross=True))
         else:
+            nd = cfg.first_dense_layers
+            if nd and not cfg.mla:
+                raise ValueError("leading dense layers are implemented for "
+                                 "latent-attention models only")
+            if nd:
+                p["dense_layers"] = stack_init(
+                    ks[6], nd, lambda k: B.attn_block_init(k, cfg))
             p["layers"] = stack_init(
-                ks[3], cfg.num_layers,
+                ks[3], cfg.num_layers - nd,
                 lambda k: B.attn_block_init(k, cfg, use_moe=use_moe))
     elif cfg.block == "xlstm":
         n_seg, n_m = _xlstm_segments(cfg)
@@ -226,6 +237,8 @@ def model_forward(params: dict, tokens: jax.Array, cfg, extras: dict | None = No
     positions = jnp.arange(S, dtype=jnp.int32)
     if cfg.block == "attn" and cfg.cross_attn_every > 0:
         return _fwd_vlm(params, x, positions, cfg, extras)
+    if cfg.block == "attn" and cfg.mla:
+        return _fwd_mla(params, x, positions, cfg)
     if cfg.block == "attn":
         return _fwd_attn(params, x, positions, cfg)
     if cfg.block == "xlstm":
@@ -252,6 +265,54 @@ def _fwd_attn(params, x, positions, cfg):
     (x, bal), _ = jax.lax.scan(
         _maybe_remat(body, cfg), (x, jnp.zeros((), jnp.float32)),
         (params["layers"], windows))
+    return rmsnorm(params["final_norm"], x, cfg.norm_eps), bal
+
+
+def _scan_layers(params, cfg, step, carry, *, split_experts: bool = True,
+                 remat: bool = False):
+    """Apply `step(lp, l, carry) -> (carry, y)` to every layer of a
+    latent-attention model in order, `l` the absolute layer index: the
+    leading dense layers (`dense_layers`), then the MoE layers, whose
+    routed-expert banks are read in place from the stack (ExpertStack)
+    when `split_experts`. Returns (carry, the ys of all layers stacked, or
+    None)."""
+    segs = []
+    if cfg.first_dense_layers:
+        segs.append((params["dense_layers"], None, 0))
+    layers, banks = (_split_experts(params["layers"]) if split_experts
+                     else (params["layers"], None))
+    segs.append((layers, banks, cfg.first_dense_layers))
+    ys = []
+    for stack, bank, off in segs:
+        def body(c, lp, bank=bank, off=off):
+            inner, l = c
+            inner, y = step(_with_experts(lp, bank, l - off), l, inner)
+            return (inner, l + 1), y
+        if remat:
+            body = jax.checkpoint(body)
+        (carry, _), y = jax.lax.scan(
+            body, (carry, jnp.asarray(off, jnp.int32)), stack)
+        ys.append(y)
+    if ys[0] is None:
+        return carry, None
+    return carry, jax.tree.map(lambda *a: jnp.concatenate(a), *ys)
+
+
+def _fwd_mla(params, x, positions, cfg):
+    goe = expert_groups(cfg)
+    gm = expert_group_members(cfg)
+
+    def step(lp, l, carry):
+        x, bal = carry
+        x, aux, _ = B.mla_block(lp, x, cfg=cfg, positions=positions,
+                                group_of_expert=goe, group_members=gm)
+        if aux is not None and "balance_loss" in aux:
+            bal = bal + jnp.sum(aux["balance_loss"])
+        return (x, bal), None
+
+    (x, bal), _ = _scan_layers(params, cfg, step,
+                               (x, jnp.zeros((), jnp.float32)),
+                               split_experts=False, remat=cfg.remat)
     return rmsnorm(params["final_norm"], x, cfg.norm_eps), bal
 
 
@@ -417,6 +478,17 @@ def loss_fn(params: dict, batch: dict, cfg):
 
 # --------------------------------------------------------------- decode state
 
+# each page store of a paged decode state -> its dense counterpart, which is
+# also its name in a preemption snapshot
+PAGE_STORES = {"k_pages": "k", "v_pages": "v", "c_pages": "c"}
+
+
+def page_stores(state: dict) -> list:
+    """The page stores a paged decode state holds: K and V pages, or the
+    latent pages of a latent-attention model."""
+    return [k for k in PAGE_STORES if k in state]
+
+
 def kv_cache_spec(cfg, batch: int, max_len: int):
     hd = cfg.resolved_head_dim()
     return (batch, max_len, cfg.num_kv_heads, hd)
@@ -460,6 +532,14 @@ def init_decode_state(cfg, batch: int, max_len: int,
             raise ValueError(f"max_len={max_len} must be a multiple of "
                              f"page_size={ps}")
         Q.validate_kv_quant(cfg.kv_quant)
+        if cfg.mla:
+            if cfg.kv_quant != "none":
+                raise ValueError(f"kv_quant={cfg.kv_quant!r}: the latent "
+                                 "page pool is not quantized")
+            st["block_table"] = jnp.zeros((batch, max_len // ps), jnp.int32)
+            st["c_pages"] = jnp.zeros(
+                (cfg.num_layers, num_pages, ps, cfg.latent_row_width()), dt)
+            return st
         quant = cfg.kv_quant == "int8"
         L = cfg.num_layers
         hd = cfg.resolved_head_dim()
@@ -501,6 +581,9 @@ def init_decode_state(cfg, batch: int, max_len: int,
         st["v"] = jnp.zeros((n_sup * n_self, *shp), dt)
         st["memory"] = extras.get(
             "memory", jnp.zeros((batch, cfg.num_image_tokens, cfg.d_model), dt))
+    elif cfg.block == "attn" and cfg.mla:
+        st["c"] = jnp.zeros(
+            (cfg.num_layers, batch, max_len, cfg.latent_row_width()), dt)
     elif cfg.block == "attn":
         L = cfg.num_layers
         st["k"] = jnp.zeros((L, *shp), dt)
@@ -553,7 +636,7 @@ def init_decode_slot(state: dict, slot) -> dict:
         st["t"] = jnp.zeros((), jnp.int32)
     if "block_table" in st:
         st["block_table"] = st["block_table"].at[slot].set(0)
-    for key in ("k", "v"):
+    for key in ("k", "v", "c"):
         if key in st:
             st[key] = st[key].at[:, slot].set(0)
     if "go" in st:
@@ -587,18 +670,19 @@ def write_decode_slot(state: dict, slot, src: dict, page_ids=None) -> dict:
         assert page_ids is not None, "paged pool: pass the slot's page_ids"
         pid = jnp.asarray(page_ids, jnp.int32)
         st["block_table"] = st["block_table"].at[slot].set(pid)
-        L, _, ps, h, hd = st["k_pages"].shape
         P = pid.shape[0]
         quant = "k_scales" in st
-        for key, srck in (("k_pages", "k"), ("v_pages", "v")):
+        for key in page_stores(st):
+            srck = PAGE_STORES[key]
             if srck not in src:
                 # paged-native chunk prefill: the chunk run already scattered
                 # its KV into the pool's pages — nothing to splat here
                 continue
+            L, _, ps = st[key].shape[:3]
             assert src[srck].shape[2] == P * ps, \
                 f"{srck}: prefill len {src[srck].shape[2]} != pool " \
                 f"max_tokens {P * ps} (prefill must use the pool's max_len)"
-            pages = src[srck][:, 0].reshape(L, P, ps, h, hd)
+            pages = src[srck][:, 0].reshape(L, P, ps, *st[key].shape[3:])
             if quant:
                 # splat-quantize: each page against its own amax — a pure
                 # function of the tokens, independent of pool history
@@ -608,7 +692,7 @@ def write_decode_slot(state: dict, slot, src: dict, page_ids=None) -> dict:
                 st[sk] = st[sk].at[:, pid].set(sc)
             else:
                 st[key] = st[key].at[:, pid].set(pages.astype(st[key].dtype))
-    for key in ("k", "v"):
+    for key in ("k", "v", "c"):
         if key in st:
             assert st[key].shape[2:] == src[key].shape[2:], \
                 f"{key}: pool {st[key].shape} vs slot {src[key].shape} " \
@@ -669,7 +753,28 @@ def serve_step(params: dict, state: dict, tokens_t: jax.Array, cfg):
     return logits, state
 
 
+def _dec_mla(params, x, state, cfg):
+    """Decode through a latent-attention stack: the stacked latent cache
+    (dense `c`, or the paged pool `c_pages`) rides the carry whole, each
+    layer writes its new row in place and reads its layer through the
+    layer index — no per-layer slice of the cache."""
+    t = state["t"]
+    key = "c_pages" if "c_pages" in state else "c"
+    bt = state.get("block_table")
+
+    def step(lp, l, carry):
+        x, cache = carry
+        x, cache = B.mla_block_decode(lp, x, cache, l, t, cfg=cfg,
+                                      block_table=bt)
+        return (x, cache), None
+
+    (x, state[key]), _ = _scan_layers(params, cfg, step, (x, state[key]))
+    return x, state
+
+
 def _dec_attn(params, x, state, cfg):
+    if cfg.mla:
+        return _dec_mla(params, x, state, cfg)
     t = state["t"]
     windows = jnp.asarray(layer_windows(cfg))
     goe = expert_groups(cfg)
@@ -915,6 +1020,16 @@ def prefill(params: dict, tokens: jax.Array, cfg, extras: dict | None = None,
     if cfg.cross_attn_every > 0:
         assert valid_len is None, "bucketed prefill: cross-attn archs TODO"
         state, x = _prefill_vlm(params, x, positions, state, cfg)
+    elif cfg.mla:
+        def step(lp, l, x):
+            x, _, rows = B.mla_block(lp, x, cfg=cfg, positions=positions,
+                                     group_of_expert=goe, group_members=gm,
+                                     valid_len=vl)
+            return x, rows
+
+        x, rows = _scan_layers(params, cfg, step, x)
+        state["c"] = jax.lax.dynamic_update_slice(
+            state["c"], rows.astype(state["c"].dtype), (0, 0, 0, 0))
     else:
         (x, _), ys = jax.lax.scan(body, (x, jnp.zeros((), jnp.int32)),
                                   (layers, windows))
@@ -977,6 +1092,8 @@ def prefill_chunk(params: dict, state: dict, tokens: jax.Array, cfg,
     gm = expert_group_members(cfg)
     with jax.named_scope("embed"):
         x = embed_tokens(params, tokens, cfg)
+    if cfg.mla:
+        return _chunk_mla(params, state, x, cfg, start, vl, goe, gm)
     has_go = "go" in state
     paged = "block_table" in state
     qkv = paged and "k_scales" in state
@@ -1022,6 +1139,29 @@ def prefill_chunk(params: dict, state: dict, tokens: jax.Array, cfg,
         state[kk], state[vk] = K, V
     if has_go:
         state["go"] = go
+    with jax.named_scope("head"):
+        x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
+        logits = logits_from_hidden(params, jnp.take(x, vl - 1, axis=1), cfg)
+    state["t"] = start + vl
+    return state, logits
+
+
+def _chunk_mla(params, state, x, cfg, start, vl, goe, gm):
+    """prefill_chunk for a latent-attention stack: each layer appends the
+    chunk's latent rows to the stacked cache (`c`, or the paged `c_pages`)
+    and attends over the prefix."""
+    key = "c_pages" if "c_pages" in state else "c"
+    bt = state.get("block_table")
+
+    def step(lp, l, carry):
+        x, cache = carry
+        x, cache = B.mla_block_chunk(
+            lp, x, cache, l, start, cfg=cfg, valid_len=vl,
+            group_of_expert=goe, group_members=gm, block_table=bt)
+        return (x, cache), None
+
+    state = dict(state)
+    (x, state[key]), _ = _scan_layers(params, cfg, step, (x, state[key]))
     with jax.named_scope("head"):
         x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
         logits = logits_from_hidden(params, jnp.take(x, vl - 1, axis=1), cfg)

@@ -101,7 +101,8 @@ import numpy as np
 
 from repro.core import quant as Q
 from repro.kernels.paged_attn import decode_tick_pages
-from repro.models.model import (init_decode_state, paged_supported, prefill,
+from repro.models.model import (init_decode_state, page_stores,
+                                paged_supported, prefill,
                                 prefill_chunk as _model_prefill_chunk,
                                 serve_step)
 from repro.runtime.fault import StepSupervisor
@@ -229,9 +230,10 @@ class TickRecord:
     """What one engine tick asked of the device, from values the engine
     already holds on the host (`engine.last_tick`). The same counters ride
     as arguments on the tick's `engine.step` span (`counters()`).
-    Paged-attention pages are per layer: `live_pages` the decode rows' live
-    pages, `grid_pages` the decode kernel's grid, B x P steps, retired rows
-    included (both 0 on a tick without decode or on a dense pool)."""
+    Paged-attention pages are per layer — K/V pages, or a latent-attention
+    model's latent pages: `live_pages` the decode rows' live pages,
+    `grid_pages` the pages the decode kernel's grid covers, B x P,
+    retired rows included (both 0 on a tick without decode or on a dense pool)."""
     step: int = 0                    # engine step count after the tick
     decode_rows: int = 0             # rows the decode program advanced
     chunks: list = field(default_factory=list)   # (start, valid) per run
@@ -309,10 +311,12 @@ class ServingEngine:
         # static compile key). The REPRO_KV_QUANT env lane silently no-ops
         # where the pool won't be paged or the page geometry can't tile int8
         # pages; the explicit kwarg is an API contract — SlotPool raises a
-        # typed error when it can't honor it.
+        # typed error when it can't honor it. The latent pool of a
+        # latent-attention model has no int8 form, prefix entries or journal
+        # payload yet: the env lanes skip such an engine, the kwargs refuse.
         if kv_quant is None:
             if _env_on("REPRO_KV_QUANT") and paged and paged_supported(cfg) \
-                    and page_size % 8 == 0:
+                    and page_size % 8 == 0 and not cfg.mla:
                 cfg = cfg.with_overrides(kv_quant="int8")
         else:
             cfg = cfg.with_overrides(kv_quant=kv_quant)
@@ -326,10 +330,14 @@ class ServingEngine:
         # whose shape can't support them; the explicit kwargs are API
         # contracts and raise instead.
         if prefix_share is None:
-            prefix_share = _env_on("REPRO_PREFIX_SHARE") and self.pool.paged
+            prefix_share = _env_on("REPRO_PREFIX_SHARE") and self.pool.paged \
+                and not cfg.mla
         elif prefix_share and not self.pool.paged:
             raise ValueError("prefix sharing needs a paged pool (it is "
                              "copy-on-write block-table surgery)")
+        elif prefix_share and cfg.mla:
+            raise ValueError("prefix sharing does not handle the latent page "
+                             "pool of a latent-attention model yet")
         self.prefix_share = bool(prefix_share)
         # expert-aware admission needs observable routing: a plain-attention
         # MoE stack (the gate probe reads the stacked layer-0 gate)
@@ -445,7 +453,8 @@ class ServingEngine:
             prefix_share=self.prefix_share, expert_aware=self.expert_aware)
         if journal_dir is None and journal_dir is not False:
             env_dir = os.environ.get("REPRO_JOURNAL_DIR", "").strip()
-            if env_dir and self.pool.paged and extras is None:
+            if env_dir and self.pool.paged and extras is None \
+                    and not cfg.mla:
                 # unique per engine: one journal describes ONE engine's
                 # lifecycle (sweeps build many engines per process)
                 journal_dir = os.path.join(
@@ -1240,7 +1249,8 @@ class ServingEngine:
             state = init_decode_state(skel_cfg, 1, self.pool.max_tokens,
                                       req.extras or {},
                                       paged=(1, self.pool.page_size))
-            del state["k_pages"], state["v_pages"]
+            for key in page_stores(state):
+                del state[key]
             state["block_table"] = jnp.asarray(page_row, jnp.int32)[None, :]
         else:
             state = init_decode_state(self.cfg, 1, self.pool.max_tokens,
@@ -1269,8 +1279,8 @@ class ServingEngine:
             # scatters its KV into the job's claimed pages (disjoint from
             # every active slot's), interleaved decode ticks touch only
             # other pages, so ownership transfers cleanly back each tick
-            job.state["k_pages"] = self.pool.state["k_pages"]
-            job.state["v_pages"] = self.pool.state["v_pages"]
+            for key in page_stores(self.pool.state):
+                job.state[key] = self.pool.state[key]
             if self.pool.quant:
                 job.state["k_scales"] = self.pool.state["k_scales"]
                 job.state["v_scales"] = self.pool.state["v_scales"]
@@ -1281,8 +1291,8 @@ class ServingEngine:
         with self._mesh_scope():
             job.state, job.logits = _jit_prefill_chunk(*args)
         if paged:
-            self.pool.state["k_pages"] = job.state.pop("k_pages")
-            self.pool.state["v_pages"] = job.state.pop("v_pages")
+            for key in page_stores(self.pool.state):
+                self.pool.state[key] = job.state.pop(key)
             if self.pool.quant:
                 self.pool.state["k_scales"] = job.state.pop("k_scales")
                 self.pool.state["v_scales"] = job.state.pop("v_scales")
@@ -1329,6 +1339,9 @@ class ServingEngine:
         if self._engine_extras is not None:
             raise ValueError("journaling rejects engine extras: cross-attn "
                              "memory is not part of the snapshot payload")
+        if self.cfg.mla:
+            raise ValueError("journaling does not handle the latent page "
+                             "pool of a latent-attention model yet")
         self.journal = EngineJournal(
             directory, snapshot_every=snapshot_every or 32)
         self.journal.commit_snapshot(self._snapshot_payload(),

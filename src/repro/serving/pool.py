@@ -28,7 +28,10 @@ kernels/paged_attn.py, walks the block table directly so per-tick traffic
 scales with live pages; the gather fallback re-materializes a TRANSIENT
 dense-layout K/V per layer per tick.) GO rows stay slot-resident (they are
 [E, k]-shaped, not sequence-shaped); their score reset to -inf happens on
-the allocator's free path at retirement.
+the allocator's free path at retirement. A latent-attention model's pool
+holds one store of latent pages (`c_pages`) in place of K and V; every
+page move (admission, release, scrub, preemption) goes over whichever
+stores the state holds (models/model.py::page_stores).
 
 With a `mesh`, the pool's tensors are laid out by the rule-based sharder
 (`launch/sharding.py::serve_state_shardings`): slot rows over the
@@ -47,7 +50,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import quant as Q
-from repro.models.model import (init_decode_slot, init_decode_state,
+from repro.models.model import (PAGE_STORES, init_decode_slot,
+                                init_decode_state, page_stores,
                                 paged_supported, write_decode_slot)
 from repro.serving.paging import PageAllocator, pages_for_tokens
 from repro.serving.scheduler import Request
@@ -416,8 +420,8 @@ class SlotPool:
         dirty = self.alloc.pop_dirty(released)
         if dirty:
             ids = jnp.asarray(dirty, jnp.int32)
-            self.state["k_pages"] = self.state["k_pages"].at[:, ids].set(0)
-            self.state["v_pages"] = self.state["v_pages"].at[:, ids].set(0)
+            for key in page_stores(self.state):
+                self.state[key] = self.state[key].at[:, ids].set(0)
             changed = True
         if changed:
             self.state = self._pin(self.state)
@@ -478,9 +482,9 @@ class SlotPool:
             "top_p": float(self.top_ps[slot]),
             "key": self.keys[slot].copy(),
             "n_pages": n,
-            "k": np.asarray(self.state["k_pages"][:, ids]),
-            "v": np.asarray(self.state["v_pages"][:, ids]),
         }
+        for key in page_stores(self.state):
+            snap[PAGE_STORES[key]] = np.asarray(self.state[key][:, ids])
         if self.quant:
             snap["ks"] = np.asarray(self.state["k_scales"][:, ids])
             snap["vs"] = np.asarray(self.state["v_scales"][:, ids])
@@ -512,10 +516,9 @@ class SlotPool:
         row[:len(ids)] = ids
         self.block_table[slot] = row
         jids = jnp.asarray(ids, jnp.int32)
-        self.state["k_pages"] = _scatter_pages(
-            self.state["k_pages"], jids, jnp.asarray(snap["k"]))
-        self.state["v_pages"] = _scatter_pages(
-            self.state["v_pages"], jids, jnp.asarray(snap["v"]))
+        for key in page_stores(self.state):
+            self.state[key] = _scatter_pages(
+                self.state[key], jids, jnp.asarray(snap[PAGE_STORES[key]]))
         if self.quant:
             # int8 pages restore verbatim WITH their scales — resume is
             # bit-identical to never evicting, same as the fp32 pool
@@ -567,10 +570,9 @@ class SlotPool:
                     return
                 new = self.alloc.fork(
                     self.owner[slot].request_id, page)
-                self.state["k_pages"] = self.state["k_pages"].at[:, new].set(
-                    self.state["k_pages"][:, page])
-                self.state["v_pages"] = self.state["v_pages"].at[:, new].set(
-                    self.state["v_pages"][:, page])
+                for key in page_stores(self.state):
+                    self.state[key] = self.state[key].at[:, new].set(
+                        self.state[key][:, page])
                 if self.quant:
                     # a forked int8 page is only meaningful WITH its scale
                     self.state["k_scales"] = self.state["k_scales"].at[
@@ -589,10 +591,12 @@ class SlotPool:
                 self.state["k_scales"] = \
                     self.state["k_scales"].at[:, page].set(jnp.nan)
             else:
-                self.state["k_pages"] = \
-                    self.state["k_pages"].at[:, page, off].set(jnp.nan)
-        elif "k" in self.state:
-            self.state["k"] = self.state["k"].at[:, slot, t].set(jnp.nan)
+                key = page_stores(self.state)[0]
+                self.state[key] = \
+                    self.state[key].at[:, page, off].set(jnp.nan)
+        elif "k" in self.state or "c" in self.state:
+            key = "k" if "k" in self.state else "c"
+            self.state[key] = self.state[key].at[:, slot, t].set(jnp.nan)
         else:
             # recurrent archs: no KV rows — poison the slot's carried state
             # (batch axes per key match init_decode_slot: ssm/slstm -> 1,

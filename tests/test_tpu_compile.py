@@ -9,9 +9,16 @@ Hq=Hkv=32, hd=128) and deepseek-moe-16b (E=64, d=2048, F=1408: the
 down-projection's K of 1408 is one full-extent block) and require a
 compiled program that holds a Mosaic kernel (`tpu_custom_call`). The
 decode step of a two-layer model is compiled whole once per MoE width, to
-see that its grouped GEMM reads the stacked expert banks in place. Nothing
-runs; the topology is described inside a fixture, so a host that cannot
-describe it skips these tests alone.
+see that its grouped GEMM reads the stacked expert banks in place. The
+latent-attention decode kernel and the decode tick of moonlight-16b-a3b's
+7-layer stage compile at published widths (16 heads, latent 512 + rotary
+64, rows stored 640 wide), the tick reading the stacked latent pool in
+place; granite's and deepseek-7l's ticks, and deepseek-moe-16b's decode
+tick and expert-parallel prefill over the four chips of a v5e:2x2, keep
+the instruction counts they compiled to before latent attention and the
+sigmoid router were added. Nothing runs; the topology
+is described inside a fixture, so a host that cannot describe it skips
+these tests alone.
 """
 import dataclasses
 import os
@@ -36,7 +43,7 @@ PS, PAGES, SLOTS, CHUNK = 16, 24, 4, 128
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e_2x2():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache as cc
@@ -49,9 +56,41 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
     cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e_2x2):
+    return SingleDeviceSharding(v5e_2x2.devices[0])
+
+
+def _S(one_chip, shape, dt):
+    return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+
+def _tick_hlo(one_chip, cfg, slots, max_tokens, num_pages):
+    """The engine's decode tick for `cfg` (Pallas paths), compiled."""
+    from repro.models import model as M
+    from repro.serving import engine as ENG
+
+    def shaped(tree):
+        return jax.tree.map(lambda a: _S(one_chip, a.shape, a.dtype), tree)
+
+    params = shaped(jax.eval_shape(
+        lambda: M.model_init(jax.random.PRNGKey(0), cfg)))
+    pool = shaped(jax.eval_shape(lambda: M.init_decode_state(
+        cfg, slots, max_tokens, per_slot_t=True, paged=(num_pages, PS))))
+    return ENG._decode_step.lower(
+        params, pool, _S(one_chip, (slots,), jnp.int32),
+        _S(one_chip, (slots,), jnp.bool_), cfg).compile().as_text()
+
+
+def _kernel_paths(monkeypatch):
+    from repro.kernels import moe_gmm, ops
+    for mod in (moe_gmm, ops, PA):
+        monkeypatch.setattr(mod, "lowering_platform", lambda: "tpu")
 
 
 def _compile(fn, *shapes):
@@ -182,3 +221,118 @@ def test_paged_attention_compiles(one_chip, arch, quant, kind):
     q = (S((SLOTS, Hq, hd), jnp.bfloat16) if kind == "decode"
          else S((SLOTS, CHUNK, Hq, hd), jnp.bfloat16))
     _compile(attn, q, *pages, bt, S((SLOTS,), jnp.int32), *scales)
+
+
+def test_latent_decode_kernel_compiles(one_chip):
+    """The latent-attention decode kernel over moonlight's stacked pool of
+    7 layers: 16 heads, rows of 640 (latent 512, rotary key 64, zeros)."""
+    L, H, W, P = 7, 16, 640, 512
+    NP = 1 + SLOTS * P
+
+    def attn(q, pages, layer, bt, t):
+        return PA.mla_paged_decode(q, pages, layer, bt, t, rank=512,
+                                   scale=192 ** -0.5, interpret=False)
+
+    text = _compile(attn, _S(one_chip, (SLOTS, H, W), jnp.bfloat16),
+                    _S(one_chip, (L, NP, PS, W), jnp.bfloat16),
+                    _S(one_chip, (), jnp.int32),
+                    _S(one_chip, (SLOTS, P), jnp.int32),
+                    _S(one_chip, (SLOTS,), jnp.int32))
+    # the pool reaches the kernel as it is: no copy into another layout
+    assert f"bf16[{L},{NP},{PS},{W}]" in text
+    assert not re.search(rf"= bf16\[{L},{NP},{PS},{W}\]\S* copy\(", text)
+
+
+def test_latent_decode_tick_reads_the_pool_in_place(one_chip, monkeypatch):
+    """moonlight-16b-a3b's 7-layer stage (the dense layer 0 and 6 MoE
+    layers), its decode tick at published widths over 32 slots of 8192
+    tokens: every layer's kernel takes the stacked [L, NP, ps, 640] pool
+    and the layer index, and no instruction makes one layer's pool."""
+    _kernel_paths(monkeypatch)
+    cfg = get_config("moonlight-16b-a3b")
+    cfg = cfg.with_overrides(num_layers=7, tie_embeddings=True,
+                             paged_attn="kernel",
+                             moe=dataclasses.replace(cfg.moe, group_size=1,
+                                                     backend="pallas"))
+    NP, W = 1 + 32 * 512, cfg.latent_row_width()
+    hlo = _tick_hlo(one_chip, cfg, 32, 8192, NP)
+    calls = [ln for ln in hlo.splitlines()
+             if re.match(r"\s*(ROOT )?%_mla_paged_decode", ln)]
+    assert len(calls) == 2               # the dense layer, the MoE scan
+    assert all(f"bf16[7,{NP},{PS},{W}]" in ln for ln in calls)
+    assert f"[{NP},{PS},{W}]" not in hlo
+
+
+# instructions of the optimised decode tick before latent attention and
+# the sigmoid router were added, at each cell's engine (16 slots of 4096
+# tokens over 2049 pages) and its layers
+TICK_INSTRUCTIONS = {"granite-moe-3b-a800m": (32, 1285),
+                     "deepseek-moe-16b": (7, 1373)}
+
+
+@pytest.mark.parametrize("arch", sorted(TICK_INSTRUCTIONS))
+def test_existing_ticks_keep_their_instructions(one_chip, monkeypatch, arch):
+    _kernel_paths(monkeypatch)
+    layers, count = TICK_INSTRUCTIONS[arch]
+    cfg = get_config(arch)
+    cfg = cfg.with_overrides(num_layers=layers, paged_attn="kernel",
+                             moe=dataclasses.replace(cfg.moe, group_size=1,
+                                                     backend="pallas"))
+    hlo = _tick_hlo(one_chip, cfg, 16, 4096, 2049)
+    assert len(re.findall(r"^\s*(?:ROOT )?%\S+ = \S+ [\w-]+\(", hlo,
+                          re.M)) == count
+
+
+# the same, for deepseek-moe-16b at its 28 layers over the four chips, laid
+# out as its four-chip cell lays it out (16 slots of 4096 tokens over 2049
+# pages, experts over "model"): the decode tick, and the one-shot prefill of
+# 512 tokens, whose MoE runs expert-parallel (core/moe.py moe_forward_ep)
+EP4_INSTRUCTIONS = {"decode": 1736, "prefill": 1759}
+
+
+@pytest.mark.parametrize("program", sorted(EP4_INSTRUCTIONS))
+def test_four_chip_programs_keep_their_instructions(v5e_2x2, monkeypatch,
+                                                    program):
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from repro.core import moe as MOE
+    from repro.launch.mesh import make_mesh
+    from repro.launch.sharding import param_shardings, serve_state_shardings
+    from repro.models import model as M
+    from repro.serving import engine as ENG
+    _kernel_paths(monkeypatch)
+    cfg = get_config("deepseek-moe-16b")
+    cfg = cfg.with_overrides(
+        num_layers=28, tie_embeddings=True, paged_attn="kernel",
+        moe=dataclasses.replace(cfg.moe, group_size=1, backend="pallas",
+                                capacity_factor=64 / 6))
+    slots, max_tokens, num_pages = 16, 4096, 2049
+    mesh = make_mesh((1, 4), ("data", "model"), devices=v5e_2x2.devices[:4])
+    one = NamedSharding(mesh, P())
+
+    def shaped(tree, shardings):
+        return jax.tree.map(lambda a, s: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=s), tree, shardings)
+
+    params = jax.eval_shape(lambda: M.model_init(jax.random.PRNGKey(0), cfg))
+    params = shaped(params, param_shardings(params, cfg, mesh, mode="serve"))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one)  # noqa
+    with jax.set_mesh(mesh):
+        if program == "decode":
+            pool = jax.eval_shape(lambda: M.init_decode_state(
+                cfg, slots, max_tokens, per_slot_t=True,
+                paged=(num_pages, PS)))
+            pool = shaped(pool, serve_state_shardings(
+                cfg, mesh, slots, max_tokens, paged=(num_pages, PS)))
+            lowered = ENG._decode_step.lower(
+                params, pool, i32(slots),
+                jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one), cfg)
+        else:
+            assert MOE.ep_available(cfg.moe)
+            lowered = ENG._jit_prefill.lower(params, i32(1, 512), cfg, {},
+                                             max_tokens, i32())
+        hlo = lowered.compile().as_text()
+    assert "tpu_custom_call" in hlo
+    assert len(re.findall(r"^\s*(?:ROOT )?%\S+ = \S+ [\w-]+\(", hlo,
+                          re.M)) == EP4_INSTRUCTIONS[program]
